@@ -1,7 +1,7 @@
 """Enforce-style error checking.
 
 Reference parity: ``PADDLE_ENFORCE*`` macros (paddle/common/enforce.h) and
-the typed error taxonomy (paddle/common/errors.h): InvalidArgument,
+the typed error hierarchy (paddle/common/errors.h): InvalidArgument,
 NotFound, OutOfRange, Unimplemented, PreconditionNotMet, etc.  The macros'
 error-stack formatting collapses to plain Python exceptions with the same
 category names so user-facing messages keep the reference's shape.

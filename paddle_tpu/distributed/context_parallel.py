@@ -39,10 +39,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ..compat import shard_map as _compat_shard_map
-from ..compat import axis_size as _compat_axis_size
 
 from ..common.flags import define_flag, get_flag
+from ..ops.pallas import ShapeNotCovered
 
 __all__ = ["ring_attention_local", "ulysses_attention_local",
            "sep_attention_raw"]
@@ -151,7 +150,7 @@ def _rotate(tree, axis_name: str, n: int):
 # ---------------------------------------------------------------------------
 
 def _ring_fwd_impl(q, k, v, axis_name: str, causal: bool):
-    n = _compat_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     lk = k.shape[1]
@@ -271,7 +270,7 @@ def _chunk_bwd(q, kc, vc, out, lse, do, diag: bool, q_off, k_off,
 
 def _ring_bwd_rule(axis_name, causal, res, do):
     q, k, v, out, lse = res
-    n = _compat_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     lk, hk = k.shape[1], k.shape[2]
@@ -344,7 +343,7 @@ def _local_full_attention(q, k, v, causal: bool):
         from ..ops.pallas.flash_attention import flash_attention_raw
         try:
             return flash_attention_raw(q, k, v, causal=causal)
-        except NotImplementedError:
+        except ShapeNotCovered:
             pass
     from ..ops import _nn
     return _nn.scaled_dot_product_attention(q, k, v, is_causal=causal)
@@ -383,17 +382,17 @@ def sep_attention_raw(q, k, v, causal: bool = True,
         pm = get_mesh()
         mesh = pm.mesh if pm is not None else None
     if mesh is None:
-        raise NotImplementedError("no mesh — sep attention inactive")
+        raise ShapeNotCovered("no mesh — sep attention inactive")
     sep = mesh.shape.get("sep", 1)
     if sep <= 1:
-        raise NotImplementedError("sep degree is 1")
+        raise ShapeNotCovered("sep degree is 1")
     b, s, h, d = q.shape
     sk, hk = k.shape[1], k.shape[2]
     if s != sk:
-        raise NotImplementedError("sep attention needs sq == sk "
+        raise ShapeNotCovered("sep attention needs sq == sk "
                                   "(no KV-cache decode)")
     if s % sep:
-        raise NotImplementedError(f"seq {s} not divisible by sep {sep}")
+        raise ShapeNotCovered(f"seq {s} not divisible by sep {sep}")
 
     batch_axes = tuple(a for a in ("dp", "sharding")
                        if mesh.shape.get(a, 1) > 1)
@@ -410,7 +409,7 @@ def sep_attention_raw(q, k, v, causal: bool = True,
         impl = "ulysses" if (h_loc % sep == 0 and hk_loc % sep == 0) \
             else "ring"
     if impl == "ulysses" and (h_loc % sep or hk_loc % sep):
-        raise NotImplementedError(
+        raise ShapeNotCovered(
             f"ulysses needs heads divisible by sep ({h_loc}/{hk_loc} "
             f"vs {sep})")
 
@@ -428,7 +427,7 @@ def _mapped(mesh, impl: str, causal: bool, manual: frozenset, spec):
     fn = {"ring": ring_attention_local,
           "ulysses": ulysses_attention_local}[impl]
     body = functools.partial(fn, axis_name="sep", causal=causal)
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         lambda q_, k_, v_: body(q_, k_, v_),
         mesh=mesh, axis_names=manual,
         in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)

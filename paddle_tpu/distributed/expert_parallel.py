@@ -65,8 +65,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ..compat import shard_map as _compat_shard_map
-from ..compat import axis_size as _compat_axis_size
 
 __all__ = ["moe_grouped_ep_raw", "expert_fold_axes",
            "ep_grouped_compatible", "EP_FOLD", "exchange_plan"]
@@ -101,7 +99,7 @@ def _fused_index(fold: Tuple[str, ...]):
     emulation's buffer selection must agree on it)."""
     me = jnp.int32(0)
     for a in fold:
-        me = me * _compat_axis_size(a) + lax.axis_index(a)
+        me = me * lax.axis_size(a) + lax.axis_index(a)
     return me
 
 
@@ -258,7 +256,7 @@ def _mapped_ep(mesh, fold, use_mp, k, balance_coef, z_coef, norm_topk,
     x_spec = P(fold, None)
     specs = (x_spec, P(None, None), P(fold, None, mp),
              P(fold, None, mp), P(fold, mp, None))
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, axis_names=frozenset(fold) | (
             {"mp"} if use_mp else set()),
         in_specs=specs, out_specs=(x_spec, P(), P()), check_vma=False)

@@ -9,12 +9,18 @@ training resumes from the latest checkpoint.
 
 TPU-native design: the rendezvous the env seeds is consumed by
 ``jax.distributed.initialize`` (the TCPStore analog is jax's
-coordination service; rank 0's address is the master).  One process per
-host is the TPU norm — ``--nproc_per_node`` exists for CPU simulation
-and multi-process-per-host setups.
+coordination service; rank 0's address is the master).  ONE process
+drives all chips of a TPU host: a chip belongs to one process at a time,
+every worker inherits this process's whole environment and no chip of
+its own, so ``--nproc_per_node N`` on a TPU host would make N processes
+claim the same chips and all but one would fail or hang.  The
+controller refuses that up front (``_refuse_shared_chips``);
+``--nproc_per_node`` > 1 is for CPU simulation (``JAX_PLATFORMS=cpu``).
+The controller itself never initialises a JAX backend.
 """
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import socket
@@ -31,6 +37,26 @@ def free_port() -> int:
     with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
+
+
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
+def _tpu_device_nodes(dev="/dev", sysfs="/sys") -> List[str]:
+    """Device nodes of this host's TPU chips, read without touching
+    JAX: ``/dev/accel*`` (the accel driver), or the vfio groups that
+    hold a PCI function of Google's vendor id (a vfio group of any
+    other device is not a TPU)."""
+    nodes = glob.glob(os.path.join(dev, "accel*"))
+    for group in glob.glob(os.path.join(dev, "vfio", "[0-9]*")):
+        for vendor in glob.glob(os.path.join(
+                sysfs, "kernel", "iommu_groups", os.path.basename(group),
+                "devices", "*", "vendor")):
+            with open(vendor) as f:
+                if f.read().strip().lower() == _GOOGLE_PCI_VENDOR:
+                    nodes.append(group)
+                    break
+    return nodes
 
 
 @dataclass
@@ -96,7 +122,27 @@ class Controller:
         return subprocess.Popen(cmd, env=self._worker_env(local_rank),
                                 stdout=stdout, stderr=stderr)
 
+    def _refuse_shared_chips(self):
+        """Several workers on one TPU host would all claim the same
+        chips.  Decided from the workers' environment and the device
+        nodes alone — asking JAX would make THIS process the one that
+        holds the chips."""
+        if self.cfg.nproc_per_node <= 1:
+            return
+        plats = self._worker_env(0).get("JAX_PLATFORMS", "")
+        if plats and "tpu" not in plats.split(","):
+            return
+        if _tpu_device_nodes():
+            raise RuntimeError(
+                f"nproc_per_node={self.cfg.nproc_per_node} on a TPU "
+                f"host: one process drives all chips of a host (a chip "
+                f"belongs to one process at a time, and every worker "
+                f"would claim the same chips).  Launch ONE process per "
+                f"host and let its mesh span jax.devices(); for a CPU "
+                f"simulation of several workers set JAX_PLATFORMS=cpu.")
+
     def start(self):
+        self._refuse_shared_chips()
         self.procs = [self._spawn_one(i)
                       for i in range(self.cfg.nproc_per_node)]
 

@@ -37,8 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from ..compat import shard_map as _compat_shard_map
-from ..compat import axis_size as _compat_axis_size
 
 from ..common.errors import enforce
 from ..nn.layer import Layer
@@ -52,23 +50,14 @@ __all__ = ["LayerDesc", "SharedLayerDesc", "PipelineLayer", "gpipe_spmd",
 # The compiled SPMD pipeline engine
 # ---------------------------------------------------------------------------
 
-def _typeof(x):
-    fn = getattr(jax, "typeof", None)
-    return fn(x) if fn is not None else jax.core.get_aval(x)
-
-
 def _pvary(x, axis):
     # no-op when already varying over this axis (pcast rejects that);
     # any OTHER ValueError (bad axis name etc.) must surface here, not
     # as an opaque vma mismatch deep in the scan
-    aval = _typeof(x)
+    aval = jax.typeof(x)
     if axis in getattr(aval, "vma", ()):
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis,), to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, (axis,))
-    return x   # pre-vma jax: no varying bookkeeping to maintain
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def _mesh_platform(mesh) -> str:
@@ -124,7 +113,7 @@ def _jitted_pipeline(stage_fn: Callable, mesh, pp_axis: str,
         locals_ = [p[0] for p in params_local]
         n_micro = xm.shape[0]
         stage = jax.lax.axis_index(pp_axis)
-        nstage = _compat_axis_size(pp_axis)
+        nstage = jax.lax.axis_size(pp_axis)
         v = n_virtual
         rounds = -(-n_micro // nstage) if v > 1 else 1
         total = (rounds * v * nstage + nstage - 1) if v > 1 \
@@ -199,9 +188,7 @@ def _jitted_pipeline(stage_fn: Callable, mesh, pp_axis: str,
     in_specs = (tuple(P(pp_axis) for _ in range(n_params)), P(),
                 *(P() for _ in range(n_extra + n_tail_params + n_tail_idx)))
     out_specs = P() if tail_fn is not None else P(pp_axis)
-    manual = ({pp_axis} if hasattr(jax, "shard_map")
-              else set(mesh.axis_names))
-    mapped = _compat_shard_map(inner, mesh=mesh, axis_names=manual,
+    mapped = jax.shard_map(inner, mesh=mesh, axis_names={pp_axis},
                            in_specs=in_specs, out_specs=out_specs)
     # jit wrapper: eager evaluation of checkpoint/scan inside shard_map is
     # unsupported; under an outer jit this inlines
@@ -582,7 +569,7 @@ def _jitted_1f1b(stage_fn: Callable, tail_fn: Callable, mesh,
 
             def seed(p, fill):
                 ct = jnp.full(p.shape, fill, p.dtype)
-                if pp_axis in getattr(_typeof(p), "vma", ()):
+                if pp_axis in getattr(jax.typeof(p), "vma", ()):
                     ct = _pvary(ct, pp_axis)
                 return ct
 
@@ -681,9 +668,7 @@ def _jitted_1f1b(stage_fn: Callable, tail_fn: Callable, mesh,
                                      + n_tail_idx)))
     out_specs = (P(), P(), tuple(P(pp_axis) for _ in range(n_params)),
                  P(), tuple(P() for _ in range(n_tail_params)))
-    manual = ({pp_axis} if hasattr(jax, "shard_map")
-              else set(mesh.axis_names))
-    mapped = _compat_shard_map(inner, mesh=mesh, axis_names=manual,
+    mapped = jax.shard_map(inner, mesh=mesh, axis_names={pp_axis},
                            in_specs=in_specs, out_specs=out_specs)
     return jax.jit(mapped)
 

@@ -52,16 +52,28 @@ class TPShardings:
         return _axis_size(self.mesh, self.axis)
 
     def _sharding(self, ndim: int, dim: Optional[int]):
-        from .. import compat
         spec = [None] * ndim
         if dim is not None:
             spec[dim] = self.axis
-        return compat.named_sharding(self.mesh, *spec)
+        return NamedSharding(self.mesh, PartitionSpec(*spec))
 
     def constrain(self, x, dim: Optional[int] = None):
-        from .. import compat
-        return compat.with_sharding_constraint(
+        return jax.lax.with_sharding_constraint(
             x, self._sharding(x.ndim, dim))
+
+    def per_shard(self, fn, in_dims, out_dims):
+        """``fn`` run once per tp shard (``jax.shard_map``, manual over
+        the tp axis) — how a Pallas kernel runs under the mesh: Mosaic
+        kernels cannot be partitioned by GSPMD, so each shard gets its
+        own heads.  ``in_dims`` / ``out_dims`` give, per argument /
+        result of ``fn`` (which returns a tuple), the dimension sharded
+        over tp (``None`` = replicated)."""
+        def spec(dim):
+            return PartitionSpec() if dim is None else \
+                PartitionSpec(*([None] * dim + [self.axis]))
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=tuple(map(spec, in_dims)),
+            out_specs=tuple(map(spec, out_dims)), check_vma=False)
 
     def put(self, x, dim: Optional[int] = None):
         x = jax.numpy.asarray(x)

@@ -175,6 +175,16 @@ def _mm(x, w):
     return jnp.matmul(x, w)
 
 
+def _per_shard(shardings, kernel, in_dims, out_dims):
+    """A Pallas kernel call under the engine's tp mesh: wrapped in a
+    ``shard_map`` so every shard runs it on its own heads (GSPMD cannot
+    partition a Mosaic call; see ``TPShardings.per_shard``).  Without a
+    mesh the kernel is returned untouched."""
+    if shardings is None:
+        return kernel
+    return shardings.per_shard(kernel, in_dims, out_dims)
+
+
 def _tpc(x, shardings, dim=None):
     """Tensor-parallel sharding constraint: shard ``dim`` over the tp
     axis (``None`` = fully replicated) when the engine carries a mesh,
@@ -269,12 +279,16 @@ def _paged_prefill_chunk(stack, norm_w, head_w, embed_w, rope,
     def attend(q, k_full, v_full):
         # q [C, NH, D], k/v_full [S_kv, KVH, D]
         if is_compiled_with_tpu():
+            from ..ops.pallas import ShapeNotCovered
             from ..ops.pallas.flash_attention import flash_attention_raw
+            flash = _per_shard(
+                shardings, lambda q_, k_, v_, m_: (flash_attention_raw(
+                    q_, k_, v_, causal=False, mask=m_),),
+                (2, 2, 2, None), (2,))
             try:
-                return flash_attention_raw(
-                    q[None], k_full[None], v_full[None], causal=False,
-                    mask=amask[None, None])[0]
-            except NotImplementedError:
+                return flash(q[None], k_full[None], v_full[None],
+                             amask[None, None])[0][0]
+            except ShapeNotCovered:
                 pass
         g = q.shape[1] // kvh
         qg = q.reshape(c, kvh, g, head_dim)
@@ -360,7 +374,7 @@ def _paged_prefill_chunk(stack, norm_w, head_w, embed_w, rope,
             return (_tpc(hcur + _mm(_tpc(ff, shardings), dw),
                          shardings), (kp, vp, ksp, vsp))
         ff, cnt = moe_ffn(hn, (rw, egw, euw, edw, sgw, suw, sdw, seg),
-                          arch, moe_live, moe_group)
+                          arch, moe_live, moe_group, shardings)
         return (_tpc(hcur + ff, shardings), (kp, vp, ksp, vsp, cnt))
 
     if arch is None:
@@ -430,8 +444,15 @@ def _decode_one_token_fn(stack, norm_w, head_w, embed_w, rope, tables,
     # TPU (round-3 serving bottleneck; see paged_attention.py).  The
     # _raw form: this body is traced INSIDE an already-jitted program,
     # often inside its scan/while loop.
+    on_tpu = is_compiled_with_tpu()
     append_attend = paged_decode_append_attend_raw \
-        if is_compiled_with_tpu() else paged_decode_append_attend_reference
+        if on_tpu else paged_decode_append_attend_reference
+
+    def kernel_dims(n_scales):
+        # q/k_new/v_new shard on their head dim, pools and scale pools
+        # on KVH, tables and lengths replicate
+        return ((1, 0, 0, 1, 1, None, None) + (0,) * n_scales,
+                (1, 0, 0) + (0,) * n_scales)
 
     if arch is not None:
         from .moe_dispatch import moe_ffn
@@ -469,13 +490,15 @@ def _decode_one_token_fn(stack, norm_w, head_w, embed_w, rope, tables,
             kf = k.astype(jnp.float32)
             q = (qf * cos + rotate_half(qf) * sin).astype(q.dtype)
             k = (kf * cos + rotate_half(kf) * sin).astype(k.dtype)
+            step_fn = append_attend if not on_tpu else _per_shard(
+                shardings, append_attend,
+                *kernel_dims(0 if ksp is None else 2))
             if ksp is None:
-                attn, kp, vp = append_attend(q, kp, vp, k, v, tables,
-                                             lens)
+                attn, kp, vp = step_fn(q, kp, vp, k, v, tables, lens)
             else:
                 # int8 pools ride the same fused kernel with their
                 # per-token scale rows ([KVH, n_pages, 1, P] views)
-                attn, kp, vp, ks4, vs4 = append_attend(
+                attn, kp, vp, ks4, vs4 = step_fn(
                     q, kp, vp, k, v, tables, lens,
                     ksp[:, :, None, :], vsp[:, :, None, :])
                 ksp = ks4.reshape(ksp.shape)
@@ -491,7 +514,8 @@ def _decode_one_token_fn(stack, norm_w, head_w, embed_w, rope, tables,
                 return (_tpc(hcur + _mm(_tpc(ff, shardings), dw),
                              shardings), (kp, vp, ksp, vsp))
             ff, cnt = moe_ffn(hn, (rw, egw, euw, edw, sgw, suw, sdw,
-                                   seg), arch, live)
+                                   seg), arch, live,
+                              shardings=shardings)
             return (_tpc(hcur + ff, shardings),
                     (kp, vp, ksp, vsp, cnt))
 
@@ -757,16 +781,24 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
         if on_tpu:
             # ragged kernel: per-descriptor [P, H, D] output blocks,
             # gathered back to the flat row order
+            # (under a tp mesh each shard runs the kernel on its own
+            # heads: q/k/v rows shard on the head dim, pools and scale
+            # pools on KVH, descriptors replicate, output blocks come
+            # back sharded on H)
+            n_sc = 0 if ksp is None else 2
+            ragged = _per_shard(
+                shardings, ragged_paged_append_attend_raw,
+                (1, 0, 0, 1, 1, None, None, None, None) + (0,) * n_sc,
+                (2, 0, 0) + (0,) * n_sc)
             if ksp is None:
-                blocks, kp, vp = ragged_paged_append_attend_raw(
+                blocks, kp, vp = ragged(
                     q, kp, vp, k, v, q_start, q_len, kv_len,
                     desc_tables)
             else:
-                blocks, kp, vp, ks4, vs4 = \
-                    ragged_paged_append_attend_raw(
-                        q, kp, vp, k, v, q_start, q_len, kv_len,
-                        desc_tables, ksp[:, :, None, :],
-                        vsp[:, :, None, :])
+                blocks, kp, vp, ks4, vs4 = ragged(
+                    q, kp, vp, k, v, q_start, q_len, kv_len,
+                    desc_tables, ksp[:, :, None, :],
+                    vsp[:, :, None, :])
                 ksp = ks4.reshape(ksp.shape)
                 vsp = vs4.reshape(vsp.shape)
             attn = blocks[desc_of_row, off_of_row]          # [T, NH, D]
@@ -791,7 +823,7 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
             return (_tpc(hcur + _mm(_tpc(ff, shardings), dw),
                          shardings), (kp, vp, ksp, vsp))
         ff, cnt = moe_ffn(hn, (rw, egw, euw, edw, sgw, suw, sdw, seg),
-                          arch, moe_live, moe_group)
+                          arch, moe_live, moe_group, shardings)
         return (_tpc(hcur + ff, shardings), (kp, vp, ksp, vsp, cnt))
 
     if arch is None:
@@ -2413,7 +2445,7 @@ class LLMEngine:
         """Decode up to ``steps_per_sync`` tokens for every active
         request in one device dispatch.  The host only
         syncs (EOS checks, admission window) once per call, so over a
-        high-latency dispatch path (remote PJRT) throughput scales with
+        high-latency dispatch path throughput scales with
         steps_per_sync; the window never exceeds any request's
         remaining token budget, so page capacity is exact.  With
         ``scan_decode`` (default) multi-step windows run the early-exit

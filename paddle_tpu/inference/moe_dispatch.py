@@ -91,27 +91,36 @@ def _expert_rows_mm(x, w, row_expert):
                       wr.astype(jnp.float32))
 
 
-def _gmm_apply(xs, w, tile_expert, gcounts, tm, on_tpu):
+def _gmm_apply(xs, w, tile_expert, gcounts, tm, on_tpu, shardings=None):
     """One grouped matmul over the sorted tile-aligned buffer: the
     Pallas kernel on TPU, the per-row oracle (same rows, same math as
-    dense mode) on CPU."""
+    dense mode) on CPU.  Under a tp mesh the expert stacks are
+    replicated, so every shard runs the whole kernel (a Mosaic call
+    cannot be partitioned by GSPMD: ``TPShardings.per_shard``)."""
     import jax.numpy as jnp
 
-    from ..ops.pallas.grouped_matmul import gmm, gmm_reference
+    from ..ops.pallas import grouped_matmul
     if not on_tpu:
         row_e = jnp.repeat(tile_expert, tm)
         return _expert_rows_mm(xs, w, row_e)
+
+    def gmm(lhs, rhs, te, gc, tm):
+        if shardings is None:
+            return grouped_matmul.gmm(lhs, rhs, te, gc, tm=tm)
+        return shardings.per_shard(
+            lambda *a: (grouped_matmul.gmm(*a, tm=tm),),
+            (None,) * 4, (None,))(lhs, rhs, te, gc)[0]
     if isinstance(w, tuple):
         # the kernel streams one weight dtype; upcast feeds the MXU
         # copy XLA fuses into the kernel's input stream, and the
         # per-out-channel scale folds into the output like _mm's
         qw, sc = w
-        y = gmm(xs, qw.astype(xs.dtype), tile_expert, gcounts, tm=tm)
+        y = gmm(xs, qw.astype(xs.dtype), tile_expert, gcounts, tm)
         return y * sc[jnp.repeat(tile_expert, tm)]
-    return gmm(xs, w, tile_expert, gcounts, tm=tm)
+    return gmm(xs, w, tile_expert, gcounts, tm)
 
 
-def moe_ffn(hn, mw, arch, live, group_start=None):
+def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None):
     """The MoE decoder-layer FFN for one serving dispatch.
 
     hn [T, H] post-attention-layernorm rows; ``mw`` the per-layer
@@ -183,11 +192,14 @@ def moe_ffn(hn, mw, arch, live, group_start=None):
             make_dropless_plan_rows(row_expert, e, tm)
         xs = jnp.zeros((m_pad, h), f32).at[dest].set(
             xf[order // k], mode="drop")
-        hg = _gmm_apply(xs, egw, tile_expert, gcounts, tm, on_tpu)
-        hu = _gmm_apply(xs, euw, tile_expert, gcounts, tm, on_tpu)
+        hg = _gmm_apply(xs, egw, tile_expert, gcounts, tm, on_tpu,
+                        shardings)
+        hu = _gmm_apply(xs, euw, tile_expert, gcounts, tm, on_tpu,
+                        shardings)
         hs = (jax.nn.silu(hg.astype(f32))
               * hu.astype(f32)).astype(xs.dtype)
-        ys = _gmm_apply(hs, edw, tile_expert, gcounts, tm, on_tpu)
+        ys = _gmm_apply(hs, edw, tile_expert, gcounts, tm, on_tpu,
+                        shardings)
         dest_safe = jnp.minimum(dest, m_pad - 1)
         y_sorted = jnp.where(valid_sorted[:, None],
                              ys[dest_safe].astype(f32), 0.0)

@@ -474,10 +474,11 @@ def _attn_for_shape(q, k, v):
     from ..common.flags import get_flag
     from ..runtime.device import is_compiled_with_tpu
     if get_flag("use_pallas") and is_compiled_with_tpu():
+        from ..ops.pallas import ShapeNotCovered
         from ..ops.pallas.spmd import flash_attention_spmd
         try:
             return flash_attention_spmd(q, k, v, causal=True)
-        except NotImplementedError:
+        except ShapeNotCovered:
             pass
     from ..ops import _nn
     return _nn.scaled_dot_product_attention(q, k, v, is_causal=True)

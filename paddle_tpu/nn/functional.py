@@ -42,25 +42,11 @@ from ..ops.api import (  # noqa: F401
 )
 from ..ops import api as _api
 from ..tensor import apply_op
-from ..runtime.device import is_compiled_with_tpu
+from ..ops.pallas import ShapeNotCovered
+from ..runtime import device as _device
 
 batch_norm = _api.batch_norm
 scaled_dot_product_attention_ref = _api.scaled_dot_product_attention
-
-_FLASH_RAW = 0  # unresolved; becomes the kernel fn or None after first use
-
-
-def _flash_kernel():
-    """One-time cached import of the Pallas flash kernel (a failing
-    import must not re-run per attention call on the hot path)."""
-    global _FLASH_RAW
-    if _FLASH_RAW == 0:
-        try:
-            from ..ops.pallas.spmd import flash_attention_spmd
-            _FLASH_RAW = flash_attention_spmd
-        except ImportError:
-            _FLASH_RAW = None
-    return _FLASH_RAW
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
@@ -83,65 +69,64 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             try:
                 return apply_op(sep_attention_raw, query, key, value,
                                 causal=is_causal)
-            except NotImplementedError:
+            except ShapeNotCovered:
                 pass  # shape not sep-shardable; plain paths below
     from ..tensor import Tensor as _T
     # a TRAINED additive bias keeps its REAL gradient via the dmask
     # kernel (round 3); boolean trainable masks make no sense, and a
     # query-broadcast trainable bias is not kernel-covered — those fall
-    # back to the jnp path below via NotImplementedError
+    # back to the jnp path below via ShapeNotCovered
     mask_trainable = (isinstance(attn_mask, _T)
                       and not attn_mask.stop_gradient)
     use_pallas = (
         get_flag("use_pallas")
-        and is_compiled_with_tpu()
+        and _device.is_compiled_with_tpu()
     )
     if use_pallas:
-        kernel = _flash_kernel()
-        if kernel is not None:
-            mask = attn_mask
-            if mask is not None:
-                if mask_trainable:
-                    # keep the Tensor so grads flow; a bool mask can't
-                    # be "trainable" — treat it as constant instead of
-                    # feeding raw 0/1 to the additive kernel
-                    if attn_mask.dtype == jnp.bool_:
-                        mask_trainable = False
-                        mask = jnp.where(attn_mask.value, 0.0,
-                                         -1e30).astype(jnp.float32)
-                    else:
-                        mask = attn_mask
+        from ..ops.pallas.spmd import flash_attention_spmd as kernel
+        mask = attn_mask
+        if mask is not None:
+            if mask_trainable:
+                # keep the Tensor so grads flow; a bool mask can't
+                # be "trainable" — treat it as constant instead of
+                # feeding raw 0/1 to the additive kernel
+                if attn_mask.dtype == jnp.bool_:
+                    mask_trainable = False
+                    mask = jnp.where(attn_mask.value, 0.0,
+                                     -1e30).astype(jnp.float32)
                 else:
-                    mval = mask.value if isinstance(mask, _T) \
-                        else jnp.asarray(mask)
-                    # bool masks (True = attend) → additive -inf bias
-                    if mval.dtype == jnp.bool_:
-                        mval = jnp.where(mval, 0.0,
-                                         -1e30).astype(jnp.float32)
-                    mask = mval
-            dp = float(dropout_p) if training else 0.0
-            try:
-                # NotImplementedError is the kernel's documented "shape not
-                # covered" signal; anything else is a real bug and must
-                # propagate (ADVICE.md round-1)
-                if mask_trainable or dp > 0.0:
-                    import jax as _jax
+                    mask = attn_mask
+            else:
+                mval = mask.value if isinstance(mask, _T) \
+                    else jnp.asarray(mask)
+                # bool masks (True = attend) → additive -inf bias
+                if mval.dtype == jnp.bool_:
+                    mval = jnp.where(mval, 0.0,
+                                     -1e30).astype(jnp.float32)
+                mask = mval
+        dp = float(dropout_p) if training else 0.0
+        try:
+            # ShapeNotCovered is the kernel wrappers' documented "shape
+            # not covered" signal; anything else (a Mosaic compile
+            # error included) is a real bug and must propagate
+            if mask_trainable or dp > 0.0:
+                import jax as _jax
 
-                    from ..ops import random as _R
-                    from ..ops.pallas.spmd import \
-                        flash_attention_spmd_ext
-                    seed = _jax.random.randint(
-                        _R.split_key(), (), 0, 2**31 - 1,
-                        dtype=jnp.int32) if dp > 0.0 \
-                        else jnp.zeros((), jnp.int32)
-                    return apply_op(flash_attention_spmd_ext, query, key,
-                                    value, mask, seed, causal=is_causal,
-                                    dropout_p=dp,
-                                    mask_grad=mask_trainable)
-                return apply_op(kernel, query, key, value, causal=is_causal,
-                                mask=mask)
-            except NotImplementedError:
-                pass
+                from ..ops import random as _R
+                from ..ops.pallas.spmd import \
+                    flash_attention_spmd_ext
+                seed = _jax.random.randint(
+                    _R.split_key(), (), 0, 2**31 - 1,
+                    dtype=jnp.int32) if dp > 0.0 \
+                    else jnp.zeros((), jnp.int32)
+                return apply_op(flash_attention_spmd_ext, query, key,
+                                value, mask, seed, causal=is_causal,
+                                dropout_p=dp,
+                                mask_grad=mask_trainable)
+            return apply_op(kernel, query, key, value, causal=is_causal,
+                            mask=mask)
+        except ShapeNotCovered:
+            pass
     if mask_trainable:
         # positional-mask variant keeps the trainable bias on the tape
         # (kwargs are static to the op layer)

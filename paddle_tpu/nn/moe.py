@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import device as _device
 from ..tensor import Tensor, apply_op
 from .initializer import Normal
 from .layer import Layer
@@ -316,8 +317,7 @@ class MoELayer(Layer):
                         f"({num_tokens}) divisible by the expert fold "
                         f"({fold})")
                 return "grouped_ep"
-            import jax as _jax
-            if divisible and _jax.default_backend() == "tpu":
+            if divisible and _device.is_compiled_with_tpu():
                 return "grouped_ep"
             return "dense"
         if mode != "auto":
@@ -326,8 +326,7 @@ class MoELayer(Layer):
         # — keep the GSPMD-partitionable einsums
         if pm is not None and pm.mesh.shape.get("mp", 1) > 1:
             return "dense"
-        import jax as _jax
-        return "grouped" if _jax.default_backend() == "tpu" else "dense"
+        return "grouped" if _device.is_compiled_with_tpu() else "dense"
 
     def forward(self, x):
         b, s, h = x.shape
@@ -344,7 +343,7 @@ class MoELayer(Layer):
                 self.experts.down_w, k=self.gate.k,
                 balance_coef=self.gate.balance_loss_weight,
                 z_coef=self.gate.z_loss_weight, tm=self.group_tile,
-                interpret=jax.default_backend() != "tpu",
+                interpret=not _device.is_compiled_with_tpu(),
                 norm_topk=self.gate.norm_topk_prob,
                 mesh=get_mesh().mesh,
                 capacity_factor=self.ep_capacity_factor,
@@ -362,7 +361,7 @@ class MoELayer(Layer):
                 self.experts.down_w, k=self.gate.k,
                 balance_coef=self.gate.balance_loss_weight,
                 z_coef=self.gate.z_loss_weight, tm=self.group_tile,
-                interpret=jax.default_backend() != "tpu",
+                interpret=not _device.is_compiled_with_tpu(),
                 norm_topk=self.gate.norm_topk_prob)
         else:
             combine, dispatch, aux = self.gate(flat)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import ShapeNotCovered
 from .vma import out_sds
 
 __all__ = ["flash_attention_raw", "flash_attention_bhsd",
@@ -190,6 +191,7 @@ def _fwd(q, k, v, *, causal: bool, bq: int, bk: int, mask=None,
                           bq=bq, bk=bk, nk=nk, off=off,
                           has_mask=mask is not None,
                           dropout_p=dropout_p),
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -392,6 +394,7 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal, bq, bk, mask=None,
                           bq=bq, bk=bk, nk=nk, off=off,
                           has_mask=mask is not None,
                           dropout_p=dropout_p),
+        name="flash_bwd_dq",
         grid=(b, h, nq, nk),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, 1, bq, d),
@@ -433,6 +436,7 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal, bq, bk, mask=None,
                           bq=bq, bk=bk, nq=nq, group=group, off=off,
                           has_mask=mask is not None,
                           dropout_p=dropout_p),
+        name="flash_bwd_dkv",
         grid=(b, hk, nk, group, nq),
         in_specs=dkv_specs,
         out_specs=[
@@ -612,7 +616,7 @@ def _bwd_dmask(q, k, v, out, lse, do, mask, *, causal, bq, bk,
     group = h // hk
     mb, mh, msq, _ = mask.shape
     if msq != sq:
-        raise NotImplementedError(
+        raise ShapeNotCovered(
             "trainable bias needs full Sq (no query-broadcast)")
     nq, nk = sq // bq, sk // bk
     rb = b if mb == 1 else 1
@@ -656,6 +660,7 @@ def _bwd_dmask(q, k, v, out, lse, do, mask, *, causal, bq, bk,
         functools.partial(_bwd_dmask_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, off=off, mb=mb, mh=mh, rb=rb,
                           rh=rh, group=group, dropout_p=dropout_p),
+        name="flash_bwd_dmask",
         grid=(mb, mh, nq, nk, rb, rh),
         in_specs=specs,
         out_specs=dm_spec,
@@ -721,9 +726,9 @@ def check_eligibility(sq, sk, h, hk, d, *, causal, dropout_p,
         # divides by zero (ADVICE r3)
         raise ValueError(f"dropout_p must be in [0, 1), got {dropout_p}")
     if causal and sq > sk:
-        raise NotImplementedError("causal flash kernel needs sq <= sk")
+        raise ShapeNotCovered("causal flash kernel needs sq <= sk")
     if d not in (64, 128, 256) or h % hk or sq % 8 or sk % 8:
-        raise NotImplementedError("flash kernel shape constraints")
+        raise ShapeNotCovered("flash kernel shape constraints")
     bq, bk = _pick_blocks(sq, sk, d)
     if mask_grad or dropout_p > 0.0:
         # extra VMEM pressure in the backward kernels — the dmask path
@@ -761,12 +766,12 @@ def flash_attention_raw(q, k, v, causal: bool = False, mask=None,
         mb, mh, msq, msk = mask.shape
         if (msk != sk or mb not in (1, b) or mh not in (1, h)
                 or msq not in (1, sq)):
-            raise NotImplementedError(
+            raise ShapeNotCovered(
                 f"flash mask shape {mask.shape} not broadcastable to "
                 f"[{b},{h},{sq},{sk}]")
         if mask_grad:
             if msq != sq:
-                raise NotImplementedError(
+                raise ShapeNotCovered(
                     "trainable bias needs full Sq (no query broadcast)")
             out = flash_attention_bhsd_bias(qt, kt, vt, mask, causal,
                                             bq, bk, dropout_p, seed)

@@ -42,6 +42,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import ShapeNotCovered
+
 __all__ = [
     "kernels_active", "SLOT_KEYS", "fused_update_flat",
     "fused_update_reference", "update_flop_estimate",
@@ -96,9 +98,19 @@ def _clip_fold_f32(gf, clip_scale, grad_dtype):
     return (gf * clip_scale).astype(grad_dtype).astype(jnp.float32)
 
 
-def _update_math(kind, hp, pf, gf, slots, lr, step_f):
+def _bias_corrections(kind, hp, step_f):
+    """Adam's ``1 - beta ** t`` pair (None for the other kinds).  Scalars,
+    so they are computed ONCE outside the kernel body and ride its SMEM
+    scalar operand: Mosaic has no lowering for a scalar ``math.powf``."""
+    if kind != "adam":
+        return None
+    return 1 - hp["beta1"] ** step_f, 1 - hp["beta2"] ** step_f
+
+
+def _update_math(kind, hp, pf, gf, slots, lr, bias_corr):
     """The single source of optimizer math: called by the Pallas kernel
-    body and the reference path with the same f32 operands.  Mirrors
+    body and the reference path with the same f32 operands (``bias_corr``
+    from ``_bias_corrections``).  Mirrors
     ``Optimizer.apply_gradients``'s per-leaf ``upd()`` op-for-op (note:
     like that path, L1Decay is applied in its L2 form — the compiled
     path has never special-cased L1)."""
@@ -119,15 +131,14 @@ def _update_math(kind, hp, pf, gf, slots, lr, step_f):
         b1, b2, eps = hp["beta1"], hp["beta2"], hp["epsilon"]
         m = b1 * slots["moment1"] + (1 - b1) * gf
         v = b2 * slots["moment2"] + (1 - b2) * jnp.square(gf)
-        bc1 = 1 - b1 ** step_f
-        bc2 = 1 - b2 ** step_f
+        bc1, bc2 = bias_corr
         mhat = m / bc1
         vhat = v / bc2
         new_p = pf - lr * mhat / (jnp.sqrt(vhat) + eps)
         if wd and hp.get("decoupled", False):
             new_p = new_p - lr * wd * pf
         return new_p, {"moment1": m, "moment2": v}
-    raise NotImplementedError(f"no fused update for optimizer kind {kind!r}")
+    raise ShapeNotCovered(f"no fused update for optimizer kind {kind!r}")
 
 
 def fused_update_reference(kind, p, g, slots, *, lr, step_f, clip_scale,
@@ -140,7 +151,9 @@ def fused_update_reference(kind, p, g, slots, *, lr, step_f, clip_scale,
     if clip_scale is not None:
         gf = _clip_fold_f32(gf, clip_scale, g.dtype)
     pf = p.astype(jnp.float32)
-    new_p, new_slots = _update_math(kind, hyper, pf, gf, slots, lr, step_f)
+    new_p, new_slots = _update_math(
+        kind, hyper, pf, gf, slots, lr,
+        _bias_corrections(kind, hyper, step_f))
     return new_p.astype(p.dtype), new_slots
 
 
@@ -153,13 +166,13 @@ def _opt_kernel_body(kind, hp, has_clip, slot_keys, scal_ref, p_ref, g_ref,
     slot_in = refs[:n]
     outs = refs[n:]
     lr = scal_ref[0]
-    step_f = scal_ref[1]
     gf = g_ref[...].astype(jnp.float32)
     if has_clip:
-        gf = _clip_fold_f32(gf, scal_ref[2], g_ref.dtype)
+        gf = _clip_fold_f32(gf, scal_ref[1], g_ref.dtype)
     pf = p_ref[...].astype(jnp.float32)
     slots = {k: slot_in[i][...] for i, k in enumerate(slot_keys)}
-    new_p, new_slots = _update_math(kind, hp, pf, gf, slots, lr, step_f)
+    new_p, new_slots = _update_math(kind, hp, pf, gf, slots, lr,
+                                    (scal_ref[2], scal_ref[3]))
     outs[0][...] = new_p.astype(outs[0].dtype)
     for i, k in enumerate(slot_keys):
         outs[1 + i][...] = new_slots[k]
@@ -172,7 +185,7 @@ def _fused_update_kernel(kind, p, g, slots, *, lr, step_f, clip_scale,
     the clipped f32 grad and the new param/moments are produced
     in-register, and the results overwrite the inputs in the same pass."""
     if p.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
-        raise NotImplementedError(f"fused update: dtype {p.dtype}")
+        raise ShapeNotCovered(f"fused update: dtype {p.dtype}")
     slot_keys = SLOT_KEYS[kind]
     n = p.size
     tile = _OPT_TILE_ROWS * _LANES
@@ -186,16 +199,17 @@ def _fused_update_kernel(kind, p, g, slots, *, lr, step_f, clip_scale,
 
     p2, g2 = prep(p), prep(g)
     s2 = [prep(slots[k]) for k in slot_keys]
+    bias_corr = _bias_corrections(kind, hyper, step_f) or (1.0, 1.0)
     scal = jnp.stack([
-        jnp.asarray(lr, jnp.float32),
-        jnp.asarray(step_f, jnp.float32),
-        jnp.asarray(clip_scale if clip_scale is not None else 1.0,
-                    jnp.float32)])
+        jnp.asarray(x, jnp.float32)
+        for x in (lr, 1.0 if clip_scale is None else clip_scale,
+                  *bias_corr)])
     blk = pl.BlockSpec((_OPT_TILE_ROWS, _LANES), lambda i: (i, 0))
     n_in = 2 + len(slot_keys)
     outs = pl.pallas_call(
         functools.partial(_opt_kernel_body, kind, hyper,
                           clip_scale is not None, slot_keys),
+        name=f"fused_update_{kind}",
         grid=(p2.shape[0] // _OPT_TILE_ROWS,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [blk] * n_in,
@@ -229,7 +243,7 @@ def fused_update_flat(kind, p, g, slots, *, lr, step_f, clip_scale, hyper):
             return _fused_update_kernel(kind, p, g, slots, lr=lr,
                                         step_f=step_f,
                                         clip_scale=clip_scale, hyper=hyper)
-        except NotImplementedError:
+        except ShapeNotCovered:     # param dtype / optimizer kind
             pass
     return fused_update_reference(kind, p, g, slots, lr=lr, step_f=step_f,
                                   clip_scale=clip_scale, hyper=hyper)
@@ -334,6 +348,7 @@ def _add_norm_call(body, x, residual, weight, bias, out_dt, tile_r):
         in_specs.append(wblk)
     h, out = pl.pallas_call(
         body,
+        name="add_norm",
         grid=(rows // tile_r,),
         in_specs=in_specs,
         out_specs=[blk, blk],
@@ -508,6 +523,7 @@ def _matmul_rope_k(x, w, cos, sin, n_heads, head_dim, interleaved):
     s_blocks = s // tile_r
     out = pl.pallas_call(
         functools.partial(_mmr_kernel_body, head_dim // 2),
+        name="matmul_rope",
         grid=(rows // tile_r, n_heads),
         in_specs=[
             pl.BlockSpec((tile_r, hidden), lambda i, j: (i, 0)),

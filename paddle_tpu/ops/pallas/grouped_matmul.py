@@ -100,6 +100,7 @@ def _gmm_call(lhs, w, tile_expert, *, transpose_w, tm, tc, tj,
     nm, nj, nc = m // tm, j_dim // tj, lhs.shape[1] // tc
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, nc=nc, transpose_w=transpose_w),
+        name="gmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nm, nj, nc),
@@ -171,6 +172,7 @@ def _gmm_glu_call(lhs, wg, wu, tile_expert, *, tm, tc, tj, save_pre,
                               wg)] * 2
     outs = pl.pallas_call(
         functools.partial(_gmm_glu_kernel, nc=nc, save_pre=save_pre),
+        name="gmm_glu",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nm, nj, nc),
@@ -282,6 +284,7 @@ def _gmm_dw_call(lhs, dout, tile_expert, counts, num_experts, *, tm, tk,
     nm, nk, nn = m // tm, k // tk, n // tn
     dw = pl.pallas_call(
         functools.partial(_gmm_dw_kernel, nm=nm),
+        name="gmm_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             # m innermost: each (e, kk, j) output block is one contiguous

@@ -39,7 +39,10 @@ per-sequence descriptors ``(q_start, q_len, kv_len)`` — a decode slot
 contributes one query row (q_len == 1), a prefill chunk up to
 ``page_size`` rows, all landing inside ONE page (the engine chunks
 prompts at page boundaries, so ``kv_len % P + q_len <= P`` holds per
-descriptor).  The grid is (descriptor, kv-head); each step streams that
+descriptor).  Each descriptor's P query rows and (page-aligned) new K/V
+rows are gathered by XLA into per-descriptor blocks that reach the
+kernel through plain BlockSpecs; the only manual DMAs are whole pages.
+The grid is (descriptor, kv-head); each step streams that
 sequence's pages through the same double-buffered pipeline, substitutes
 the chunk's freshly-projected K/V rows in registers (quantizing them
 per row first in int8 mode), applies the causal-within-chunk mask
@@ -58,11 +61,12 @@ so under GSPMD each shard's kernel dispatch sees a self-contained
 problem — KVH/tp heads of EVERY page, with the (sequence, kv-head)
 grid partitioning trivially along its second axis and zero cross-chip
 traffic inside the kernel (page tables and seq_lens are replicated
-scalars/int32 vectors).  Nothing in this file needs a mesh: a
-``pallas_call`` is opaque to GSPMD, so the partitioning happens at the
-engine-program level via ``with_sharding_constraint`` on the kernel's
-operands (pools constrained on KVH, q/k_new/v_new on the head dim),
-which makes XLA shard the dispatch rather than the kernel body.  The
+scalars/int32 vectors).  Nothing in this file needs a mesh, but GSPMD
+cannot partition a Mosaic call ("wrap the call in a shard_map"): on
+the TPU the engine wraps each kernel call in a ``shard_map`` over the
+tp axis (``TPShardings.per_shard``: pools sharded on KVH, q/k_new/v_new
+on the head dim, descriptors replicated), so every shard runs the
+kernel body on its own heads.  The
 per-token scale pools ride the same KVH sharding, so the int8 path's
 ~2× HBM saving multiplies the tp capacity win instead of fighting it.
 The jnp reference paths below are likewise head-parallel by
@@ -308,8 +312,8 @@ def paged_attention_raw(q, k_pages, v_pages, page_table, seq_lens,
                      lambda b_, h_, pt, ln: (b_, h_, 0, 0)),
         # page pools stay in HBM; the kernel streams pages with
         # manual double-buffered async copies
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch = [
         pltpu.VMEM((_NBUF, page_size, d), k_pages.dtype),
@@ -318,13 +322,14 @@ def paged_attention_raw(q, k_pages, v_pages, page_table, seq_lens,
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                     pl.BlockSpec(memory_space=pltpu.ANY)]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                     pl.BlockSpec(memory_space=pl.ANY)]
         scratch += [pltpu.VMEM((_NBUF, 1, page_size), jnp.float32),
                     pltpu.VMEM((_NBUF, 1, page_size), jnp.float32)]
         operands += [k_scales, v_scales]
     out = pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -459,14 +464,14 @@ def paged_decode_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
                      lambda b_, h_, pt, ln: (b_, 0, 0)),
         pl.BlockSpec((1, kvh, d),
                      lambda b_, h_, pt, ln: (b_, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     out_specs = [
         pl.BlockSpec((1, 1, g, d),
                      lambda b_, h_, pt, ln: (b_, h_, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch = [
         pltpu.VMEM((_NBUF, page_size, d), k_pages.dtype),
@@ -489,10 +494,10 @@ def paged_decode_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
     ]
     aliases = {5: 1, 6: 2}
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                     pl.BlockSpec(memory_space=pltpu.ANY)]
-        out_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                      pl.BlockSpec(memory_space=pltpu.ANY)]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                     pl.BlockSpec(memory_space=pl.ANY)]
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)]
         scratch += [pltpu.VMEM((_NBUF, 1, page_size), jnp.float32),
                     pltpu.VMEM((_NBUF, 1, page_size), jnp.float32),
                     pltpu.VMEM((2, 1, page_size), jnp.float32)]
@@ -504,6 +509,7 @@ def paged_decode_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
         aliases = {5: 1, 6: 2, 7: 3, 8: 4}
     outs = pl.pallas_call(
         kernel,
+        name="paged_decode_append",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, kvh),
@@ -777,32 +783,29 @@ def _stream_pages_ragged(pt_ref, s_i, h, q2, k_hbm, v_hbm, k_scr, v_scr,
     return l, acc, (kmod, vmod)
 
 
-def _ragged_kernel(qs_ref, ql_ref, kl_ref, pt_ref, q_hbm, kn_hbm,
-                   vn_hbm, k_in, v_in, *rest,
+def _ragged_kernel(ql_ref, kl_ref, pt_ref, q_ref, kn_ref, vn_ref,
+                   k_in, v_in, *rest,
                    scale, page_size, maxp, quantized):
     if quantized:
         (ks_in, vs_in, o_ref, k_out, v_out, ks_out, vs_out,
-         q_scr, kn_scr, vn_scr, k_scr, v_scr, w_scr, qsem, sem, wsem,
+         k_scr, v_scr, w_scr, sem, wsem,
          ks_scr, vs_scr, ws_scr) = rest
         quant = (ks_in, vs_in, ks_scr, vs_scr)
     else:
-        (o_ref, k_out, v_out,
-         q_scr, kn_scr, vn_scr, k_scr, v_scr, w_scr, qsem, sem,
-         wsem) = rest
+        (o_ref, k_out, v_out, k_scr, v_scr, w_scr, sem, wsem) = rest
         quant = None
     s_i, h = pl.program_id(0), pl.program_id(1)
-    q_start = qs_ref[s_i]
     q_len = ql_ref[s_i]
     kv_len = kl_ref[s_i]
     P = page_size
-    g = q_scr.shape[1]
-    d = q_scr.shape[2]
+    d = q_ref.shape[3]
+    g = q_ref.shape[2] // P
 
     @pl.when(q_len == 0)
     def _():
         # unused descriptor: zero its output block so the flat-row
         # gather never reads uninitialized memory
-        o_ref[0, :, 0] = jnp.zeros((P, g, d), o_ref.dtype)
+        o_ref[0, 0] = jnp.zeros(o_ref.shape[2:], o_ref.dtype)
 
     @pl.when(q_len > 0)
     def _():
@@ -811,33 +814,17 @@ def _ragged_kernel(qs_ref, ql_ref, kl_ref, pt_ref, q_hbm, kn_hbm,
         ap = kv_len // P                    # the ONE page this chunk
         base = kv_len - ap * P              # fills, from row ``base``
 
-        # q/k_new/v_new are front-padded by P rows, so these FIXED-size
-        # row copies take any dynamic start: q scratch row j is flat
-        # row q_start + j; the k/v scratch is loaded shifted by -base
-        # so its row r aligns with append-page row r (rows outside
-        # [base, base + q_len) are dead and deselected below)
-        qc = pltpu.make_async_copy(
-            q_hbm.at[pl.ds(P + q_start, P), h], q_scr, qsem.at[0])
-        knc = pltpu.make_async_copy(
-            kn_hbm.at[pl.ds(P + q_start - base, P), h], kn_scr,
-            qsem.at[1])
-        vnc = pltpu.make_async_copy(
-            vn_hbm.at[pl.ds(P + q_start - base, P), h], vn_scr,
-            qsem.at[2])
-        for c in (qc, knc, vnc):
-            c.start()
-        for c in (qc, knc, vnc):
-            c.wait()
-
+        # q_ref holds this descriptor's P query rows x G heads; kn_ref /
+        # vn_ref its new K/V rows already shifted so block row r is
+        # append-page row r (rows outside [base, base + q_len) are dead
+        # and deselected below)
         riota = jax.lax.broadcasted_iota(jnp.int32, (P, 1), 0)
         rowsel = jnp.logical_and(riota >= base, riota < base + q_len)
-        knf = kn_scr[...]
-        vnf = vn_scr[...]
+        knf = kn_ref[0, 0]
+        vnf = vn_ref[0, 0]
         if quantized:
             # per-row absmax quantize of the appended rows in registers
             # (the quantize_rows_raw contract, like the decode kernel)
-            knf = knf.astype(jnp.float32)
-            vnf = vnf.astype(jnp.float32)
             kamax = jnp.maximum(
                 jnp.max(jnp.abs(knf), axis=1, keepdims=True), EPS)
             vamax = jnp.maximum(
@@ -869,12 +856,12 @@ def _ragged_kernel(qs_ref, ql_ref, kl_ref, pt_ref, q_hbm, kn_hbm,
         else:
             inject = (ap, rowsel, knf, vnf)
 
-        q2 = (q_scr[...].astype(jnp.float32) * scale).reshape(P * g, d)
+        q2 = q_ref[0, 0].astype(jnp.float32) * scale
         l, acc, wb = _stream_pages_ragged(
             pt_ref, s_i, h, q2, k_in, v_in, k_scr, v_scr, sem, kv_len,
             q_len, npages, P, g, inject, quant=quant)
         o = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        o_ref[0, :, 0] = o.reshape(P, g, d)
+        o_ref[0, 0] = o
 
         # write the modified append page (and its scale row) back with
         # full-page DMAs — same contract as the decode append kernel
@@ -945,68 +932,78 @@ def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
         scale = 1.0 / (d ** 0.5)
     quantized = k_scales is not None
 
-    pad = ((P, P), (0, 0), (0, 0), (0, 0))
-    qp = jnp.pad(q.reshape(t, kvh, g, d), pad)
-    knp = jnp.pad(k_new.astype(jnp.float32 if quantized
-                               else k_pages.dtype)[:, :, None, :],
-                  pad)[:, :, 0]
-    vnp = jnp.pad(v_new.astype(jnp.float32 if quantized
-                               else v_pages.dtype)[:, :, None, :],
-                  pad)[:, :, 0]
+    # per-descriptor row blocks, gathered by XLA and handed to the
+    # kernel through plain BlockSpecs: q block row j is flat row
+    # q_start + j; the k/v block is aligned to the append page (page
+    # row r <- flat row q_start - base + r).  Rows outside the
+    # descriptor are clamped reads, dead, and deselected in the kernel.
+    # (Round 10 fetched these rows with manual DMAs of one kv-head's
+    # slice at a dynamic row offset.  Mosaic refuses that slice for
+    # 16-bit operands, and for 32-bit ones it compiles but the copy
+    # never completes on a v5e when G is not a power of two — the
+    # kernel hung at 12/4 heads.  The only DMAs left are whole pages.)
+    ar = jnp.arange(P, dtype=jnp.int32)[None, :]
+    qs = q_start.astype(jnp.int32)[:, None]
+    base = (kv_len.astype(jnp.int32) % P)[:, None]
+    qrows = jnp.clip(qs + ar, 0, t - 1)
+    krows = jnp.clip(qs - base + ar, 0, t - 1)
+    qb = jnp.transpose(q.reshape(t, kvh, g, d)[qrows],
+                       (0, 2, 1, 3, 4)).reshape(s_max, kvh, P * g, d)
+    ndt = jnp.float32 if quantized else k_pages.dtype
+    knb = jnp.transpose(k_new.astype(ndt)[krows], (0, 2, 1, 3))
+    vnb = jnp.transpose(v_new.astype(ndt)[krows], (0, 2, 1, 3))
 
     kernel = functools.partial(_ragged_kernel, scale=scale,
                                page_size=P, maxp=maxp,
                                quantized=quantized)
+
+    def blk(rows):
+        return pl.BlockSpec((1, 1, rows, d),
+                            lambda s_, h_, ql, kl, pt: (s_, h_, 0, 0))
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),   # q (manual row DMA)
-        pl.BlockSpec(memory_space=pltpu.ANY),   # k_new
-        pl.BlockSpec(memory_space=pltpu.ANY),   # v_new
-        pl.BlockSpec(memory_space=pltpu.ANY),   # k_pages
-        pl.BlockSpec(memory_space=pltpu.ANY),   # v_pages
+        blk(P * g), blk(P), blk(P),
+        pl.BlockSpec(memory_space=pl.ANY),   # k_pages
+        pl.BlockSpec(memory_space=pl.ANY),   # v_pages
     ]
     out_specs = [
-        pl.BlockSpec((1, P, 1, g, d),
-                     lambda s_, h_, qs, ql, kl, pt: (s_, 0, h_, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        blk(P * g),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch = [
-        pltpu.VMEM((P, g, d), q.dtype),
-        pltpu.VMEM((P, d), knp.dtype),
-        pltpu.VMEM((P, d), vnp.dtype),
         pltpu.VMEM((_NBUF, P, d), k_pages.dtype),
         pltpu.VMEM((_NBUF, P, d), v_pages.dtype),
         pltpu.VMEM((2, P, d), k_pages.dtype),
-        pltpu.SemaphoreType.DMA((3,)),
         pltpu.SemaphoreType.DMA((_NBUF, 4 if quantized else 2)),
         pltpu.SemaphoreType.DMA((4 if quantized else 2,)),
     ]
-    operands = [qp, knp, vnp, k_pages, v_pages]
+    operands = [qb, knb, vnb, k_pages, v_pages]
     out_shape = [
-        out_sds((s_max, P, kvh, g, d), q.dtype, qp, k_pages, v_pages),
-        out_sds(k_pages.shape, k_pages.dtype, qp, k_pages, v_pages),
-        out_sds(v_pages.shape, v_pages.dtype, qp, k_pages, v_pages),
+        out_sds((s_max, kvh, P * g, d), q.dtype, qb, k_pages, v_pages),
+        out_sds(k_pages.shape, k_pages.dtype, qb, k_pages, v_pages),
+        out_sds(v_pages.shape, v_pages.dtype, qb, k_pages, v_pages),
     ]
-    # alias indices count the 4 scalar-prefetch operands first
-    aliases = {7: 1, 8: 2}
+    # alias indices count the 3 scalar-prefetch operands first
+    aliases = {6: 1, 7: 2}
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                     pl.BlockSpec(memory_space=pltpu.ANY)]
-        out_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                      pl.BlockSpec(memory_space=pltpu.ANY)]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                     pl.BlockSpec(memory_space=pl.ANY)]
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)]
         scratch += [pltpu.VMEM((_NBUF, 1, P), jnp.float32),
                     pltpu.VMEM((_NBUF, 1, P), jnp.float32),
                     pltpu.VMEM((2, 1, P), jnp.float32)]
         operands += [k_scales, v_scales]
         out_shape += [
-            out_sds(k_scales.shape, k_scales.dtype, qp, k_scales),
-            out_sds(v_scales.shape, v_scales.dtype, qp, v_scales),
+            out_sds(k_scales.shape, k_scales.dtype, qb, k_scales),
+            out_sds(v_scales.shape, v_scales.dtype, qb, v_scales),
         ]
-        aliases = {7: 1, 8: 2, 9: 3, 10: 4}
+        aliases = {6: 1, 7: 2, 8: 3, 9: 4}
     outs = pl.pallas_call(
         kernel,
+        name="ragged_paged_append_attend",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=3,
             grid=(s_max, kvh),
             in_specs=in_specs,
             out_specs=out_specs,
@@ -1014,14 +1011,11 @@ def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
         ),
         out_shape=out_shape,
         input_output_aliases=aliases,
-    )(q_start.astype(jnp.int32), q_len.astype(jnp.int32),
-      kv_len.astype(jnp.int32), page_tables.astype(jnp.int32),
-      *operands)
-    if quantized:
-        out, kp, vp, ks, vs = outs
-        return out.reshape(s_max, P, h, d), kp, vp, ks, vs
-    out, kp, vp = outs
-    return out.reshape(s_max, P, h, d), kp, vp
+    )(q_len.astype(jnp.int32), kv_len.astype(jnp.int32),
+      page_tables.astype(jnp.int32), *operands)
+    out = jnp.transpose(outs[0].reshape(s_max, kvh, P, g, d),
+                        (0, 2, 1, 3, 4)).reshape(s_max, P, h, d)
+    return (out,) + tuple(outs[1:])
 
 
 # standalone dispatch entry / in-graph body split, same contract as

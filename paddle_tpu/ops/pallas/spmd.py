@@ -40,8 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ...compat import shard_map as _compat_shard_map
-from ...compat import axis_size as _compat_axis_size
+
+from . import ShapeNotCovered
 
 __all__ = ["flash_attention_spmd", "flash_attention_spmd_ext",
            "active_wrap_axes"]
@@ -115,7 +115,7 @@ def _ctx_mesh(meta):
 def _perturbed(meta, seed):
     idx = jnp.int32(0)
     for a in meta.axes:
-        idx = idx * _compat_axis_size(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return seed + idx
 
 
@@ -140,7 +140,7 @@ def _fwd_shard_map(meta, q, k, v, mask, seed):
         args.append(mask)
     in_specs.append(P())
     args.append(seed)
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         body, mesh=_ctx_mesh(meta), axis_names=meta.axis_names,
         in_specs=tuple(in_specs),
         out_specs=(meta.qkv_spec, meta.lse_spec), check_vma=False)
@@ -184,7 +184,7 @@ def _bwd_shard_map(meta, q, k, v, mask, seed, out, lse, do):
     out_specs = [meta.qkv_spec] * 3
     if meta.mask_grad:
         out_specs.append(meta.mask_spec)
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         body, mesh=_ctx_mesh(meta), axis_names=meta.axis_names,
         in_specs=tuple(in_specs), out_specs=tuple(out_specs),
         check_vma=False)
@@ -262,11 +262,11 @@ def flash_attention_spmd(q, k, v, causal=False, mask=None,
         mb, mh, msq, msk = mask.shape
         if (msk != sk or mb not in (1, b) or mh not in (1, h)
                 or msq not in (1, sq)):
-            raise NotImplementedError(
+            raise ShapeNotCovered(
                 f"flash mask shape {mask.shape} not broadcastable to "
                 f"[{b},{h},{sq},{sk}]")
         if mask_grad and msq != sq:
-            raise NotImplementedError(
+            raise ShapeNotCovered(
                 "trainable bias needs full Sq (no query broadcast)")
         mask_spec = P(bspec if mb > 1 else None,
                       hspec if mh > 1 else None, None, None)

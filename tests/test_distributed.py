@@ -66,7 +66,7 @@ class TestShardTensor:
 class TestCollectives:
     def test_psum_inside_shard_map(self):
         from jax.sharding import Mesh
-        from paddle_tpu.compat import shard_map
+        from jax import shard_map
         hcg = fleet.init(strategy=make_strategy(dp=8))
         mesh = hcg.mesh
         group = hcg.get_data_parallel_group()
@@ -88,7 +88,7 @@ class TestCollectives:
         np.testing.assert_allclose(out.numpy(), t.numpy())
 
     def test_all_gather_traced(self):
-        from paddle_tpu.compat import shard_map
+        from jax import shard_map
         hcg = fleet.init(strategy=make_strategy(dp=8))
         group = hcg.get_data_parallel_group()
 

@@ -13,16 +13,6 @@ from paddle_tpu.inference import (Config, PagedKVCache, Predictor,
 from paddle_tpu.ops.pallas.paged_attention import (
     paged_attention_raw, paged_attention_reference, paged_write)
 
-# capability probes: jax 0.4.x lacks the Pallas interpret-mode context
-# manager and the jax.export module attribute — skip (not fail) the
-# tests that need them so tier-1 is green on environment, red on code
-needs_tpu_interpret = pytest.mark.skipif(
-    not hasattr(pltpu, "force_tpu_interpret_mode"),
-    reason="this jax has no pltpu.force_tpu_interpret_mode "
-           "(kernel-vs-reference parity runs on TPU-capable jax only)")
-needs_jax_export = pytest.mark.skipif(
-    not hasattr(jax, "export"),
-    reason="this jax has no jax.export (jit.save interchange format)")
 
 
 def _rand_pages(rng, kvh=2, n_pages=16, page=8, d=16):
@@ -81,7 +71,6 @@ class TestPagedAttentionKernel:
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
                                    atol=2e-5)
 
-    @needs_tpu_interpret
     def test_kernel_matches_reference_ragged(self):
         args = self._case([5, 16, 23, 1])
         with pltpu.force_tpu_interpret_mode():
@@ -90,7 +79,6 @@ class TestPagedAttentionKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
-    @needs_tpu_interpret
     def test_kernel_full_pages_and_single_token(self):
         args = self._case([32, 8], maxp=4)
         with pltpu.force_tpu_interpret_mode():
@@ -99,7 +87,6 @@ class TestPagedAttentionKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
-    @needs_tpu_interpret
     def test_fused_append_attend_matches_reference(self):
         """One kernel appends K/V and attends incl. the new token; the
         returned pools equal the scatter-written ones exactly."""
@@ -135,6 +122,67 @@ class TestPagedAttentionKernel:
                                       np.asarray(want_k))
         np.testing.assert_array_equal(np.asarray(got_v),
                                       np.asarray(want_v))
+
+    @pytest.mark.parametrize("pool", ["float32", "bfloat16", "int8"])
+    def test_ragged_append_attend_matches_reference(self, pool):
+        """The ragged mixed-step kernel (XLA row-block gather, the
+        BlockSpecs, the pool aliases, the output transpose) against the
+        per-row jnp reference, at the chip's page and head width with a
+        query group that is not a power of two.  Descriptors keep the
+        kernel's contract ``kv_len % P + q_len <= P``: decode rows
+        mid-page and on a page's last row, chunks that start a
+        sequence, start a page and end mid-page, and one unused slot."""
+        from paddle_tpu.ops.pallas.paged_attention import (
+            ragged_paged_append_attend_raw,
+            ragged_paged_append_attend_reference)
+        rng = np.random.default_rng(3)
+        kvh, g, d, page, n_pages, maxp = 2, 3, 128, 128, 16, 2
+        q_len = np.array([1, 1, 20, 0, 37, 5], np.int32)
+        kv_len = np.array([130, 127, 100, 0, 0, 128], np.int32)
+        q_start = (np.cumsum(q_len) - q_len).astype(np.int32)
+        n_desc, t = len(q_len), int(q_len.sum())
+        tables = 1 + rng.permutation(n_pages - 1)[:n_desc * maxp] \
+            .reshape(n_desc, maxp).astype(np.int32)   # page 0 is the pad
+        act = jnp.float32 if pool == "float32" else jnp.bfloat16
+        q = jnp.asarray(rng.normal(size=(t, kvh * g, d)), act)
+        kn = jnp.asarray(rng.normal(size=(t, kvh, d)), act)
+        vn = jnp.asarray(rng.normal(size=(t, kvh, d)), act)
+        shape = (kvh, n_pages, page, d)
+        if pool == "int8":
+            pools = tuple(jnp.asarray(rng.integers(-127, 128, shape),
+                                      jnp.int8) for _ in range(2))
+            scales = tuple(jnp.asarray(
+                rng.uniform(0.005, 0.03, (kvh, n_pages, 1, page)),
+                jnp.float32) for _ in range(2))
+        else:
+            pools = tuple(jnp.asarray(rng.normal(size=shape), pool)
+                          for _ in range(2))
+            scales = ()
+        positions = np.concatenate(
+            [np.arange(kv, kv + ql) for kv, ql in zip(kv_len, q_len)])
+        row_tables = np.repeat(tables, q_len, axis=0)
+        # jitted like every caller of it: inside a program XLA turns
+        # the row quantizer's ``absmax / 127`` into a multiply by the
+        # reciprocal, so an eager reference differs in the last bit of
+        # a few scales (and the int8 codes that sit on a half)
+        want = jax.jit(ragged_paged_append_attend_reference)(
+            q, *pools, kn, vn, jnp.asarray(positions),
+            jnp.asarray(row_tables), *scales)
+        with pltpu.force_tpu_interpret_mode():
+            got = ragged_paged_append_attend_raw(
+                q, *pools, kn, vn, jnp.asarray(q_start),
+                jnp.asarray(q_len), jnp.asarray(kv_len),
+                jnp.asarray(tables), *scales)
+        blocks = np.asarray(got[0].astype(jnp.float32))
+        assert not blocks[3].any()                 # the unused slot
+        flat = np.concatenate([blocks[s, :n] for s, n in enumerate(q_len)])
+        ref = np.asarray(want[0].astype(jnp.float32))
+        # f32: accumulation order only; bf16 outputs: one rounding
+        tol = 2e-5 if pool == "float32" else 2.0 ** -7
+        np.testing.assert_allclose(flat, ref, rtol=tol, atol=tol)
+        for a, b in zip(got[1:], want[1:]):        # pools (and scales)
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_paged_write_places_token(self):
         rng = np.random.default_rng(1)
@@ -201,7 +249,6 @@ class TestPagedKVCache:
 
 
 class TestPredictor:
-    @needs_jax_export
     def test_save_then_serve(self, tmp_path):
         paddle.seed(0)
         net = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 4))

@@ -35,16 +35,8 @@ def _oracle(q, k, v, causal):
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)
 
 
-def _tpu_interpret():
-    # jax 0.4.x lacks the context manager — skip (environment), don't fail
-    if not hasattr(pltpu, "force_tpu_interpret_mode"):
-        pytest.skip("this jax has no pltpu.force_tpu_interpret_mode "
-                    "(kernel-vs-reference parity needs TPU-capable jax)")
-    return pltpu.force_tpu_interpret_mode()
-
-
 def _run(fn, *args):
-    with _tpu_interpret():
+    with pltpu.force_tpu_interpret_mode():
         return fn(*args)
 
 
@@ -157,7 +149,7 @@ def test_flash_masked_fwd_matches_oracle(mask_shape):
     mask = jnp.asarray(np.where(
         rng.uniform(size=mask_shape) < 0.25, -1e30, 0.0
     ).astype(np.float32))
-    with _tpu_interpret():
+    with pltpu.force_tpu_interpret_mode():
         got = flash_attention_raw(q, k, v, causal=False, mask=mask)
     want = _oracle_masked(q, k, v, mask, causal=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -180,7 +172,7 @@ def test_flash_masked_grads_match_oracle():
     def loss_oracle(q, k, v):
         return jnp.sum(_oracle_masked(q, k, v, mask, causal=True) ** 2)
 
-    with _tpu_interpret():
+    with pltpu.force_tpu_interpret_mode():
         g1 = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss_oracle, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
@@ -197,7 +189,7 @@ def test_flash_gqa_bwd_outputs_kv_head_granular():
     k = jnp.asarray(rng.normal(size=(1, 2, 32, 64)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(1, 2, 32, 64)).astype(np.float32))
     do = jnp.ones((1, 8, 32, 64), jnp.float32)
-    with _tpu_interpret():
+    with pltpu.force_tpu_interpret_mode():
         out, lse = _fwd(q, k, v, causal=False, bq=32, bk=32)
         dq, dk, dv = _bwd_impl(q, k, v, out, lse, do, causal=False,
                                bq=32, bk=32)
@@ -355,21 +347,14 @@ def test_sdpa_trainable_bias_gets_real_grads():
         return np.asarray(bias.grad.numpy())
 
     from paddle_tpu.runtime import device as dev_mod
-    import paddle_tpu.nn.functional as F_mod
-    from jax.experimental.pallas import tpu as pltpu_
-    if not hasattr(pltpu_, "force_tpu_interpret_mode"):
-        pytest.skip("this jax has no pltpu.force_tpu_interpret_mode "
-                    "(kernel-vs-reference parity needs TPU-capable jax)")
 
     saved = dev_mod.is_compiled_with_tpu
     try:
         dev_mod.is_compiled_with_tpu = lambda: True
-        F_mod.is_compiled_with_tpu = lambda: True
-        with pltpu_.force_tpu_interpret_mode():
+        with pltpu.force_tpu_interpret_mode():
             g_kernel = grads(force_jnp=False)
     finally:
         dev_mod.is_compiled_with_tpu = saved
-        F_mod.is_compiled_with_tpu = saved
     g_ref = grads(force_jnp=True)
     assert np.abs(g_kernel).max() > 0
     np.testing.assert_allclose(g_kernel, g_ref, atol=2e-4, rtol=2e-3)

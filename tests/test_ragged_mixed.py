@@ -16,8 +16,9 @@ Contracts under test:
   default scheduler, first-token bookkeeping moves to delivery, a
   mid-prefill request migrates policy-only, and a runtime
   ``prefill_token_budget`` of 0 cannot livelock the engine;
-* the Pallas kernel itself mirrors the jnp reference bit-for-bit
-  (TPU-gated; the CPU suite exercises the reference path end-to-end);
+* (the Pallas kernel's own parity with the jnp reference is an
+  interpret-mode test beside the other paged kernels,
+  ``test_inference.py::TestPagedAttentionKernel``);
 * a tier-1 budget guard keeps this module's fast footprint flat.
 
 Everything runs JAX_PLATFORMS=cpu on the tiny llama config.
@@ -320,50 +321,6 @@ def test_sched_requires_unified_engine(model):
     from paddle_tpu.common.errors import EnforceError
     with pytest.raises(EnforceError):
         Scheduler(_mk(model, unified_step=False), chunked_prefill=True)
-
-
-# -- kernel vs reference (TPU only; CPU runs the reference end-to-end) ---------
-@pytest.mark.skipif(
-    __import__("jax").devices()[0].platform != "tpu",
-    reason="Pallas kernel path needs a TPU; CPU serves the jnp "
-           "reference, whose parity the engine suite above locks")
-def test_kernel_matches_reference_tpu():
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.paged_attention import (
-        ragged_paged_append_attend, ragged_paged_append_attend_reference)
-    rng = np.random.default_rng(0)
-    kvh, g, d, page, npages = 1, 2, 64, 8, 16
-    descs = [(0, 1, 11), (1, 1, 4), (2, 5, 9)]     # 2 decode + chunk
-    T = sum(q for _, q, _ in descs)
-    q = jnp.asarray(rng.standard_normal((T, kvh * g, d)), jnp.float32)
-    kn = jnp.asarray(rng.standard_normal((T, kvh, d)), jnp.float32)
-    vn = jnp.asarray(rng.standard_normal((T, kvh, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((kvh, npages, page, d)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((kvh, npages, page, d)),
-                     jnp.float32)
-    maxp = 4
-    tables = np.zeros((len(descs), maxp), np.int32)
-    for s in range(len(descs)):
-        tables[s] = rng.choice(np.arange(1, npages), maxp, replace=False)
-    q_start = np.array([0, 1, 2], np.int32)
-    q_len = np.array([1, 1, 5], np.int32)
-    kv_len = np.array([10, 3, 4], np.int32)        # pre-append lens
-    positions = np.concatenate([np.arange(kv, kv + ql)
-                                for (kv, ql) in zip(kv_len, q_len)])
-    row_tables = np.concatenate([np.repeat(tables[s:s + 1], ql, 0)
-                                 for s, ql in enumerate(q_len)])
-    blocks, k1, v1 = ragged_paged_append_attend(
-        q, kp.copy(), vp.copy(), kn, vn,
-        jnp.asarray(q_start), jnp.asarray(q_len),
-        jnp.asarray(kv_len), jnp.asarray(tables))
-    flat = jnp.concatenate(
-        [blocks[s, :ql] for s, ql in enumerate(q_len)], axis=0)
-    ref, k2, v2 = ragged_paged_append_attend_reference(
-        q, kp.copy(), vp.copy(), kn, vn,
-        jnp.asarray(positions), jnp.asarray(row_tables))
-    assert jnp.array_equal(flat, ref)
-    assert jnp.array_equal(k1, k2) and jnp.array_equal(v1, v2)
 
 
 # -- tier-1 budget guard -------------------------------------------------------
