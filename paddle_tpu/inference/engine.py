@@ -113,6 +113,7 @@ from ..observability import capsule as _capsule
 from ..observability import health as _health
 from ..observability import introspection as _insp
 from ..observability import tracing as _tracing
+from ..observability.tracing import phase as _phase
 from ..profiler import RecordEvent
 from . import sampling as _sampling
 from .paged_cache import PagedKVCache
@@ -1620,6 +1621,16 @@ class LLMEngine:
             "requests": reg.counter(
                 "llm_engine_requests_total",
                 "Requests admitted.", lbl).labels(eid),
+            "steps": reg.counter(
+                "llm_engine_steps_total",
+                "step() calls that dispatched a program (one mixed "
+                "step or one decode window); generated tokens / this "
+                "= tokens per step.", lbl).labels(eid),
+            "step_prefill_tokens": reg.counter(
+                "llm_engine_step_prefill_tokens_total",
+                "Prompt tokens CONSUMED by unified steps (prefill "
+                "chunks packed), where llm_engine_prompt_tokens_total "
+                "counts whole prompts at admission.", lbl).labels(eid),
             "aborted": reg.counter(
                 "llm_engine_aborted_total",
                 "Requests cancelled via abort() before finishing "
@@ -2115,7 +2126,7 @@ class LLMEngine:
             out.append((toks, a))
         return out
 
-    def _step_spec(self) -> Dict[object, List[int]]:
+    def _step_spec(self, sp) -> Dict[object, List[int]]:
         """The speculative decode window: draft-propose ``k_run``
         tokens per active request, verify them all in ONE ragged
         target dispatch, deliver the accepted prefix plus the
@@ -2130,96 +2141,101 @@ class LLMEngine:
         import jax
 
         if self._prefilling:
-            return self._step_mixed()
+            return self._step_mixed(sp)
         if not self._active:
             return {}
-        batch = list(self._active)
-        for req in batch:
-            if req.draft_slot is None:
-                self._spec_attach(req)
-        # runtime draft length: never draft past the tightest budget
-        # (the window delivers at most k_run + 1 <= remaining + 1
-        # tokens; the merge loop truncates the last one exactly like a
-        # plain multi-step window)
-        k_run = min([self.spec_k] +
-                    [r.max_new - len(r.out) for r in batch])
-        k_run = max(k_run, 1)
-        self._key, sub = jax.random.split(self._key)
-        rows = [{"slot": r.slot, "dslot": r.draft_slot,
-                 "last": r.out[-1],
-                 "cur": len(r.prompt) + len(r.out) - 1,
-                 "seq": list(r.prompt) + r.out, "row": i}
-                for i, r in enumerate(batch)]
-        t_win = time.perf_counter()
-        span = _tracing.span("engine.spec_window")
-        span.set_attr("rows", len(batch))
-        span.set_attr("k_run", k_run)
-        try:
-            with RecordEvent("llm_engine.decode"):
-                results = self._spec_window(rows, sub, k_run)
-        finally:
-            span.end()
-        dt_win = time.perf_counter() - t_win
+        with _phase("engine.step.plan"):
+            batch = list(self._active)
+            for req in batch:
+                if req.draft_slot is None:
+                    self._spec_attach(req)
+            # runtime draft length: never draft past the tightest
+            # budget (the window delivers at most k_run + 1 <=
+            # remaining + 1 tokens; the merge loop truncates the last
+            # one exactly like a plain multi-step window)
+            k_run = min([self.spec_k] +
+                        [r.max_new - len(r.out) for r in batch])
+            k_run = max(k_run, 1)
+        sp.set_metadata(decode_slots=len(batch), prefill_tokens=0,
+                        nsteps=k_run, path="spec")
+        with _phase("engine.step.pack"):
+            rows = [{"slot": r.slot, "dslot": r.draft_slot,
+                     "last": r.out[-1],
+                     "cur": len(r.prompt) + len(r.out) - 1,
+                     "seq": list(r.prompt) + r.out, "row": i}
+                    for i, r in enumerate(batch)]
+        # the draft-propose / verify / accept chain packs, dispatches
+        # and reads back several times inside _spec_window; it is not
+        # divided further (no cell runs it)
+        with _phase("engine.step.launch"):
+            self._key, sub = jax.random.split(self._key)
+            t_win = time.perf_counter()
+            results = self._spec_window(rows, sub, k_run)
+            dt_win = time.perf_counter() - t_win
 
-        out = {}
-        accepted = {}
-        for i, req in enumerate(batch):
-            toks, _a = results[i]
-            accepted[req.rid] = int(_a)
-            new_toks = []
-            for tok in toks:
-                if req.done:
-                    break
-                req.out.append(tok)
-                new_toks.append(tok)
-                if (req.eos is not None and tok == req.eos) or \
-                        len(req.out) >= req.max_new:
-                    req.done = True
-                    self.cache.release(req.slot)
-                    self._spec_release(req)
-                    self._active.remove(req)
-            if new_toks:
-                out[req.rid] = new_toks
-        delivered = max((len(v) for v in out.values()), default=0)
-        self.last_window_steps = delivered
+        with _phase("engine.step.merge"):
+            out = {}
+            accepted = {}
+            for i, req in enumerate(batch):
+                toks, _a = results[i]
+                accepted[req.rid] = int(_a)
+                new_toks = []
+                for tok in toks:
+                    if req.done:
+                        break
+                    req.out.append(tok)
+                    new_toks.append(tok)
+                    if (req.eos is not None and tok == req.eos) or \
+                            len(req.out) >= req.max_new:
+                        req.done = True
+                        self.cache.release(req.slot)
+                        self._spec_release(req)
+                        self._active.remove(req)
+                if new_toks:
+                    out[req.rid] = new_toks
+            delivered = max((len(v) for v in out.values()), default=0)
+            self.last_window_steps = delivered
 
-        n_prop = len(batch) * k_run
-        n_acc = sum(a for (_, a) in results)
-        st = self.spec_stats
-        st["windows"] += 1
-        st["proposed"] += n_prop
-        st["accepted"] += n_acc
-        st["delivered"] += sum(len(v) for v in out.values())
+        with _phase("engine.step.account"):
+            n_prop = len(batch) * k_run
+            n_acc = sum(a for (_, a) in results)
+            st = self.spec_stats
+            st["windows"] += 1
+            st["proposed"] += n_prop
+            st["accepted"] += n_acc
+            st["delivered"] += sum(len(v) for v in out.values())
 
-        cs = _capsule.get_capsule_store()
-        if cs.enabled and out:
-            cs.on_window(out, _sampling.key_fingerprint(sub),
-                         k_run + 1, delivered, "spec_window",
-                         rows={r.rid: i for i, r in enumerate(batch)},
-                         accepted=accepted)
-        # TPOT counts only DELIVERED tokens: dt_win amortizes over the
-        # window's real payoff, so a low-acceptance draft shows up as
-        # WORSE per-token latency, not phantom throughput (proposed-
-        # but-rejected tokens never touch the histogram or the AIMD
-        # SLO window)
-        if delivered:
-            _health.get_health().observe_tpot(dt_win / delivered,
-                                              n=delivered)
-        if self._metrics is not None:
-            m = self._metrics
+            cs = _capsule.get_capsule_store()
+            if cs.enabled and out:
+                cs.on_window(out, _sampling.key_fingerprint(sub),
+                             k_run + 1, delivered, "spec_window",
+                             rows={r.rid: i
+                                   for i, r in enumerate(batch)},
+                             accepted=accepted)
+            # TPOT counts only DELIVERED tokens: dt_win amortizes over
+            # the window's real payoff, so a low-acceptance draft shows
+            # up as WORSE per-token latency, not phantom throughput
+            # (proposed-but-rejected tokens never touch the histogram
+            # or the AIMD SLO window)
             if delivered:
-                m["tpot"].observe(dt_win / delivered, n=delivered)
-            m["generated_tokens"].inc(
-                sum(len(v) for v in out.values()))
-            m["queue_depth"].set(len(self._active))
-            m["occupancy"].set(len(batch) / self.max_seqs)
-            m["spec_proposed"].inc(n_prop)
-            m["spec_accepted"].inc(n_acc)
-            if st["proposed"]:
-                m["spec_rate"].set(st["accepted"] / st["proposed"])
-            for _, a in results:
-                m["spec_len"].observe(float(a))
-            self._record_compiles()
+                _health.get_health().observe_tpot(dt_win / delivered,
+                                                  n=delivered)
+            if self._metrics is not None:
+                m = self._metrics
+                if delivered:
+                    m["tpot"].observe(dt_win / delivered, n=delivered)
+                m["steps"].inc()
+                m["generated_tokens"].inc(
+                    sum(len(v) for v in out.values()))
+                m["queue_depth"].set(len(self._active))
+                m["occupancy"].set(len(batch) / self.max_seqs)
+                m["spec_proposed"].inc(n_prop)
+                m["spec_accepted"].inc(n_acc)
+                if st["proposed"]:
+                    m["spec_rate"].set(st["accepted"] / st["proposed"])
+                for _, a in results:
+                    m["spec_len"].observe(float(a))
+                self._record_compiles()
         return out
 
     # -- admission -------------------------------------------------------------
@@ -2434,14 +2450,22 @@ class LLMEngine:
         the speculative path (``_step_spec``): greedy streams stay
         bit-identical to plain decode, sampled streams stay
         distributionally exact — only the tokens-per-dispatch ratio
-        changes."""
-        if self._spec is not None:
-            return self._step_spec()
-        if self.unified_step:
-            return self._step_mixed()
-        return self._step_split()
+        changes.
 
-    def _step_split(self) -> Dict[object, List[int]]:
+        Whichever path runs, the call is ONE ``engine.step`` phase on
+        the profiler's clock (``observability.tracing.phase``) whose
+        leaves — ``engine.step.plan`` / ``.pack`` / ``.launch`` /
+        ``.wait`` / ``.moe_counts`` / ``.merge`` / ``.account`` —
+        cover every line of the path, so a capture shows which host
+        stretch the chip idled under."""
+        with _phase("engine.step") as sp:
+            if self._spec is not None:
+                return self._step_spec(sp)
+            if self.unified_step:
+                return self._step_mixed(sp)
+            return self._step_split(sp)
+
+    def _step_split(self, sp) -> Dict[object, List[int]]:
         """Decode up to ``steps_per_sync`` tokens for every active
         request in one device dispatch.  The host only
         syncs (EOS checks, admission window) once per call, so over a
@@ -2456,46 +2480,54 @@ class LLMEngine:
 
         if not self._active:
             return {}
-        batch = list(self._active)
-        n = len(batch)
-        nsteps = min([self.steps_per_sync] +
-                     [r.max_new - len(r.out) for r in batch])
-        nsteps = max(nsteps, 1)
-        # bucket the window to a power of two so ragged remaining
-        # budgets compile at most log2(steps_per_sync) decode programs
-        # (n_steps is a static jit arg), not one per distinct tail
-        while nsteps & (nsteps - 1):
-            nsteps &= nsteps - 1
-        # pad to max_seqs: continuous batching must keep ONE compiled
-        # shape as requests join/leave (dummy rows write into the
-        # reserved pad page 0 with len 0 and are discarded)
-        pad = self.max_seqs - n
-        slots = np.array([r.slot for r in batch])
-        tokens = np.array([r.out[-1] for r in batch] + [0] * pad,
-                          np.int32)
-        for s in slots:
-            self.cache.extend(int(s), nsteps)
-        lens = np.concatenate([self.cache.seq_lens[slots],
-                               np.zeros(pad, np.int32)])
-        tables = np.concatenate(
-            [self.cache.page_table[slots],
-             np.zeros((pad,) + self.cache.page_table.shape[1:],
-                      np.int32)])
-
-        self._key, sub = jax.random.split(self._key)
-        t_win = time.perf_counter()
-        with RecordEvent("llm_engine.decode"):
-            if self.scan_decode and nsteps > 1:
-                # on-device window: one while_loop program runs the
-                # whole window, exiting early once every row retired
-                # (EOS/budget tracked in-graph — same predicate as the
-                # merge loop below)
+        with _phase("engine.step.plan"):
+            batch = list(self._active)
+            n = len(batch)
+            nsteps = min([self.steps_per_sync] +
+                         [r.max_new - len(r.out) for r in batch])
+            nsteps = max(nsteps, 1)
+            # bucket the window to a power of two so ragged remaining
+            # budgets compile at most log2(steps_per_sync) decode
+            # programs (n_steps is a static jit arg), not one per
+            # distinct tail
+            while nsteps & (nsteps - 1):
+                nsteps &= nsteps - 1
+            slots = np.array([r.slot for r in batch])
+            for s in slots:
+                self.cache.extend(int(s), nsteps)
+        sp.set_metadata(decode_slots=n, prefill_tokens=0, nsteps=nsteps,
+                        path="split")
+        window = self.scan_decode and nsteps > 1
+        with _phase("engine.step.pack"):
+            # pad to max_seqs: continuous batching must keep ONE
+            # compiled shape as requests join/leave (dummy rows write
+            # into the reserved pad page 0 with len 0 and are
+            # discarded)
+            pad = self.max_seqs - n
+            tokens = np.array([r.out[-1] for r in batch] + [0] * pad,
+                              np.int32)
+            lens = np.concatenate([self.cache.seq_lens[slots],
+                                   np.zeros(pad, np.int32)])
+            tables = np.concatenate(
+                [self.cache.page_table[slots],
+                 np.zeros((pad,) + self.cache.page_table.shape[1:],
+                          np.int32)])
+            if window:
+                # EOS/budget tracked in-graph — same predicate as the
+                # merge loop below
                 eos_ids = np.full(self.max_seqs, -1, np.int32)
                 budgets = np.ones(self.max_seqs, np.int32)
                 for i, r in enumerate(batch):
                     if r.eos is not None:
                         eos_ids[i] = r.eos
                     budgets[i] = r.max_new - len(r.out)
+
+        with _phase("engine.step.launch"):
+            self._key, sub = jax.random.split(self._key)
+            t_win = time.perf_counter()
+            if window:
+                # on-device window: one while_loop program runs the
+                # whole window, exiting early once every row retired
                 res = _insp.watched_call(
                     "engine.decode_window", _paged_decode_window,
                     self._stack, self._norm_w, self._head_w,
@@ -2518,10 +2550,7 @@ class LLMEngine:
                 (toks, _, steps_d, self.cache.k_pages,
                  self.cache.v_pages, self.cache.k_scales,
                  self.cache.v_scales) = res[:7]
-                steps_done = int(jax.device_get(steps_d))
-                if self._arch is not None:
-                    self._note_expert_counts(
-                        res[7], n * self._arch.top_k * steps_done)
+                counts = res[7] if self._arch is not None else None
             else:
                 res = _insp.watched_call(
                     "engine.decode_step", _paged_decode_step,
@@ -2542,71 +2571,81 @@ class LLMEngine:
                     shardings=self._shardings, arch=self._arch)
                 (toks, self.cache.k_pages, self.cache.v_pages,
                  self.cache.k_scales, self.cache.v_scales) = res[:5]
-                if self._arch is not None:
-                    self._note_expert_counts(
-                        res[5], n * self._arch.top_k * nsteps)
-                steps_done = nsteps
-            self.cache.advance(slots, steps_done)
+                counts = res[5] if self._arch is not None else None
+        steps_done = nsteps
+        if window:
+            with _phase("engine.step.wait"):
+                steps_done = int(jax.device_get(steps_d))
+        if counts is not None:
+            with _phase("engine.step.moe_counts"):
+                self._note_expert_counts(
+                    counts, n * self._arch.top_k * steps_done)
+        with _phase("engine.step.wait"):
             # [steps_done, n]
             toks = np.asarray(jax.device_get(toks))[:steps_done, :n]
         dt_win = time.perf_counter() - t_win
-        self.last_window_steps = steps_done
 
-        # contract (ADVICE r3): with steps_per_sync > 1 a window emits
-        # up to nsteps tokens per request — return the LIST of new
-        # tokens per rid so streaming callers never lose intermediates
-        out = {}
-        for i, req in enumerate(batch):
-            new_toks = []
-            for j in range(steps_done):
-                if req.done:
-                    break
-                tok = int(toks[j, i])
-                req.out.append(tok)
-                new_toks.append(tok)
-                if (req.eos is not None and tok == req.eos) or \
-                        len(req.out) >= req.max_new:
-                    req.done = True
-                    self.cache.release(req.slot)
-                    self._spec_release(req)
-                    self._active.remove(req)
-            if new_toks:
-                out[req.rid] = new_toks
-        # capsule capture: one window record per captured rid — the
-        # forked window key anchors the in-window split_step chain, so
-        # replay reproduces the draws key for key
-        cs = _capsule.get_capsule_store()
-        if cs.enabled and out:
-            cs.on_window(out, _sampling.key_fingerprint(sub), nsteps,
-                         steps_done,
-                         "decode_window"
-                         if self.scan_decode and nsteps > 1
-                         else "decode_step",
-                         rows={r.rid: i for i, r in enumerate(batch)})
-        # TPOT counts only tokens actually DELIVERED to a stream: a
-        # request that retired mid-window stops contributing positions
-        # (the fixed window-boundary over-count), and the window's
-        # per-token wall time is wall / steps actually run
-        delivered = max((len(v) for v in out.values()), default=0)
-        if delivered:
-            _health.get_health().observe_tpot(dt_win / steps_done,
-                                              n=delivered)
-        if self._metrics is not None:
-            m = self._metrics
-            # ONE weighted observe per window: value is the wall time a
-            # stream waits per token, count advances by the window's
-            # DELIVERED token positions — O(1) recording however long
-            # the window
+        with _phase("engine.step.merge"):
+            self.cache.advance(slots, steps_done)
+            self.last_window_steps = steps_done
+            # contract (ADVICE r3): with steps_per_sync > 1 a window
+            # emits up to nsteps tokens per request — return the LIST
+            # of new tokens per rid so streaming callers never lose
+            # intermediates
+            out = {}
+            for i, req in enumerate(batch):
+                new_toks = []
+                for j in range(steps_done):
+                    if req.done:
+                        break
+                    tok = int(toks[j, i])
+                    req.out.append(tok)
+                    new_toks.append(tok)
+                    if (req.eos is not None and tok == req.eos) or \
+                            len(req.out) >= req.max_new:
+                        req.done = True
+                        self.cache.release(req.slot)
+                        self._spec_release(req)
+                        self._active.remove(req)
+                if new_toks:
+                    out[req.rid] = new_toks
+        with _phase("engine.step.account"):
+            # capsule capture: one window record per captured rid —
+            # the forked window key anchors the in-window split_step
+            # chain, so replay reproduces the draws key for key
+            cs = _capsule.get_capsule_store()
+            if cs.enabled and out:
+                cs.on_window(out, _sampling.key_fingerprint(sub), nsteps,
+                             steps_done,
+                             "decode_window" if window
+                             else "decode_step",
+                             rows={r.rid: i for i, r in enumerate(batch)})
+            # TPOT counts only tokens actually DELIVERED to a stream:
+            # a request that retired mid-window stops contributing
+            # positions (the fixed window-boundary over-count), and
+            # the window's per-token wall time is wall / steps
+            # actually run
+            delivered = max((len(v) for v in out.values()), default=0)
             if delivered:
-                m["tpot"].observe(dt_win / steps_done, n=delivered)
-            m["generated_tokens"].inc(
-                sum(len(v) for v in out.values()))
-            m["queue_depth"].set(len(self._active))
-            m["occupancy"].set(n / self.max_seqs)
-            self._record_compiles()
+                _health.get_health().observe_tpot(dt_win / steps_done,
+                                                  n=delivered)
+            if self._metrics is not None:
+                m = self._metrics
+                # ONE weighted observe per window: value is the wall
+                # time a stream waits per token, count advances by the
+                # window's DELIVERED token positions — O(1) recording
+                # however long the window
+                if delivered:
+                    m["tpot"].observe(dt_win / steps_done, n=delivered)
+                m["steps"].inc()
+                m["generated_tokens"].inc(
+                    sum(len(v) for v in out.values()))
+                m["queue_depth"].set(len(self._active))
+                m["occupancy"].set(n / self.max_seqs)
+                self._record_compiles()
         return out
 
-    def _step_mixed(self) -> Dict[object, List[int]]:
+    def _step_mixed(self, sp) -> Dict[object, List[int]]:
         """The ragged unified step: ONE ``_paged_mixed_step`` dispatch
         carries every active decode slot (1 row each — the slot→row
         map is compacted host-side, no padded dead slots) plus pending
@@ -2624,297 +2663,306 @@ class LLMEngine:
 
         if not self._active and not self._prefilling:
             return {}
-        P = self.cache.page_size
-        maxp = self.cache.page_table.shape[1]
-        t_cap = self.max_seqs + self._pf_budget_static
-        batch = list(self._active)
-        n = len(batch)
+        with _phase("engine.step.plan"):
+            P = self.cache.page_size
+            maxp = self.cache.page_table.shape[1]
+            t_cap = self.max_seqs + self._pf_budget_static
+            batch = list(self._active)
+            n = len(batch)
 
-        # prefill plan: (req, pos, chunk_len, first_row, descriptor).
-        # The runtime budget is clamped to the static one (T is fixed)
-        # and floored at 1 when only prefill is pending — a zero
-        # budget must not livelock has_work().
-        budget = max(0, min(int(self.prefill_token_budget),
-                            self._pf_budget_static))
-        if not batch and budget == 0:
-            budget = min(P, self._pf_budget_static)
-        # capacity-factor MoE defines its drop ranks per page-group =
-        # page chunk, so the planner must pack WHOLE chunks (a split
-        # chunk would rank differently than the split prefill path);
-        # floor the runtime budget to one chunk when only prefill is
-        # pending so a low budget can't livelock has_work()
-        whole_chunks = self._arch is not None and \
-            self._arch.capacity > 0
-        if whole_chunks and not batch:
-            budget = max(budget, min(P, self._pf_budget_static))
-        plan = []
-        finishing = []                        # (req, last_row)
-        cursor, desc_i, used = n, n, 0
-        stop = False
-        for req in self._prefilling:
-            plen = len(req.prompt)
-            pos = req.pf_pos
-            while pos < plen and used < budget:
-                chunk = min(P - pos % P, plen - pos)
-                if whole_chunks and used + chunk > budget:
-                    stop = True
+            # prefill plan: (req, pos, chunk_len, first_row,
+            # descriptor).  The runtime budget is clamped to the static
+            # one (T is fixed) and floored at 1 when only prefill is
+            # pending — a zero budget must not livelock has_work().
+            budget = max(0, min(int(self.prefill_token_budget),
+                                self._pf_budget_static))
+            if not batch and budget == 0:
+                budget = min(P, self._pf_budget_static)
+            # capacity-factor MoE defines its drop ranks per page-group
+            # = page chunk, so the planner must pack WHOLE chunks (a
+            # split chunk would rank differently than the split prefill
+            # path); floor the runtime budget to one chunk when only
+            # prefill is pending so a low budget can't livelock
+            # has_work()
+            whole_chunks = self._arch is not None and \
+                self._arch.capacity > 0
+            if whole_chunks and not batch:
+                budget = max(budget, min(P, self._pf_budget_static))
+            plan = []
+            finishing = []                        # (req, last_row)
+            cursor, desc_i, used = n, n, 0
+            stop = False
+            for req in self._prefilling:
+                plen = len(req.prompt)
+                pos = req.pf_pos
+                while pos < plen and used < budget:
+                    chunk = min(P - pos % P, plen - pos)
+                    if whole_chunks and used + chunk > budget:
+                        stop = True
+                        break
+                    cl = min(chunk, budget - used)
+                    plan.append((req, pos, cl, cursor, desc_i))
+                    pos += cl
+                    cursor += cl
+                    used += cl
+                    desc_i += 1
+                if pos >= plen:
+                    finishing.append((req, cursor - 1))
+                if stop or used >= budget:
                     break
-                cl = min(chunk, budget - used)
-                plan.append((req, pos, cl, cursor, desc_i))
-                pos += cl
-                cursor += cl
-                used += cl
-                desc_i += 1
-            if pos >= plen:
-                finishing.append((req, cursor - 1))
-            if stop or used >= budget:
-                break
-        if not batch and not plan:
-            return {}
+            if not batch and not plan:
+                return {}
 
-        if plan or n == 0:
-            nsteps = 1
-        else:
-            nsteps = min([self.steps_per_sync] +
-                         [r.max_new - len(r.out) for r in batch])
-            nsteps = max(nsteps, 1)
-            while nsteps & (nsteps - 1):
-                nsteps &= nsteps - 1
-        slots = np.array([r.slot for r in batch], np.int64)
-        for r in batch:
-            self.cache.extend(r.slot, nsteps)
+            if plan or n == 0:
+                nsteps = 1
+            else:
+                nsteps = min([self.steps_per_sync] +
+                             [r.max_new - len(r.out) for r in batch])
+                nsteps = max(nsteps, 1)
+                while nsteps & (nsteps - 1):
+                    nsteps &= nsteps - 1
+            slots = np.array([r.slot for r in batch], np.int64)
+            for r in batch:
+                self.cache.extend(r.slot, nsteps)
+        # ON-DEVICE window (pure decode by construction — prefill plans
+        # force nsteps == 1): the whole attend → sample → append chain
+        # runs as one while_loop program that exits as soon as every
+        # row has retired, syncing the host once
+        window = self.scan_decode and nsteps > 1
+        sp.set_metadata(decode_slots=n, prefill_tokens=used,
+                        nsteps=nsteps,
+                        path="window" if window else "mixed")
 
-        ids = np.zeros(t_cap, np.int32)
-        positions = np.zeros(t_cap, np.int32)
-        row_tables = np.zeros((t_cap, maxp), np.int32)
-        q_start = np.zeros(t_cap, np.int32)
-        q_len = np.zeros(t_cap, np.int32)
-        kv_len = np.zeros(t_cap, np.int32)
-        desc_tables = np.zeros((t_cap, maxp), np.int32)
-        # padding rows point at their own (q_len == 0) descriptor,
-        # whose kernel output block is zeroed — never garbage
-        desc_of_row = np.arange(t_cap, dtype=np.int32)
-        off_of_row = np.zeros(t_cap, np.int32)
-        if n:
-            ids[:n] = [r.out[-1] for r in batch]
-            lens = self.cache.seq_lens[slots]
-            positions[:n] = lens
-            row_tables[:n] = self.cache.page_table[slots]
-            q_start[:n] = np.arange(n)
-            q_len[:n] = 1
-            kv_len[:n] = lens
-            desc_tables[:n] = row_tables[:n]
-        for req, pos, cl, row0, d in plan:
-            tbl = self.cache.page_table[req.slot]
-            ids[row0:row0 + cl] = req.prompt[pos:pos + cl]
-            positions[row0:row0 + cl] = np.arange(pos, pos + cl)
-            row_tables[row0:row0 + cl] = tbl
-            q_start[d] = row0
-            q_len[d] = cl
-            kv_len[d] = pos
-            desc_tables[d] = tbl
-            desc_of_row[row0:row0 + cl] = d
-            off_of_row[row0:row0 + cl] = np.arange(cl)
+        with _phase("engine.step.pack"):
+            ids = np.zeros(t_cap, np.int32)
+            positions = np.zeros(t_cap, np.int32)
+            row_tables = np.zeros((t_cap, maxp), np.int32)
+            q_start = np.zeros(t_cap, np.int32)
+            q_len = np.zeros(t_cap, np.int32)
+            kv_len = np.zeros(t_cap, np.int32)
+            desc_tables = np.zeros((t_cap, maxp), np.int32)
+            # padding rows point at their own (q_len == 0) descriptor,
+            # whose kernel output block is zeroed — never garbage
+            desc_of_row = np.arange(t_cap, dtype=np.int32)
+            off_of_row = np.zeros(t_cap, np.int32)
+            if n:
+                ids[:n] = [r.out[-1] for r in batch]
+                lens = self.cache.seq_lens[slots]
+                positions[:n] = lens
+                row_tables[:n] = self.cache.page_table[slots]
+                q_start[:n] = np.arange(n)
+                q_len[:n] = 1
+                kv_len[:n] = lens
+                desc_tables[:n] = row_tables[:n]
+            for req, pos, cl, row0, d in plan:
+                tbl = self.cache.page_table[req.slot]
+                ids[row0:row0 + cl] = req.prompt[pos:pos + cl]
+                positions[row0:row0 + cl] = np.arange(pos, pos + cl)
+                row_tables[row0:row0 + cl] = tbl
+                q_start[d] = row0
+                q_len[d] = cl
+                kv_len[d] = pos
+                desc_tables[d] = tbl
+                desc_of_row[row0:row0 + cl] = d
+                off_of_row[row0:row0 + cl] = np.arange(cl)
+            if window:
+                eos_ids = np.full(t_cap, -1, np.int32)
+                budgets = np.ones(t_cap, np.int32)
+                for i, r in enumerate(batch):
+                    if r.eos is not None:
+                        eos_ids[i] = r.eos
+                    budgets[i] = r.max_new - len(r.out)
 
-        self._key, sub = jax.random.split(self._key)
-        key = sub
         toks_all = []
         steps_done = nsteps
+        with _phase("engine.step.launch"):
+            self._key, sub = jax.random.split(self._key)
+            key = sub
         t_win = time.perf_counter()
-        span = _tracing.span("engine.mixed_step")
-        span.set_attr("decode_slots", n)
-        span.set_attr("prefill_tokens", int(used))
-        span.set_attr("nsteps", nsteps)
-        try:
-            with RecordEvent("llm_engine.decode"):
-                if self.scan_decode and nsteps > 1:
-                    # ON-DEVICE window (pure decode by construction —
-                    # prefill plans force nsteps == 1): the whole
-                    # attend → sample → append chain runs as one
-                    # while_loop program that exits as soon as every
-                    # row has retired, syncing the host once
-                    eos_ids = np.full(t_cap, -1, np.int32)
-                    budgets = np.ones(t_cap, np.int32)
-                    for i, r in enumerate(batch):
-                        if r.eos is not None:
-                            eos_ids[i] = r.eos
-                        budgets[i] = r.max_new - len(r.out)
+        if window:
+            with _phase("engine.step.launch"):
+                res = _insp.watched_call(
+                    "engine.mixed_window", _paged_mixed_window,
+                    self._stack, self._norm_w, self._head_w,
+                    self._embed_w, self._rope,
+                    self.cache.k_pages, self.cache.v_pages,
+                    self.cache.k_scales, self.cache.v_scales,
+                    jnp.asarray(ids), jnp.asarray(positions),
+                    jnp.asarray(row_tables),
+                    jnp.asarray(q_start), jnp.asarray(q_len),
+                    jnp.asarray(kv_len),
+                    jnp.asarray(desc_tables),
+                    jnp.asarray(desc_of_row),
+                    jnp.asarray(off_of_row), key,
+                    jnp.int32(0),
+                    jnp.asarray(eos_ids),
+                    jnp.asarray(budgets), jnp.int32(n),
+                    eps=self.eps, kvh=self.kvh,
+                    head_dim=self.head_dim,
+                    transpose_head=self._tied,
+                    strategy=self.decode_strategy,
+                    top_k=self.top_k, top_p=self.top_p,
+                    temperature=self.temperature,
+                    n_steps=nsteps,
+                    shardings=self._shardings, arch=self._arch)
+                (toks_d, _, steps_d, self.cache.k_pages,
+                 self.cache.v_pages, self.cache.k_scales,
+                 self.cache.v_scales, key) = res[:8]
+            with _phase("engine.step.wait"):
+                steps_done = int(jax.device_get(steps_d))
+            if self._arch is not None:
+                with _phase("engine.step.moe_counts"):
+                    self._note_expert_counts(
+                        res[8], n * self._arch.top_k * steps_done)
+            with _phase("engine.step.wait"):
+                toks_np = np.asarray(jax.device_get(toks_d))
+            toks_all = [toks_np[j] for j in range(steps_done)]
+        else:
+            for si in range(nsteps):
+                with _phase("engine.step.launch"):
                     res = _insp.watched_call(
-                        "engine.mixed_window", _paged_mixed_window,
-                        self._stack, self._norm_w, self._head_w,
-                        self._embed_w, self._rope,
+                        "engine.mixed_step", _paged_mixed_step,
+                        self._stack, self._norm_w,
+                        self._head_w, self._embed_w,
+                        self._rope,
                         self.cache.k_pages, self.cache.v_pages,
-                        self.cache.k_scales, self.cache.v_scales,
-                        jnp.asarray(ids), jnp.asarray(positions),
+                        self.cache.k_scales,
+                        self.cache.v_scales,
+                        jnp.asarray(ids),
+                        jnp.asarray(positions),
                         jnp.asarray(row_tables),
-                        jnp.asarray(q_start), jnp.asarray(q_len),
+                        jnp.asarray(q_start),
+                        jnp.asarray(q_len),
                         jnp.asarray(kv_len),
                         jnp.asarray(desc_tables),
                         jnp.asarray(desc_of_row),
                         jnp.asarray(off_of_row), key,
                         jnp.int32(0),
-                        jnp.asarray(eos_ids),
-                        jnp.asarray(budgets), jnp.int32(n),
                         eps=self.eps, kvh=self.kvh,
                         head_dim=self.head_dim,
                         transpose_head=self._tied,
                         strategy=self.decode_strategy,
                         top_k=self.top_k, top_p=self.top_p,
                         temperature=self.temperature,
-                        n_steps=nsteps,
-                        shardings=self._shardings, arch=self._arch)
-                    (toks_d, _, steps_d, self.cache.k_pages,
-                     self.cache.v_pages, self.cache.k_scales,
-                     self.cache.v_scales, key) = res[:8]
-                    steps_done = int(jax.device_get(steps_d))
-                    if self._arch is not None:
+                        shardings=self._shardings,
+                        arch=self._arch)
+                    (nxt, self.cache.k_pages, self.cache.v_pages,
+                     self.cache.k_scales, self.cache.v_scales,
+                     key) = res[:6]
+                if self._arch is not None:
+                    with _phase("engine.step.moe_counts"):
+                        # live rows this dispatch: n decode slots + the
+                        # packed prefill tokens (used == 0 past the
+                        # first step — multi-step windows are pure
+                        # decode)
                         self._note_expert_counts(
-                            res[8],
-                            n * self._arch.top_k * steps_done)
-                    toks_np = np.asarray(jax.device_get(toks_d))
-                    toks_all = [toks_np[j] for j in range(steps_done)]
-                    if n:
-                        self.cache.advance(slots, steps_done)
-                else:
-                    for si in range(nsteps):
-                        res = _insp.watched_call(
-                            "engine.mixed_step", _paged_mixed_step,
-                            self._stack, self._norm_w,
-                            self._head_w, self._embed_w,
-                            self._rope,
-                            self.cache.k_pages, self.cache.v_pages,
-                            self.cache.k_scales,
-                            self.cache.v_scales,
-                            jnp.asarray(ids),
-                            jnp.asarray(positions),
-                            jnp.asarray(row_tables),
-                            jnp.asarray(q_start),
-                            jnp.asarray(q_len),
-                            jnp.asarray(kv_len),
-                            jnp.asarray(desc_tables),
-                            jnp.asarray(desc_of_row),
-                            jnp.asarray(off_of_row), key,
-                            jnp.int32(0),
-                            eps=self.eps, kvh=self.kvh,
-                            head_dim=self.head_dim,
-                            transpose_head=self._tied,
-                            strategy=self.decode_strategy,
-                            top_k=self.top_k, top_p=self.top_p,
-                            temperature=self.temperature,
-                            shardings=self._shardings,
-                            arch=self._arch)
-                        (nxt, self.cache.k_pages, self.cache.v_pages,
-                         self.cache.k_scales, self.cache.v_scales,
-                         key) = res[:6]
-                        if self._arch is not None:
-                            # live rows this dispatch: n decode slots
-                            # + the packed prefill tokens (used == 0
-                            # past the first step — multi-step windows
-                            # are pure decode)
-                            self._note_expert_counts(
-                                res[6],
-                                (n + (used if si == 0 else 0))
-                                * self._arch.top_k)
-                        nxt = np.asarray(jax.device_get(nxt))
-                        toks_all.append(nxt)
-                        if n:
-                            self.cache.advance(slots, 1)
-                        if si + 1 < nsteps:
-                            # host-chained window (pure decode): feed
-                            # each slot's sampled token back as the
-                            # next input
-                            ids[:n] = nxt[:n]
-                            positions[:n] += 1
-                            kv_len[:n] += 1
-        finally:
-            span.set_attr("steps_done", steps_done)
-            span.end()
+                            res[6],
+                            (n + (used if si == 0 else 0))
+                            * self._arch.top_k)
+                with _phase("engine.step.wait"):
+                    nxt = np.asarray(jax.device_get(nxt))
+                toks_all.append(nxt)
+                if si + 1 < nsteps:
+                    with _phase("engine.step.pack"):
+                        # host-chained window (pure decode): feed each
+                        # slot's sampled token back as the next input
+                        ids[:n] = nxt[:n]
+                        positions[:n] += 1
+                        kv_len[:n] += 1
         dt_win = time.perf_counter() - t_win
-        self.last_window_steps = steps_done
 
-        out = {}
-        for i, req in enumerate(batch):
-            new_toks = []
-            for j in range(steps_done):
-                if req.done:
-                    break
-                tok = int(toks_all[j][i])
-                req.out.append(tok)
-                new_toks.append(tok)
-                if (req.eos is not None and tok == req.eos) or \
-                        len(req.out) >= req.max_new:
+        with _phase("engine.step.merge"):
+            if n:
+                self.cache.advance(slots, steps_done)
+            self.last_window_steps = steps_done
+            out = {}
+            for i, req in enumerate(batch):
+                new_toks = []
+                for j in range(steps_done):
+                    if req.done:
+                        break
+                    tok = int(toks_all[j][i])
+                    req.out.append(tok)
+                    new_toks.append(tok)
+                    if (req.eos is not None and tok == req.eos) or \
+                            len(req.out) >= req.max_new:
+                        req.done = True
+                        self.cache.release(req.slot)
+                        self._spec_release(req)
+                        self._active.remove(req)
+                if new_toks:
+                    out[req.rid] = new_toks
+            # decode tokens DELIVERED this window (prefill-completing
+            # first tokens are TTFT, appended to `out` below, never
+            # TPOT)
+            delivered = max((len(v) for v in out.values()), default=0)
+
+            # prefill bookkeeping AFTER the dispatch succeeded — a raise
+            # above leaves every pf_pos where it was (no token lost)
+            for req, pos, cl, row0, d in plan:
+                req.pf_pos = pos + cl
+            for req, last_row in finishing:
+                first = int(toks_all[0][last_row])
+                plen = len(req.prompt)
+                self.cache.set_len(req.slot, plen)
+                if self.enable_prefix_caching:
+                    self.cache.register_prefix(req.slot, req.prompt,
+                                               upto=(plen // P) * P)
+                req.out.append(first)
+                self._prefilling.remove(req)
+                out[req.rid] = [first]
+                if req.t_submit is not None:
+                    ttft = time.perf_counter() - req.t_submit
+                    _health.get_health().observe_ttft(ttft)
+                    if self._metrics is not None:
+                        self._metrics["ttft"].observe(ttft)
+                if (req.eos is not None and first == req.eos) or \
+                        req.max_new <= 1:
                     req.done = True
                     self.cache.release(req.slot)
                     self._spec_release(req)
-                    self._active.remove(req)
-            if new_toks:
-                out[req.rid] = new_toks
-        # decode tokens DELIVERED this window (prefill-completing first
-        # tokens are TTFT, appended to `out` below, never TPOT)
-        delivered = max((len(v) for v in out.values()), default=0)
-
-        # prefill bookkeeping AFTER the dispatch succeeded — a raise
-        # above leaves every pf_pos where it was (no token lost)
-        for req, pos, cl, row0, d in plan:
-            req.pf_pos = pos + cl
-        for req, last_row in finishing:
-            first = int(toks_all[0][last_row])
-            plen = len(req.prompt)
-            self.cache.set_len(req.slot, plen)
-            if self.enable_prefix_caching:
-                self.cache.register_prefix(req.slot, req.prompt,
-                                           upto=(plen // P) * P)
-            req.out.append(first)
-            self._prefilling.remove(req)
-            out[req.rid] = [first]
-            if req.t_submit is not None:
-                ttft = time.perf_counter() - req.t_submit
-                _health.get_health().observe_ttft(ttft)
-                if self._metrics is not None:
-                    self._metrics["ttft"].observe(ttft)
-            if (req.eos is not None and first == req.eos) or \
-                    req.max_new <= 1:
-                req.done = True
-                self.cache.release(req.slot)
-                self._spec_release(req)
-            else:
-                self._active.append(req)
-        # capsule capture after the finishing loop, so prefill-
-        # completing first tokens ride the same window record as the
-        # decode tokens (the forked key `sub` anchors the whole
-        # window's split_step chain, host-chained or scanned)
-        cs = _capsule.get_capsule_store()
-        if cs.enabled and out:
-            # per-rid draw rows: decode slots are rows 0..n-1 in batch
-            # order; a prefill-finishing first token drew at its chunk's
-            # last flat row — recorded so stochastic replay can re-fold
-            # the exact draw id whatever slot the request decoded in
-            rows = {r.rid: i for i, r in enumerate(batch)}
-            for req, last_row in finishing:
-                rows[req.rid] = int(last_row)
-            cs.on_window(out, _sampling.key_fingerprint(sub), nsteps,
-                         steps_done,
-                         "mixed_window"
-                         if self.scan_decode and nsteps > 1
-                         else "mixed_step", rows=rows)
-        # TPOT over-count fix: only DELIVERED decode positions advance
-        # the histogram / SLO window — a window whose requests all
-        # finished early contributes its real token count, not nsteps;
-        # pure-prefill steps contribute nothing (their latency is TTFT)
-        if delivered:
-            _health.get_health().observe_tpot(dt_win / steps_done,
-                                              n=delivered)
-        if self._metrics is not None:
-            m = self._metrics
+                else:
+                    self._active.append(req)
+        with _phase("engine.step.account"):
+            # capsule capture after the finishing loop, so prefill-
+            # completing first tokens ride the same window record as
+            # the decode tokens (the forked key `sub` anchors the whole
+            # window's split_step chain, host-chained or scanned)
+            cs = _capsule.get_capsule_store()
+            if cs.enabled and out:
+                # per-rid draw rows: decode slots are rows 0..n-1 in
+                # batch order; a prefill-finishing first token drew at
+                # its chunk's last flat row — recorded so stochastic
+                # replay can re-fold the exact draw id whatever slot
+                # the request decoded in
+                rows = {r.rid: i for i, r in enumerate(batch)}
+                for req, last_row in finishing:
+                    rows[req.rid] = int(last_row)
+                cs.on_window(out, _sampling.key_fingerprint(sub), nsteps,
+                             steps_done,
+                             "mixed_window" if window else "mixed_step",
+                             rows=rows)
+            # TPOT over-count fix: only DELIVERED decode positions
+            # advance the histogram / SLO window — a window whose
+            # requests all finished early contributes its real token
+            # count, not nsteps; pure-prefill steps contribute nothing
+            # (their latency is TTFT)
             if delivered:
-                m["tpot"].observe(dt_win / steps_done, n=delivered)
-            m["generated_tokens"].inc(
-                sum(len(v) for v in out.values()))
-            m["queue_depth"].set(len(self._active))
-            m["occupancy"].set(n / self.max_seqs)
-            m["mixed_decode_slots"].set(n)
-            m["mixed_prefill_tokens"].set(used)
-            self._record_compiles()
+                _health.get_health().observe_tpot(dt_win / steps_done,
+                                                  n=delivered)
+            if self._metrics is not None:
+                m = self._metrics
+                if delivered:
+                    m["tpot"].observe(dt_win / steps_done, n=delivered)
+                m["steps"].inc()
+                m["step_prefill_tokens"].inc(used)
+                m["generated_tokens"].inc(
+                    sum(len(v) for v in out.values()))
+                m["queue_depth"].set(len(self._active))
+                m["occupancy"].set(n / self.max_seqs)
+                m["mixed_decode_slots"].set(n)
+                m["mixed_prefill_tokens"].set(used)
+                self._record_compiles()
         return out
 
     def has_work(self) -> bool:
@@ -3368,6 +3416,9 @@ class LLMEngine:
                 "prompt_tokens": int(m["prompt_tokens"].value),
                 "generated_tokens": int(m["generated_tokens"].value),
                 "requests": int(m["requests"].value),
+                "steps": int(m["steps"].value),
+                "step_prefill_tokens":
+                    int(m["step_prefill_tokens"].value),
                 "queue_depth": m["queue_depth"].value,
                 "batch_occupancy": m["occupancy"].value,
                 "mixed_batch_decode_slots":

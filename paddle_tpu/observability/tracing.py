@@ -1,4 +1,4 @@
-"""Request/step tracing + crash flight recorder (stdlib-only).
+"""Request/step tracing + crash flight recorder.
 
 Reference parity: the reference framework's profiler tells you what the
 *process* spent time on; a serving tier needs to know what one
@@ -25,6 +25,17 @@ multi-host tier) across hosts.  This module is that layer:
   before it died" record that survives the chaos schedules the
   serving/trainer tiers inject.
 
+- :data:`phase` — one stretch of a LOOP THREAD's iteration (the
+  serving loop's ``sched.step`` > ``engine.step`` > ``engine.step.pack``
+  ...), as opposed to a request's spans above.  A phase is a
+  ``jax.profiler.TraceAnnotation`` and nothing else: it lands in
+  whatever profiler session is open (an operator's ``jax.profiler``
+  capture, ``paddle_tpu.profiler.Profiler``, the benchmark's
+  ``--trace 1``) on the DEVICE TRACE'S CLOCK, beside the device's
+  operations, and is inert otherwise — no clock read, no ring, no
+  switch to turn.  Phases never enter the tracer's ring: some 200 a
+  second would push every request's trace out of it in 20 s.
+
 Disabled-is-free contract: every instrumentation site goes through the
 module-level :func:`span` / :func:`record_event` helpers, which read
 ONE module global and return the shared :data:`NULL_SPAN` singleton
@@ -44,7 +55,12 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "FlightRecorder", "NULL_SPAN",
+from jax.profiler import TraceAnnotation as phase
+# ``with phase("engine.step.pack"):`` / ``phase(name, rows=n)`` /
+# ``sp.set_metadata(path="mixed")`` for what is known only later.
+# Attributes are the annotation's keyword arguments (module docstring).
+
+__all__ = ["Span", "Tracer", "FlightRecorder", "NULL_SPAN", "phase",
            "get_tracer", "set_tracer", "enable_tracing",
            "disable_tracing", "span", "start_span", "record_span",
            "current_context", "get_flight_recorder",

@@ -17,7 +17,6 @@ from __future__ import annotations
 import json
 import os
 import time
-from collections import deque
 from enum import Enum
 from typing import Callable, Iterable, Optional
 
@@ -26,11 +25,11 @@ from ..observability import tracing as _tracing
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result"]
 
-# Completed RecordEvent host ranges (name, t0, t1) — bounded ring so
-# always-on instrumentation (e.g. the serving engine's prefill/decode
-# spans) can't grow memory; export_chrome_tracing drains the ranges
-# that overlap the profiler session into the chrome-trace JSON.
-_HOST_EVENTS: deque = deque(maxlen=100_000)
+# Profilers between start() and stop().  A RecordEvent that runs while
+# one is open also leaves (name, t0, t1) in that session's own list,
+# for the chrome-trace stub of a ``timer_only`` session (which has no
+# XPlane); with none open it reads no clock and keeps nothing.
+_SESSIONS: list = []
 
 
 class ProfilerState(Enum):
@@ -82,13 +81,13 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None
         events = [{"name": f"step {i}", "ph": "X", "pid": 0, "tid": 0,
                    "ts": int(t0 * 1e6), "dur": int((t1 - t0) * 1e6)}
                   for i, (t0, t1) in enumerate(prof._step_times)]
-        # RecordEvent host ranges from this session (engine prefill/
-        # decode spans etc.) land on their own track next to the steps
+        # RecordEvent host ranges of this session land on their own
+        # track next to the steps
         begin = prof._session_begin or 0.0
         events.extend(
             {"name": name, "ph": "X", "pid": 0, "tid": 1,
              "ts": int(t0 * 1e6), "dur": int((t1 - t0) * 1e6)}
-            for name, t0, t1 in list(_HOST_EVENTS) if t0 >= begin)
+            for name, t0, t1 in prof._host_events)
         # the observability tracer's spans (request spans, scheduler
         # queue waits, engine chunk/window spans) land on their own
         # track — the profiler session and the serving tracer share
@@ -110,36 +109,35 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None
 
 class RecordEvent:
     """Host range annotation visible in the device trace
-    (reference: paddle.profiler.RecordEvent over C++ RecordEvent).
-    When the observability tracer is enabled, the range ALSO records
-    as a span there — nesting under whatever span is active on this
-    thread (e.g. the scheduler's admit span), so profiler-annotated
-    engine work lands inside the request's trace."""
+    (reference: paddle.profiler.RecordEvent over C++ RecordEvent): the
+    Paddle-shaped name for ``observability.tracing.phase``, which is
+    what it opens.  When the observability tracer is enabled, the range
+    ALSO records as a span there — nesting under whatever span is
+    active on this thread (e.g. the scheduler's admit span), so
+    profiler-annotated engine work lands inside the request's trace."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
         self._ann = None
         self._t0 = None
-        self._span = None
+        self._span = _tracing.NULL_SPAN
 
     def begin(self):
-        import jax
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = _tracing.phase(self.name)
         self._ann.__enter__()
-        sp = _tracing.span(self.name)
-        self._span = sp if sp is not _tracing.NULL_SPAN else None
-        self._t0 = time.perf_counter()
+        self._span = _tracing.span(self.name)
+        self._t0 = time.perf_counter() if _SESSIONS else None
 
     def end(self):
-        if self._span is not None:
-            self._span.end()
-            self._span = None
+        self._span.end()
+        self._span = _tracing.NULL_SPAN
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
         if self._t0 is not None:
-            _HOST_EVENTS.append((self.name, self._t0,
-                                 time.perf_counter()))
+            rng = (self.name, self._t0, time.perf_counter())
+            for prof in _SESSIONS:
+                prof._host_events.append(rng)
             self._t0 = None
 
     def __enter__(self):
@@ -191,6 +189,7 @@ class Profiler:
         self._step_times = []
         self._step_begin = None
         self._session_begin = None
+        self._host_events = []
 
     # -- lifecycle -----------------------------------------------------------
     def start(self):
@@ -198,6 +197,8 @@ class Profiler:
         self._apply_state(self._schedule(0))
         self._step_begin = time.perf_counter()
         self._session_begin = self._step_begin
+        if self not in _SESSIONS:
+            _SESSIONS.append(self)
         return self
 
     def stop(self):
@@ -210,6 +211,8 @@ class Profiler:
             if now > self._step_begin:
                 self._step_times.append((self._step_begin, now))
             self._step_begin = None
+        if self in _SESSIONS:
+            _SESSIONS.remove(self)
         self._stop_trace()
         if self._on_trace_ready is not None:
             self._on_trace_ready(self)

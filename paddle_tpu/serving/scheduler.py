@@ -73,6 +73,7 @@ from ..observability import capsule as _capsule
 from ..observability import health as _health
 from ..observability import introspection as _insp
 from ..observability import tracing as _tracing
+from ..observability.tracing import phase as _phase
 
 __all__ = ["Scheduler", "RejectedError", "ScheduledRequest"]
 
@@ -435,46 +436,62 @@ class Scheduler:
         finer-grained engine state to race with."""
         events: List = []
         out: Dict[object, List[int]] = {}
-        with self._lock:
-            self._process_aborts(events)
-            self._expire_waiting(events)
-            self._admit(events, out)
-            if self.engine.has_work():
-                t0 = time.perf_counter()
-                try:
-                    step_out = self.engine.step()
-                except BaseException as e:
-                    # triggered capture: an engine step blowing up is
-                    # THE reproduction case — persist every in-flight
-                    # capsule before the error propagates
-                    for rec in self._reqs.values():
-                        if rec.state == ACTIVE:
-                            self._capsule_persist(
-                                rec, f"error:{type(e).__name__}")
-                    raise
-                self._adapt_prefill_budget(time.perf_counter() - t0,
-                                           step_out)
-                for rid, toks in step_out.items():
-                    rec = self._reqs.get(rid)
-                    if rec is None or rec.state != ACTIVE:
-                        continue
-                    if (rec.first_token_t is None and toks
-                            and not rec.tokens):
-                        # chunked admission: the first token arrives
-                        # from a mixed step, not at admit time
-                        rec.first_token_t = self._clock()
-                        rec.timeline.append(("first_token",
-                                             rec.first_token_t))
-                        self._capsule_first_token(rec)
-                    rec.tokens.extend(toks)
-                    out.setdefault(rid, []).extend(toks)
-                    self._event(events, rec,
-                                {"type": "tokens", "rid": rid,
-                                 "tokens": list(toks)})
-                self._capsule_sentinel_check()
-            self._retire_done(events)
-        self._dispatch(events)
+        # the iteration's phases, on the profiler's clock (see
+        # observability.tracing.phase): every line below lies in one
+        # of intake / admit / engine.step / emit
+        with _phase("sched.step"):
+            with self._lock:
+                with _phase("sched.step.intake"):
+                    self._process_aborts(events)
+                    self._expire_waiting(events)
+                with _phase("sched.step.admit"):
+                    self._admit(events, out)
+                    has_work = self.engine.has_work()
+                if has_work:
+                    t0 = time.perf_counter()
+                    try:
+                        step_out = self.engine.step()
+                    except BaseException as e:
+                        # triggered capture: an engine step blowing up
+                        # is THE reproduction case — persist every
+                        # in-flight capsule before the error propagates
+                        for rec in self._reqs.values():
+                            if rec.state == ACTIVE:
+                                self._capsule_persist(
+                                    rec, f"error:{type(e).__name__}")
+                        raise
+                    dt = time.perf_counter() - t0
+                with _phase("sched.step.emit"):
+                    if has_work:
+                        self._adapt_prefill_budget(dt, step_out)
+                        self._note_tokens(step_out, events, out)
+                        self._capsule_sentinel_check()
+                    self._retire_done(events)
+            # the callbacks run outside the lock
+            with _phase("sched.step.emit"):
+                self._dispatch(events)
         return out
+
+    def _note_tokens(self, step_out, events, out):
+        """Fold one engine step's tokens into the records, the step's
+        return value and the event list."""
+        for rid, toks in step_out.items():
+            rec = self._reqs.get(rid)
+            if rec is None or rec.state != ACTIVE:
+                continue
+            if (rec.first_token_t is None and toks
+                    and not rec.tokens):
+                # chunked admission: the first token arrives
+                # from a mixed step, not at admit time
+                rec.first_token_t = self._clock()
+                rec.timeline.append(("first_token",
+                                     rec.first_token_t))
+                self._capsule_first_token(rec)
+            rec.tokens.extend(toks)
+            out.setdefault(rid, []).extend(toks)
+            self._event(events, rec,
+                        {"type": "tokens", "rid": rid,
+                         "tokens": list(toks)})
 
     def _adapt_prefill_budget(self, dt: float, step_out: dict):
         """AIMD on the engine's runtime ``prefill_token_budget``

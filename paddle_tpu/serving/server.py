@@ -85,6 +85,7 @@ from ..observability import capsule as _capsule
 from ..observability import health as _health
 from ..observability import introspection as _insp
 from ..observability import tracing as _tracing
+from ..observability.tracing import phase as _phase
 from ..observability.exposition import CONTENT_TYPE as _PROM_CONTENT_TYPE
 from .scheduler import RejectedError
 
@@ -255,27 +256,39 @@ class HTTPFrontend:
 
     # -- the scheduling loop ---------------------------------------------------
     def _loop(self):
+        # what this thread does between two steps of its target shows
+        # in a profiler capture as serve.loop.cmds / .poll / .wait
+        # (observability.tracing.phase), beside the target's own
+        # sched.step > engine.step phases.  The poll takes the target's
+        # lock, behind whatever handler threads queued for it during
+        # the step.
         while not self._stop.is_set():
             self._run_cmds()
-            if self.target.busy():
+            with _phase("serve.loop.poll"):
+                busy = self.target.busy()
+            if busy:
                 self.target.step()
             else:
-                self._stop.wait(self.poll_interval)
+                with _phase("serve.loop.wait"):
+                    self._stop.wait(self.poll_interval)
         self._run_cmds()                      # unblock late callers
 
     def _run_cmds(self):
         """Execute marshaled closures (engine-state work from handler
         threads) on the loop thread."""
-        while True:
-            try:
-                fn, box, done = self._cmds.get_nowait()
-            except queue.Empty:
-                return
-            try:
-                box[0] = fn()
-            except BaseException as e:
-                box[1] = e
-            done.set()
+        if self._cmds.empty():      # only this thread takes from it
+            return
+        with _phase("serve.loop.cmds"):
+            while True:
+                try:
+                    fn, box, done = self._cmds.get_nowait()
+                except queue.Empty:
+                    return
+                try:
+                    box[0] = fn()
+                except BaseException as e:
+                    box[1] = e
+                done.set()
 
     def _on_loop(self, fn, timeout: float = 60.0):
         """Run ``fn`` on the scheduling loop thread and return its

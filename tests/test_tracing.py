@@ -31,6 +31,7 @@ import logging
 import os
 import re
 import signal
+import threading
 import urllib.request
 from pathlib import Path
 
@@ -79,6 +80,16 @@ def _direct(model, prompt, n):
     while eng.has_work():
         eng.step()
     return eng.result("ref")
+
+
+def _until(cond, what, timeout=60.0):
+    """Wait for a CONDITION another thread brings about (never a fixed
+    sleep: a loaded machine stretches every guess)."""
+    import time
+    end = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < end, f"timed out waiting for {what}"
+        time.sleep(0.005)
 
 
 def _connected(tracer, rid):
@@ -435,6 +446,7 @@ class TestTraceChaos:
         tr = T.enable_tracing(max_spans=16384)
         scheds = [_mk_sched(model) for _ in range(2)]
         fes = [start_http_frontend(s) for s in scheds]
+        parked = None
         try:
             reps = [RemoteReplica(fe.url, timeout=30, sleep=_NOSLEEP)
                     for fe in fes]
@@ -449,8 +461,29 @@ class TestTraceChaos:
             prober = HealthProber(router, dead_after=2, timeout=1.0,
                                   sleep=_NOSLEEP)
             rids = [f"x{i}" for i in range(3)]
+            if schedule == "crash":
+                # the crash falls on the main thread's 4th poll.  What
+                # it must find, on any machine, is backend 0 with
+                # ADMITTED work still in flight: its loop thread (which
+                # owns all stepping) takes the requests through one
+                # step and then stands still until it is killed
+                arrived, stepped = threading.Event(), threading.Event()
+
+                def one_step_then_stand():
+                    assert arrived.wait(60)
+                    scheds[0].step()
+                    stepped.set()
+                    fes[0]._stop.wait(60)
+                parked = threading.Thread(
+                    target=fes[0]._on_loop, args=(one_step_then_stand,))
+                parked.start()
             for i, rid in enumerate(rids):
                 router.submit(rid, [1 + i, 2, 3], max_new_tokens=8)
+            if parked is not None:
+                arrived.set()
+                assert stepped.wait(60)
+                assert all(r["state"] == "active"
+                           for r in scheds[0].requests_overview())
             steps = 0
             while router.busy() and steps < 3000:
                 router.step()
@@ -477,6 +510,9 @@ class TestTraceChaos:
                     fe.shutdown(drain=False)
                 except Exception:
                     pass
+            if parked is not None:
+                parked.join(30)
+                assert not parked.is_alive()
 
     def test_remote_hop_headers_connect_trace(self, model):
         """Trace context crosses the HTTP seam in HEADERS: a client
@@ -516,8 +552,14 @@ class TestDebugEndpoints:
         sched.run_until_idle()
         fe = start_http_frontend(sched)
         try:
-            sched.submit("s", [5, 9, 2], max_new_tokens=40)
-            raw = urllib.request.urlopen(fe.url + "/statusz").read()
+            def submit_and_fetch():
+                # on the loop thread, which therefore cannot step: the
+                # request is still live when the HTTP thread renders
+                # the page, however fast 40 tokens decode
+                sched.submit("s", [5, 9, 2], max_new_tokens=40)
+                return urllib.request.urlopen(fe.url + "/statusz",
+                                              timeout=30).read()
+            raw = fe._on_loop(submit_and_fetch)
             out = json.loads(raw)      # round-trips
             assert out["status"] == "ok"
             assert out["uptime_seconds"] >= 0
@@ -578,14 +620,17 @@ class TestDebugEndpoints:
             req = urllib.request.Request(
                 fe.url + "/v1/completions", data=body,
                 headers={"Content-Type": "application/json"})
+            def slow():
+                return [r for r in caplog.records
+                        if "slow request" in r.getMessage()]
             with caplog.at_level(logging.WARNING,
                                  logger="paddle_tpu.serving"):
                 out = json.loads(urllib.request.urlopen(req).read())
+                # the handler thread logs AFTER it has answered: keep
+                # the level until the line is there
+                _until(slow, "the slow-request log line")
             assert out["state"] == "finished"
-            slow = [r for r in caplog.records
-                    if "slow request" in r.getMessage()]
-            assert slow, "expected a slow-request log line"
-            msg = slow[0].getMessage()
+            msg = slow()[0].getMessage()
             assert "rid=slow1" in msg and "trace_id=" in msg
 
             def post(path, obj):
@@ -598,12 +643,9 @@ class TestDebugEndpoints:
             # owns all stepping; the client only submits and polls)
             assert post("/v1/submit", {"id": "tl", "prompt": [1, 2, 3],
                                        "max_tokens": 4})["accepted"]
-            import time as _time
-            for _ in range(2000):
-                st = post("/v1/poll", {"ids": ["tl"]})
-                if st["requests"]["tl"]["state"] == "finished":
-                    break
-                _time.sleep(0.01)
+            _until(lambda: post("/v1/poll", {"ids": ["tl"]})
+                   ["requests"]["tl"]["state"] == "finished",
+                   "request tl to finish")
             out = post("/v1/timeline", {"id": "tl"})
             assert out["timeline"]["state"] == "finished"
             assert out["timeline"]["ttft"] is not None
