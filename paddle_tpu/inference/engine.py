@@ -758,15 +758,39 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
         moe_live = off_of_row < jnp.take(q_len, desc_of_row)
         moe_group = jnp.take(q_start, desc_of_row)
 
+    # Every operand of the layer loop is used WHERE IT LIES (PR 26).
+    # The KV pools ride the scan's carry whole, [L, KVH, ..]: the
+    # ragged kernel takes the stacked pool plus the layer index (a
+    # scalar-prefetch operand), picks the layer before its page DMAs
+    # and aliases the whole pool input to output.  The expert matrices
+    # are not scanned either: their [L, E, ..] stacks are closed over,
+    # flattened to [L·E, ..] (a bitcast), and ``moe_ffn`` offsets the
+    # grouped matmul's tile→expert map by ``layer · E``.  As scanned
+    # ``xs`` (and ``ys`` for the pools) each of them was copied out of
+    # its stack for the custom call every layer — a Pallas call is a
+    # custom call, nothing fuses into it — the pools were written back
+    # into the stacked ``ys``, and those were copied whole to the
+    # program's outputs: 60 % of the chip's busy time in both serving
+    # cells.  What stays scanned is what XLA's own matmuls consume.
+    n_layers = k_pages.shape[0]
+    scanned = tuple(stack)
+    if arch is not None:
+        def flat(w):
+            if isinstance(w, tuple):
+                return tuple(flat(a) for a in w)
+            return w.reshape((-1,) + w.shape[2:])
+        egw, euw, edw = (flat(w) for w in scanned[10:13])
+        scanned = scanned[:10] + scanned[13:]
+
     def layer(carry, xs):
-        hcur = carry
-        lp, kp, vp, ksp, vsp = xs              # per-layer params + pools
+        hcur, pools = carry                    # the pools whole, [L, ..]
+        li, lp = xs                            # layer index + params
         if arch is None:
             iln, qw, kw, vw, ow, pln, gw, uw, dw = lp
             qb = kb = vb = None
         else:
-            (iln, qw, qb, kw, kb, vw, vb, ow, pln, rw, egw, euw,
-             edw, sgw, suw, sdw, seg) = lp
+            (iln, qw, qb, kw, kb, vw, vb, ow, pln, rw,
+             sgw, suw, sdw, seg) = lp
         hn = _nn.rms_norm(hcur, iln, epsilon=eps)
         nh = _wout(qw) // head_dim
         qx, kx, vx = _mm(hn, qw), _mm(hn, kw), _mm(hn, vw)
@@ -780,39 +804,55 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
         q = (qf * cos + rotate_half(qf) * sin).astype(q.dtype)
         k = (kf * cos + rotate_half(kf) * sin).astype(k.dtype)
         if on_tpu:
-            # ragged kernel: per-descriptor [P, H, D] output blocks,
-            # gathered back to the flat row order
+            # ragged kernel on the STACKED pools at layer ``li``:
+            # per-descriptor [P, H, D] output blocks, gathered back to
+            # the flat row order
             # (under a tp mesh each shard runs the kernel on its own
             # heads: q/k/v rows shard on the head dim, pools and scale
-            # pools on KVH, descriptors replicate, output blocks come
-            # back sharded on H)
-            n_sc = 0 if ksp is None else 2
+            # pools on KVH, descriptors and the layer index replicate,
+            # output blocks come back sharded on H)
+            kps, vps, kss, vss = pools
+            n_sc = 0 if kss is None else 2
             ragged = _per_shard(
-                shardings, ragged_paged_append_attend_raw,
-                (1, 0, 0, 1, 1, None, None, None, None) + (0,) * n_sc,
-                (2, 0, 0) + (0,) * n_sc)
-            if ksp is None:
-                blocks, kp, vp = ragged(
-                    q, kp, vp, k, v, q_start, q_len, kv_len,
-                    desc_tables)
+                shardings,
+                lambda *a: ragged_paged_append_attend_raw(
+                    *a[:-1], layer=a[-1]),
+                (1, 1, 1, 1, 1, None, None, None, None)
+                + (1,) * n_sc + (None,),
+                (2, 1, 1) + (1,) * n_sc)
+            if kss is None:
+                blocks, kps, vps = ragged(
+                    q, kps, vps, k, v, q_start, q_len, kv_len,
+                    desc_tables, li)
             else:
-                blocks, kp, vp, ks4, vs4 = ragged(
-                    q, kp, vp, k, v, q_start, q_len, kv_len,
-                    desc_tables, ksp[:, :, None, :],
-                    vsp[:, :, None, :])
+                blocks, kps, vps, ks5, vs5 = ragged(
+                    q, kps, vps, k, v, q_start, q_len, kv_len,
+                    desc_tables, kss[:, :, :, None, :],
+                    vss[:, :, :, None, :], li)
+                kss = ks5.reshape(kss.shape)
+                vss = vs5.reshape(vss.shape)
+            pools = (kps, vps, kss, vss)
+            attn = blocks[desc_of_row, off_of_row]          # [T, NH, D]
+        else:
+            # the per-row jnp mirror, on this layer's slice
+            kp, vp, ksp, vsp = (
+                None if p is None else
+                jax.lax.dynamic_index_in_dim(p, li, keepdims=False)
+                for p in pools)
+            if ksp is None:
+                attn, kp, vp = ragged_paged_append_attend_reference(
+                    q, kp, vp, k, v, positions, row_tables)
+            else:
+                attn, kp, vp, ks4, vs4 = \
+                    ragged_paged_append_attend_reference(
+                        q, kp, vp, k, v, positions, row_tables,
+                        ksp[:, :, None, :], vsp[:, :, None, :])
                 ksp = ks4.reshape(ksp.shape)
                 vsp = vs4.reshape(vsp.shape)
-            attn = blocks[desc_of_row, off_of_row]          # [T, NH, D]
-        elif ksp is None:
-            attn, kp, vp = ragged_paged_append_attend_reference(
-                q, kp, vp, k, v, positions, row_tables)
-        else:
-            attn, kp, vp, ks4, vs4 = \
-                ragged_paged_append_attend_reference(
-                    q, kp, vp, k, v, positions, row_tables,
-                    ksp[:, :, None, :], vsp[:, :, None, :])
-            ksp = ks4.reshape(ksp.shape)
-            vsp = vs4.reshape(vsp.shape)
+            pools = tuple(
+                None if p is None else
+                jax.lax.dynamic_update_index_in_dim(p, new, li, 0)
+                for p, new in zip(pools, (kp, vp, ksp, vsp)))
         attn = _tpc(attn, shardings, 1)
         hcur = _tpc(hcur + _mm(
             _tpc(attn.reshape(t, nh * head_dim), shardings), ow),
@@ -822,19 +862,15 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
             ff = _tpc(_nn.silu(_mm(hn, gw)) * _mm(hn, uw),
                       shardings, 1)
             return (_tpc(hcur + _mm(_tpc(ff, shardings), dw),
-                         shardings), (kp, vp, ksp, vsp))
+                         shardings), pools), None
         ff, cnt = moe_ffn(hn, (rw, egw, euw, edw, sgw, suw, sdw, seg),
-                          arch, moe_live, moe_group, shardings)
-        return (_tpc(hcur + ff, shardings), (kp, vp, ksp, vsp, cnt))
+                          arch, moe_live, moe_group, shardings,
+                          expert_base=li * arch.num_experts)
+        return (_tpc(hcur + ff, shardings), pools), cnt
 
-    if arch is None:
-        x, (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
-            layer, x,
-            (tuple(stack), k_pages, v_pages, k_scales, v_scales))
-    else:
-        x, (k_pages, v_pages, k_scales, v_scales, cnts) = jax.lax.scan(
-            layer, x,
-            (tuple(stack), k_pages, v_pages, k_scales, v_scales))
+    (x, (k_pages, v_pages, k_scales, v_scales)), cnts = jax.lax.scan(
+        layer, (x, (k_pages, v_pages, k_scales, v_scales)),
+        (jnp.arange(n_layers, dtype=jnp.int32), scanned))
     x = _nn.rms_norm(x, norm_w, epsilon=eps)
     logits = _tpc(jnp.matmul(x, head_w.T) if transpose_head
                   else _mm(x, head_w), shardings)

@@ -91,17 +91,24 @@ def _expert_rows_mm(x, w, row_expert):
                       wr.astype(jnp.float32))
 
 
-def _gmm_apply(xs, w, tile_expert, gcounts, tm, on_tpu, shardings=None):
+def _gmm_apply(xs, w, tile_expert, gcounts, tm, on_tpu, shardings, base,
+               e):
     """One grouped matmul over the sorted tile-aligned buffer: the
     Pallas kernel on TPU, the per-row oracle (same rows, same math as
     dense mode) on CPU.  Under a tp mesh the expert stacks are
     replicated, so every shard runs the whole kernel (a Mosaic call
-    cannot be partitioned by GSPMD: ``TPShardings.per_shard``)."""
+    cannot be partitioned by GSPMD: ``TPShardings.per_shard``).
+
+    ``base`` (see ``moe_ffn``'s ``expert_base``) is where this layer's
+    ``e`` experts start in ``w``: it rides the kernel's tile→expert
+    map, so the kernel's own block DMAs reach into the layer-stacked
+    weights and XLA never copies a layer out for the custom call."""
+    import jax
     import jax.numpy as jnp
 
     from ..ops.pallas import grouped_matmul
     if not on_tpu:
-        row_e = jnp.repeat(tile_expert, tm)
+        row_e = jnp.repeat(tile_expert, tm) + base
         return _expert_rows_mm(xs, w, row_e)
 
     def gmm(lhs, rhs, te, gc, tm):
@@ -115,12 +122,17 @@ def _gmm_apply(xs, w, tile_expert, gcounts, tm, on_tpu, shardings=None):
         # copy XLA fuses into the kernel's input stream, and the
         # per-out-channel scale folds into the output like _mm's
         qw, sc = w
+        if qw.shape[0] != e:
+            # the upcast is a copy anyway: take this layer's rows first
+            qw = jax.lax.dynamic_slice_in_dim(qw, base, e)
+            sc = jax.lax.dynamic_slice_in_dim(sc, base, e)
         y = gmm(xs, qw.astype(xs.dtype), tile_expert, gcounts, tm)
         return y * sc[jnp.repeat(tile_expert, tm)]
-    return gmm(xs, w, tile_expert, gcounts, tm)
+    return gmm(xs, w, tile_expert + base, gcounts, tm)
 
 
-def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None):
+def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
+            expert_base=0):
     """The MoE decoder-layer FFN for one serving dispatch.
 
     hn [T, H] post-attention-layernorm rows; ``mw`` the per-layer
@@ -132,6 +144,12 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None):
     maps each row to its capacity page-group's first row (``None`` =
     every row its own group — the decode programs, where top-k's
     distinct experts make the in-group rank identically 0).
+    ``expert_base`` (int or traced int32 scalar): the row of the
+    expert stacks at which THIS layer's experts start — 0 for one
+    layer's ``[E, ..]`` stacks; ``layer * E`` when the caller hands
+    over every layer's experts as one ``[L·E, ..]`` array (the
+    engine's ``[L, E, ..]`` stack, flattened — a bitcast), so a layer
+    loop can use them where they lie.
 
     Returns ``(ffn_out [T, H], counts [E] int32)`` — counts are the
     KEPT routed slots per expert (the observability plane's per-expert
@@ -193,13 +211,13 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None):
         xs = jnp.zeros((m_pad, h), f32).at[dest].set(
             xf[order // k], mode="drop")
         hg = _gmm_apply(xs, egw, tile_expert, gcounts, tm, on_tpu,
-                        shardings)
+                        shardings, expert_base, e)
         hu = _gmm_apply(xs, euw, tile_expert, gcounts, tm, on_tpu,
-                        shardings)
+                        shardings, expert_base, e)
         hs = (jax.nn.silu(hg.astype(f32))
               * hu.astype(f32)).astype(xs.dtype)
         ys = _gmm_apply(hs, edw, tile_expert, gcounts, tm, on_tpu,
-                        shardings)
+                        shardings, expert_base, e)
         dest_safe = jnp.minimum(dest, m_pad - 1)
         y_sorted = jnp.where(valid_sorted[:, None],
                              ys[dest_safe].astype(f32), 0.0)
@@ -207,7 +225,7 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None):
     else:
         # dense per-expert reference: the same row-wise contractions
         # on the unsorted slot rows, dropped slots zeroed after
-        safe = jnp.minimum(eidx, e - 1)
+        safe = jnp.minimum(eidx, e - 1) + expert_base
         xdup = jnp.repeat(xf, k, axis=0)                    # [T*k, H]
         hg = _expert_rows_mm(xdup, egw, safe)
         hu = _expert_rows_mm(xdup, euw, safe)

@@ -55,8 +55,9 @@ its earlier chunks' pages already written.  The jnp mirror
 engine's mixed-step program uses off-TPU.
 
 TENSOR-PARALLEL SERVING (engine ``mesh=``/``tp_axis=``): the engine
-shards the page pools on the KVH axis (dim 0 here after the layer
-stack is unstacked) and the query/new-KV projections on the head axis,
+shards the page pools on the KVH axis (dim 0 of one layer's pool, dim
+1 of the layer-stacked pools the ragged kernel takes with ``layer=``)
+and the query/new-KV projections on the head axis,
 so under GSPMD each shard's kernel dispatch sees a self-contained
 problem — KVH/tp heads of EVERY page, with the (sequence, kv-head)
 grid partitioning trivially along its second axis and zero cross-chip
@@ -783,17 +784,24 @@ def _stream_pages_ragged(pt_ref, s_i, h, q2, k_hbm, v_hbm, k_scr, v_scr,
     return l, acc, (kmod, vmod)
 
 
-def _ragged_kernel(ql_ref, kl_ref, pt_ref, q_ref, kn_ref, vn_ref,
+def _ragged_kernel(ql_ref, kl_ref, pt_ref, ly_ref, q_ref, kn_ref, vn_ref,
                    k_in, v_in, *rest,
                    scale, page_size, maxp, quantized):
+    # the pools arrive STACKED over layers and whole ([L, KVH, ...], in
+    # HBM): this call's layer is picked here, before any page DMA, so
+    # the caller never slices a layer out or writes one back
+    ly = ly_ref[0]
+    k_in, v_in = k_in.at[ly], v_in.at[ly]
     if quantized:
         (ks_in, vs_in, o_ref, k_out, v_out, ks_out, vs_out,
          k_scr, v_scr, w_scr, sem, wsem,
          ks_scr, vs_scr, ws_scr) = rest
-        quant = (ks_in, vs_in, ks_scr, vs_scr)
+        quant = (ks_in.at[ly], vs_in.at[ly], ks_scr, vs_scr)
+        ks_out, vs_out = ks_out.at[ly], vs_out.at[ly]
     else:
         (o_ref, k_out, v_out, k_scr, v_scr, w_scr, sem, wsem) = rest
         quant = None
+    k_out, v_out = k_out.at[ly], v_out.at[ly]
     s_i, h = pl.program_id(0), pl.program_id(1)
     q_len = ql_ref[s_i]
     kv_len = kl_ref[s_i]
@@ -899,7 +907,7 @@ def _ragged_kernel(ql_ref, kl_ref, pt_ref, q_ref, kn_ref, vn_ref,
 def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
                                    q_start, q_len, kv_len, page_tables,
                                    k_scales=None, v_scales=None, *,
-                                   scale=None):
+                                   scale=None, layer=None):
     """Ragged mixed prefill+decode step: ONE kernel appends and attends
     every descriptor of a flat token batch.
 
@@ -916,14 +924,31 @@ def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
                   an unused descriptor slot.
     page_tables:  [S, maxp] int32 per-descriptor page tables.
     k_scales/v_scales: optional [KVH, n_pages, 1, P] f32 — int8 pools.
+    layer:        ``None`` for one layer's pools [KVH, n_pages, P, D];
+                  an int or traced int32 scalar for pools (and scale
+                  pools) STACKED over layers, [L, KVH, n_pages, P, D]
+                  and [L, KVH, n_pages, 1, P].  The index reaches the
+                  kernel as a scalar-prefetch operand and selects the
+                  layer before the page DMAs and the write-back, so the
+                  whole stacked pool is aliased input to output and no
+                  layer is ever sliced out of it or copied back.
 
     Returns (out [S, P, H, D], k_pages', v_pages'[, k_scales',
     v_scales']): descriptor s's row j lives at out[s, j] — the caller
-    gathers flat rows with its (descriptor, offset) map.  Pools are
-    donated/aliased; the only KV writes are one modified page per
-    (descriptor, kv-head)."""
+    gathers flat rows with its (descriptor, offset) map.  Pools come
+    back in the shape they came in and are donated/aliased; the only
+    KV writes are one modified page per (descriptor, kv-head)."""
+    if layer is None:
+        # one layer's pools are a stack of one (a bitcast, no copy)
+        outs = ragged_paged_append_attend_raw(
+            q, k_pages[None], v_pages[None], k_new, v_new, q_start,
+            q_len, kv_len, page_tables,
+            None if k_scales is None else k_scales[None],
+            None if v_scales is None else v_scales[None],
+            scale=scale, layer=0)
+        return outs[:1] + tuple(o[0] for o in outs[1:])
     t, h, d = q.shape
-    kvh, n_pages, page_size, _ = k_pages.shape
+    _, kvh, n_pages, page_size, _ = k_pages.shape
     s_max = q_start.shape[0]
     maxp = page_tables.shape[1]
     g = h // kvh
@@ -959,7 +984,7 @@ def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
 
     def blk(rows):
         return pl.BlockSpec((1, 1, rows, d),
-                            lambda s_, h_, ql, kl, pt: (s_, h_, 0, 0))
+                            lambda s_, h_, ql, kl, pt, ly: (s_, h_, 0, 0))
     in_specs = [
         blk(P * g), blk(P), blk(P),
         pl.BlockSpec(memory_space=pl.ANY),   # k_pages
@@ -983,8 +1008,8 @@ def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
         out_sds(k_pages.shape, k_pages.dtype, qb, k_pages, v_pages),
         out_sds(v_pages.shape, v_pages.dtype, qb, k_pages, v_pages),
     ]
-    # alias indices count the 3 scalar-prefetch operands first
-    aliases = {6: 1, 7: 2}
+    # alias indices count the 4 scalar-prefetch operands first
+    aliases = {7: 1, 8: 2}
     if quantized:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY),
                      pl.BlockSpec(memory_space=pl.ANY)]
@@ -998,12 +1023,12 @@ def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
             out_sds(k_scales.shape, k_scales.dtype, qb, k_scales),
             out_sds(v_scales.shape, v_scales.dtype, qb, v_scales),
         ]
-        aliases = {6: 1, 7: 2, 8: 3, 9: 4}
+        aliases = {7: 1, 8: 2, 9: 3, 10: 4}
     outs = pl.pallas_call(
         kernel,
         name="ragged_paged_append_attend",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(s_max, kvh),
             in_specs=in_specs,
             out_specs=out_specs,
@@ -1012,7 +1037,8 @@ def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
         out_shape=out_shape,
         input_output_aliases=aliases,
     )(q_len.astype(jnp.int32), kv_len.astype(jnp.int32),
-      page_tables.astype(jnp.int32), *operands)
+      page_tables.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     out = jnp.transpose(outs[0].reshape(s_max, kvh, P, g, d),
                         (0, 2, 1, 3, 4)).reshape(s_max, P, h, d)
     return (out,) + tuple(outs[1:])

@@ -46,6 +46,27 @@ def _dense_oracle(q, k_pages, v_pages, page_table, seq_lens):
     return np.stack(outs)
 
 
+def _ragged_operands(rng, pool, t, kvh, g, d, shape):
+    """Flat q / new-K / new-V rows and two pools of ``shape`` (with
+    their per-token scale pools when ``pool`` is int8) for the ragged
+    kernel tests."""
+    act = jnp.float32 if pool == "float32" else jnp.bfloat16
+    q = jnp.asarray(rng.normal(size=(t, kvh * g, d)), act)
+    kn = jnp.asarray(rng.normal(size=(t, kvh, d)), act)
+    vn = jnp.asarray(rng.normal(size=(t, kvh, d)), act)
+    if pool == "int8":
+        pools = tuple(jnp.asarray(rng.integers(-127, 128, shape),
+                                  jnp.int8) for _ in range(2))
+        scales = tuple(jnp.asarray(
+            rng.uniform(0.005, 0.03, shape[:-2] + (1, shape[-2])),
+            jnp.float32) for _ in range(2))
+    else:
+        pools = tuple(jnp.asarray(rng.normal(size=shape), pool)
+                      for _ in range(2))
+        scales = ()
+    return q, kn, vn, pools, scales
+
+
 class TestPagedAttentionKernel:
     def _case(self, seq_lens, page=8, kvh=2, g=2, d=16, maxp=4):
         rng = np.random.default_rng(0)
@@ -143,21 +164,8 @@ class TestPagedAttentionKernel:
         n_desc, t = len(q_len), int(q_len.sum())
         tables = 1 + rng.permutation(n_pages - 1)[:n_desc * maxp] \
             .reshape(n_desc, maxp).astype(np.int32)   # page 0 is the pad
-        act = jnp.float32 if pool == "float32" else jnp.bfloat16
-        q = jnp.asarray(rng.normal(size=(t, kvh * g, d)), act)
-        kn = jnp.asarray(rng.normal(size=(t, kvh, d)), act)
-        vn = jnp.asarray(rng.normal(size=(t, kvh, d)), act)
-        shape = (kvh, n_pages, page, d)
-        if pool == "int8":
-            pools = tuple(jnp.asarray(rng.integers(-127, 128, shape),
-                                      jnp.int8) for _ in range(2))
-            scales = tuple(jnp.asarray(
-                rng.uniform(0.005, 0.03, (kvh, n_pages, 1, page)),
-                jnp.float32) for _ in range(2))
-        else:
-            pools = tuple(jnp.asarray(rng.normal(size=shape), pool)
-                          for _ in range(2))
-            scales = ()
+        q, kn, vn, pools, scales = _ragged_operands(
+            rng, pool, t, kvh, g, d, (kvh, n_pages, page, d))
         positions = np.concatenate(
             [np.arange(kv, kv + ql) for kv, ql in zip(kv_len, q_len)])
         row_tables = np.repeat(tables, q_len, axis=0)
@@ -183,6 +191,50 @@ class TestPagedAttentionKernel:
         for a, b in zip(got[1:], want[1:]):        # pools (and scales)
             assert a.dtype == b.dtype
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("pool", ["float32", "bfloat16", "int8"])
+    def test_ragged_append_attend_stacked_pools_at_a_layer(self, pool):
+        """The stacked-pool form the engine's layer loop calls: pools
+        (and scale pools) ``[L, KVH, ...]`` whole, the layer a
+        scalar-prefetch operand.  At layer 2 of 3, passed as a traced
+        scalar, it equals the per-layer form run on that layer's
+        slice — attention blocks, pages and scales bit for bit — and
+        every other layer's pages come back untouched."""
+        from paddle_tpu.ops.pallas.paged_attention import \
+            ragged_paged_append_attend_raw
+        rng = np.random.default_rng(5)
+        n_layers, at = 3, 2
+        kvh, g, d, page, n_pages, maxp = 2, 3, 128, 128, 9, 2
+        q_len = np.array([1, 9, 0, 1], np.int32)
+        kv_len = np.array([130, 0, 0, 127], np.int32)
+        q_start = (np.cumsum(q_len) - q_len).astype(np.int32)
+        n_desc, t = len(q_len), int(q_len.sum())
+        tables = 1 + rng.permutation(n_pages - 1)[:n_desc * maxp] \
+            .reshape(n_desc, maxp).astype(np.int32)
+        q, kn, vn, pools, scales = _ragged_operands(
+            rng, pool, t, kvh, g, d, (n_layers, kvh, n_pages, page, d))
+        desc = (jnp.asarray(q_start), jnp.asarray(q_len),
+                jnp.asarray(kv_len), jnp.asarray(tables))
+        with pltpu.force_tpu_interpret_mode():
+            want = ragged_paged_append_attend_raw(
+                q, *(p[at] for p in pools), kn, vn, *desc,
+                *(s[at] for s in scales))
+            got = jax.jit(
+                lambda layer: ragged_paged_append_attend_raw(
+                    q, *pools, kn, vn, *desc, *scales, layer=layer))(
+                jnp.int32(at))
+        np.testing.assert_array_equal(
+            np.asarray(got[0].astype(jnp.float32)),
+            np.asarray(want[0].astype(jnp.float32)))
+        for a, b, was in zip(got[1:], want[1:], pools + scales):
+            assert a.shape == was.shape and a.dtype == was.dtype
+            np.testing.assert_array_equal(np.asarray(a[at]),
+                                          np.asarray(b))
+            assert (np.asarray(a[at]) != np.asarray(was[at])).any()
+            for other in range(n_layers):
+                if other != at:
+                    np.testing.assert_array_equal(
+                        np.asarray(a[other]), np.asarray(was[other]))
 
     def test_paged_write_places_token(self):
         rng = np.random.default_rng(1)

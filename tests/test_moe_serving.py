@@ -131,6 +131,53 @@ def test_int8_experts_grouped_matches_dense(model):
     assert isinstance(sh_dn, tuple) and sh_dn[0].dtype == "int8"
 
 
+@pytest.mark.parametrize("dispatch", ["grouped", "dense"])
+@pytest.mark.parametrize("quant", [False, True])
+def test_moe_ffn_reaches_a_layer_inside_flattened_stacks(dispatch, quant):
+    """The unified step's layer loop hands ``moe_ffn`` EVERY layer's
+    experts as one ``[L·E, ..]`` array (the engine's ``[L, E, ..]``
+    stack, flattened) plus ``expert_base = layer · E`` — traced, as
+    under the scan — so no layer is copied out for the grouped matmul.
+    Output and counts are bit-equal to the same call on that layer's
+    own ``[E, ..]`` stacks, grouped and dense, fp and int8 pairs."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.inference.moe_dispatch import MoEArch, moe_ffn
+    from paddle_tpu.quantization.ops import quantize_absmax_raw
+    rng = np.random.default_rng(11)
+    n_layers, e, h, f, t, at = 3, 4, 16, 24, 10, 2
+    arch = MoEArch(num_experts=e, top_k=2, norm_topk=False, capacity=0,
+                   shared=False, shared_gate=False, attn_bias=False,
+                   dispatch=dispatch)
+
+    def stack(*shape):
+        w = jnp.asarray(rng.normal(size=(n_layers, e) + shape), jnp.float32)
+        return quantize_absmax_raw(w, axis=2) if quant else w
+
+    def at_layer(w, i):
+        return jax.tree_util.tree_map(lambda a: a[i], w)
+
+    def flat(w):
+        return jax.tree_util.tree_map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), w)
+    experts = (stack(h, f), stack(h, f), stack(f, h))
+    zed = jnp.zeros((1, 1), jnp.float32)
+    rw = jnp.asarray(rng.normal(size=(h, e)), jnp.float32)
+    hn = jnp.asarray(rng.normal(size=(t, h)), jnp.float32)
+    live = jnp.arange(t) < t - 2
+
+    def run(ws, base):
+        return moe_ffn(hn, (rw,) + ws + (zed,) * 4, arch, live,
+                       expert_base=base)
+    want = jax.jit(lambda: run(tuple(at_layer(w, at) for w in experts),
+                               0))()
+    got = jax.jit(lambda i: run(tuple(flat(w) for w in experts),
+                                i * e))(jnp.int32(at))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert int(got[1].sum()) == (t - 2) * arch.top_k
+
+
 # -- capacity vs dropless accounting -------------------------------------------
 def test_capacity_vs_dropless_accounting(model):
     """Dropless drops nothing; a starved capacity factor (0.5 -> one

@@ -85,24 +85,39 @@ def _flash(grad):
     return build
 
 
-def _pools(sds, kvh, quant, n_pages=65, page=128, d=128):
-    pool = sds((kvh, n_pages, page, d), I8 if quant else BF16)
-    scales = (sds((kvh, n_pages, 1, page), F32),) * 2 if quant else ()
+def _pools(sds, kvh, quant, n_pages=65, page=128, d=128, lead=()):
+    pool = sds(lead + (kvh, n_pages, page, d), I8 if quant else BF16)
+    scales = (sds(lead + (kvh, n_pages, 1, page), F32),) * 2 \
+        if quant else ()
     return pool, scales
 
 
-def _ragged(h, kvh, quant):
+def _ragged_at_layer(*args):
+    """The ragged kernel on pools stacked over layers, the layer index
+    its last (traced) argument — the form the unified step calls."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        ragged_paged_append_attend_raw
+    return ragged_paged_append_attend_raw(*args[:-1], layer=args[-1])
+
+
+def _ragged(h, kvh, quant, layers=None):
+    """``layers``: the pools (and scale pools) stacked over that many
+    layers, through ``_ragged_at_layer``."""
     from paddle_tpu.ops.pallas.paged_attention import \
         ragged_paged_append_attend_raw
 
     def build(sds):
         t, s, maxp = 136, 136, 16          # max_seqs 8 + one page of rows
-        pool, scales = _pools(sds, kvh, quant)
+        pool, scales = _pools(sds, kvh, quant,
+                              lead=() if layers is None else (layers,))
         new = sds((t, kvh, 128), BF16)
         desc = sds((s,), I32)
-        return (ragged_paged_append_attend_raw,
-                (sds((t, h, 128), BF16), pool, pool, new, new, desc, desc,
-                 desc, sds((s, maxp), I32)) + scales,
+        args = (sds((t, h, 128), BF16), pool, pool, new, new, desc, desc,
+                desc, sds((s, maxp), I32)) + scales
+        if layers is None:
+            return (ragged_paged_append_attend_raw, args,
+                    ("ragged_paged_append_attend",))
+        return (_ragged_at_layer, args + (sds((), I32),),
                 ("ragged_paged_append_attend",))
     return build
 
@@ -174,6 +189,8 @@ CASES = {
     **{f"ragged_{h}_{kvh}_{'int8' if q else 'bf16'}": _ragged(h, kvh, q)
        for h, kvh in ((12, 4), (16, 4), (32, 8), (16, 16))
        for q in (False, True)},
+    **{f"ragged_stacked_16_16_{'int8' if q else 'bf16'}":
+       _ragged(16, 16, q, layers=4) for q in (False, True)},
     "decode_append_12_4": _decode(True),
     "decode_12_4": _decode(False),
     "moe_ffn_rows_64e_64rows": _moe_rows,
@@ -230,12 +247,17 @@ def test_expert_parallel_dispatch_compiles_for_four_chips(
     assert per_chip.argument_size_in_bytes < 16e9
 
 
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["one_layer", "stacked_pools"])
 def test_ragged_kernel_runs_per_shard_under_a_tp_mesh(
-        topo, no_compile_cache, on_tpu):
+        stacked, topo, no_compile_cache, on_tpu):
     """GSPMD cannot partition a Mosaic call ("wrap the call in a
     shard_map"): under the serving mesh the engine hands every shard
     its own heads through ``TPShardings.per_shard``.  tp=4 on the four
-    described devices, Llama 12:4 heads (one KV head per chip)."""
+    described devices, Llama 12:4 heads (one KV head per chip) — one
+    layer's pools, and the form the unified step uses: the pools
+    stacked over layers (KVH is then dim 1) with the layer index a
+    replicated traced scalar."""
     from paddle_tpu.distributed.sharding import TPShardings
     from paddle_tpu.ops.pallas.paged_attention import \
         ragged_paged_append_attend_raw
@@ -245,17 +267,134 @@ def test_ragged_kernel_runs_per_shard_under_a_tp_mesh(
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=sh._sharding(len(shape), dim))
     t, s, maxp = 136, 136, 16
-    pool, new = sds((4, 65, 128, 128), BF16, 0), sds((t, 4, 128), BF16, 1)
+    new = sds((t, 4, 128), BF16, 1)
     desc = sds((s,), I32)
-    fn = sh.per_shard(ragged_paged_append_attend_raw,
-                      (1, 0, 0, 1, 1, None, None, None, None), (2, 0, 0))
-    compiled = jax.jit(fn).lower(
-        sds((t, 12, 128), BF16, 1), pool, pool, new, new, desc, desc,
-        desc, sds((s, maxp), I32)).compile()
+    if stacked:
+        pool, kvh_dim = sds((3, 4, 65, 128, 128), BF16, 1), 1
+        layer, kernel = (sds((), I32),), _ragged_at_layer
+    else:
+        pool, kvh_dim = sds((4, 65, 128, 128), BF16, 0), 0
+        layer, kernel = (), ragged_paged_append_attend_raw
+    args = (sds((t, 12, 128), BF16, 1), pool, pool, new, new, desc, desc,
+            desc, sds((s, maxp), I32)) + layer
+    fn = sh.per_shard(
+        kernel, (1, kvh_dim, kvh_dim, 1, 1) + (None,) * (4 + len(layer)),
+        (2, kvh_dim, kvh_dim))
+    compiled = jax.jit(fn).lower(*args).compile()
     assert any("ragged_paged_append_attend" in op
                for op in _kernels_in(compiled.as_text()))
     # and without the wrapper the compiler refuses, which is why it is there
     with pytest.raises(NotImplementedError, match="shard_map"):
-        jax.jit(ragged_paged_append_attend_raw).lower(
-            sds((t, 12, 128), BF16, 1), pool, pool, new, new, desc, desc,
-            desc, sds((s, maxp), I32))
+        jax.jit(kernel).lower(*args)
+
+
+# -- whole step programs: the layer loop uses its operands where they lie -----
+
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+_ITEM = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+         "u8": 1, "pred": 1}
+
+
+def _bytes_moved(text):
+    """(bytes written, name) of every instruction of the optimised
+    program — in the entry computation and in loop bodies, not inside
+    fusions — that XLA named after a copy, a dynamic-slice or a
+    dynamic-update-slice (fusions carry the ops they were made of in
+    their names: ``dynamic-slice_bitcast_fusion.12``)."""
+    import re
+    rows, inside = [], ""
+    for line in text.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            inside = line.split()[0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?[a-z0-9]+\[[^=]*?) "
+                     r"[\w\-]+\(", line)
+        if not m or "fused" in inside or \
+                not any(k in m.group(1) for k in _MOVES):
+            continue
+        size = 0
+        for dt, dims in re.findall(r"([a-z0-9]+)\[([0-9,]*)\]", m.group(2)):
+            n = _ITEM.get(dt, 4)
+            for d in filter(None, dims.split(",")):
+                n *= int(d)
+            size += n
+        rows.append((size, m.group(1)))
+    return rows
+
+
+def _step_program(sds, moe, window, n_layers=2):
+    """``_paged_mixed_step`` / ``_paged_mixed_window`` at the serving
+    cell's widths (DeepSeekMoE-16B: hidden 2048, 16:16 heads x 128, 64
+    experts of 2048 x 1408 + a shared 2816; dense: InternLM2-1.8B's
+    16:8 heads, 8192), two layers, the cell's 32 slots x 2048 (513
+    pages: one layer's K pool is then larger than the ragged kernel's
+    own per-descriptor row blocks, which are S3's to shrink)."""
+    from paddle_tpu.inference import engine as E
+    from paddle_tpu.inference.moe_dispatch import MoEArch
+    h, nh, d, e, f, vocab = 2048, 16, 128, 64, 1408, 32000
+    kvh = nh if moe else 8
+    slots, max_len, page = 32, 2048, 128
+    n_pages, maxp = slots * (max_len // page) + 1, max_len // page
+    t = slots + page
+
+    def w(*shape, dtype=BF16):
+        return sds((n_layers,) + shape, dtype)
+    attn = (w(h, nh * d), w(h, kvh * d), w(h, kvh * d), w(nh * d, h))
+    if moe:
+        zed = w(1, 1, dtype=F32)
+        stack = (w(h), attn[0], zed, attn[1], zed, attn[2], zed, attn[3],
+                 w(h), w(h, e), w(e, h, f), w(e, h, f), w(e, f, h),
+                 w(h, 2 * f), w(h, 2 * f), w(2 * f, h), zed)
+        arch = MoEArch(num_experts=e, top_k=6, norm_topk=False,
+                       capacity=0, shared=True, shared_gate=False,
+                       attn_bias=False, dispatch="grouped")
+        smallest = e * h * f * 2
+    else:
+        stack = (w(h),) + attn + (w(h), w(h, 8192), w(h, 8192),
+                                  w(8192, h))
+        arch, smallest = None, None
+    pool = sds((n_layers, kvh, n_pages, page, d), BF16)
+    one_pool = kvh * n_pages * page * d * 2
+    rows, tbl = sds((t,), I32), sds((t, maxp), I32)
+    rope = (sds((max_len, d), F32),) * 2
+    args = [stack, sds((h,), BF16), sds((h, vocab), BF16),
+            sds((vocab, h), BF16), rope, pool, pool, None, None,
+            rows, rows, tbl, rows, rows, rows, tbl, rows, rows,
+            sds((2,), jnp.uint32), sds((), I32)]
+    kw = dict(eps=1e-6, kvh=kvh, head_dim=d, arch=arch)
+    if window:
+        args += [rows, rows, sds((), I32)]
+        lowered = E._paged_mixed_window.lower(*args, n_steps=8, **kw)
+    else:
+        lowered = E._paged_mixed_step.lower(*args, **kw)
+    return lowered, 2 * 2 * pool.size, min(filter(None,
+                                                  (smallest, one_pool)))
+
+
+@pytest.mark.parametrize("window", [False, True],
+                         ids=["mixed_step", "mixed_window"])
+@pytest.mark.parametrize("moe", [True, False], ids=["moe", "dense"])
+def test_step_program_moves_no_layer_of_weights_or_pool(
+        moe, window, one_chip, no_compile_cache, on_tpu):
+    """The guard on PR 26's gain.  Before it, the layer scan had every
+    layer's expert stacks and K/V pools copied out of their ``[L, ..]``
+    stacks for the two custom calls, wrote the pools back into its
+    stacked ``ys`` and copied those whole to the program's outputs
+    (60 % of the chip's busy time in both serving cells).  Now the
+    pools ride the carry whole and both kernels index the layer
+    themselves: no copy, dynamic-slice or dynamic-update-slice — plain
+    or as a fusion — may write as many bytes as one layer's smallest
+    expert stack or one layer's K pool."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    lowered, pool_bytes, limit = _step_program(sds, moe, window)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    ops = _kernels_in(text)
+    for name in ("ragged_paged_append_attend",) + (("gmm",) if moe else ()):
+        assert any(name in op for op in ops), (name, ops)
+    moved = sorted(_bytes_moved(text), reverse=True)
+    assert moved, "the parser found no copy or slice at all"
+    assert moved[0][0] < limit, (limit, moved[:6])
+    # the pools are updated where they lie: donated in, aliased out
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes

@@ -31,6 +31,7 @@ it).
 import http.client
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -70,6 +71,27 @@ def _mk_engine(model, **kw):
     cfg = dict(max_seqs=4, max_len=64, page_size=8)
     cfg.update(kw)
     return LLMEngine(model, **cfg)
+
+
+def _hold_mid_decode(scheds, until, after_tokens=2):
+    """Make "mid-decode" a condition and not a race against 48 tiny
+    steps: every scheduler's engine stops making progress once its
+    requests hold ``after_tokens`` generated tokens, until ``until()``
+    is true.  The serving loop keeps turning (``step`` returns ``{}``),
+    so polls are answered and marshalled commands — a drain — still
+    run on the loop thread."""
+    def hold(engine):
+        step = engine.step
+
+        def held():
+            if not until() and sum(len(r.out) for r in
+                                   engine.requests.values()) >= after_tokens:
+                time.sleep(0.001)
+                return {}
+            return step()
+        engine.step = held
+    for sc in scheds:
+        hold(sc.engine)
 
 
 class FakeClock:
@@ -495,11 +517,14 @@ def test_remote_drain_migrates_mid_decode(model, rig):
     N = 48
     want = _direct(model, [5, 9, 2, 14], N)
     fes, scheds, reps, router = rig()
+    drained = threading.Event()
+    _hold_mid_decode(scheds, drained.is_set)
     tr = Tracker()
     idx = router.submit("m1", [5, 9, 2, 14], max_new_tokens=N,
                         on_event=tr.cb("m1"))
     router.step()                              # pull some tokens
     moved = router.drain_replica(idx)
+    drained.set()
     assert moved == ["m1"]                     # still decoding: it moved
     router.run_until_idle(max_steps=8000)
     assert router.pop_result("m1") == want
@@ -519,6 +544,8 @@ def test_prober_kill_ejects_and_requeues(model, rig):
     N = 48
     want = _direct(model, [3, 3, 7], N)
     fes, scheds, reps, router = rig()
+    killed = threading.Event()
+    _hold_mid_decode(scheds, killed.is_set)
     tr = Tracker()
     idx = router.submit("k1", [3, 3, 7], max_new_tokens=N,
                         on_event=tr.cb("k1"))
@@ -526,6 +553,7 @@ def test_prober_kill_ejects_and_requeues(model, rig):
     prober = HealthProber(router, dead_after=1, timeout=1.0,
                           sleep=_NOSLEEP)
     fes[idx].kill()
+    killed.set()
     out = prober.probe_once()
     assert out[idx] == "ejected"
     assert router._owner["k1"] == 1 - idx
@@ -602,6 +630,9 @@ def test_chaos_no_lost_requests(model, rig, schedule):
     }[schedule]
     plan = FaultPlan(faults, sleep=_NOSLEEP)
     reps[0].set_fault_plan(plan)
+    # no request finishes before the schedule's first fault has fired
+    # (the crash waits for the sixth poll)
+    _hold_mid_decode(scheds, lambda: bool(plan.injected))
     prober = HealthProber(router, dead_after=2, timeout=1.0,
                           sleep=_NOSLEEP)
     tr = Tracker()
