@@ -11,7 +11,7 @@ predicate registry resolves a model instance to its spec by DUCK
 TYPING, never by class identity, so converted/quantized wrappers keep
 working as long as the attribute shape survives.
 
-Two backbones register here:
+Three backbones register here:
 
 - ``llama`` — ``LlamaForCausalLM``-shaped models (``model.llama.*``),
   the original engine contract, byte-identical programs.
@@ -20,17 +20,55 @@ Two backbones register here:
   The spec additionally carries the router geometry the engine folds
   into its static MoE arch (see inference/moe_dispatch.py).
 
+- ``qwen3_next`` — ``Qwen3NextForCausalLM``-shaped hybrids: decoder
+  layers of two KINDS in a fixed period (``linear``: a Gated-DeltaNet
+  mixer with a per-slot recurrent state; ``full``: gated softmax
+  attention over KV pages), each followed by an expert layer that may
+  hold a share of the published experts.  The spec carries the
+  per-layer kind and the held expert range; the engine serves it on
+  the unified path only and REFUSES at construction what it does not
+  carry for layers of several kinds: prefix caching, the split
+  programs (``unified_step=False``), a tp ``mesh=``, a
+  ``draft_model=``, int8 KV or weights, capacity-factor dispatch.
+
 Unsupported models get ONE clear error listing what would make them
 servable, instead of the old attribute crash.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, List, Optional
+from typing import Any, Callable, List, NamedTuple, Optional
 
 from ..common.errors import enforce
 
-__all__ = ["BackboneSpec", "register_backbone", "resolve_backbone"]
+__all__ = ["BackboneSpec", "HybridArch", "register_backbone",
+           "resolve_backbone"]
+
+
+class HybridArch(NamedTuple):
+    """Hashable static-jit description of a backbone whose layers are
+    of several kinds: the per-layer kind (``"linear"`` / ``"full"``),
+    the linear mixer's geometry under the config's own names
+    (``ops/pallas/gated_delta.py``'s helpers read either), and whether
+    the norms' stored weights are zero-centred (scale ``1 + w``, the
+    product taken in float32)."""
+    kinds: tuple
+    rotary_dim: int
+    linear_num_key_heads: int
+    linear_num_value_heads: int
+    linear_key_head_dim: int
+    linear_value_head_dim: int
+    linear_conv_kernel_dim: int
+    conv_channels: int
+    zero_centred_norm: bool
+
+    @property
+    def n_linear(self) -> int:
+        return sum(k == "linear" for k in self.kinds)
+
+    @property
+    def n_full(self) -> int:
+        return sum(k == "full" for k in self.kinds)
 
 
 @dataclass
@@ -40,7 +78,12 @@ class BackboneSpec:
     ``moe`` is ``None`` for dense-FFN backbones; for MoE backbones it
     is the router geometry dict (num_experts, top_k, norm_topk,
     capacity_factor, shared, shared_gate) the engine freezes into its
-    static dispatch arch and its capsule fingerprint."""
+    static dispatch arch and its capsule fingerprint (plus
+    ``expert_lo`` / ``experts_held`` when the layer holds a share).
+    ``hybrid`` is ``None`` for backbones whose layers are all of one
+    kind; with it comes ``layer_weights``, one weight dict a layer whose
+    leaves are the model's own arrays (the engine's layer loop uses them
+    where they lie instead of stacking a second copy)."""
     arch: str
     config: Any
     layers: List[Any]
@@ -51,6 +94,8 @@ class BackboneSpec:
     rope_sin: Any
     attn_bias: bool = False
     moe: Optional[dict] = None
+    hybrid: Optional[HybridArch] = None
+    layer_weights: Optional[tuple] = None
 
 
 # ordered (arch, predicate, builder) triples — first predicate match
@@ -86,7 +131,11 @@ def resolve_backbone(model) -> BackboneSpec:
         f"model exposes either a ``.llama`` submodule (Llama family) "
         f"or top-level ``layers``/``norm``/``embed_tokens``/``rope_*`` "
         f"with a shared-expert MoE ``mlp`` (Qwen2-MoE/DeepSeekMoE "
-        f"family); register new families with "
+        f"family), or the same with per-layer ``kind`` and "
+        f"``linear_attn``/``self_attn`` mixers (Qwen3-Next hybrid "
+        f"family: unified step only — no prefix caching, split "
+        f"programs, tp mesh, draft model, int8 or capacity-factor "
+        f"dispatch); register new families with "
         f"inference.backbone.register_backbone().")
 
 
@@ -157,5 +206,49 @@ def _build_qwen2_moe(model) -> BackboneSpec:
              "shared_gate": m0.shared_expert_gate is not None})
 
 
+# -- qwen3-next hybrid (linear + full layers, an expert share) -----------------
+
+def _is_qwen3_next(model) -> bool:
+    if hasattr(model, "llama") or not hasattr(model, "layers"):
+        return False
+    layers = list(model.layers)
+    return bool(layers) and all(
+        getattr(l, "kind", None) in ("linear", "full")
+        and hasattr(l, "linear_attn" if l.kind == "linear"
+                    else "self_attn")
+        and hasattr(getattr(l, "mlp", None), "experts")
+        for l in layers) and hasattr(model, "serving_layer_weights")
+
+
+def _build_qwen3_next(model) -> BackboneSpec:
+    c = model.config
+    layers = list(model.layers)
+    g0 = layers[0].mlp.gate
+    lo, n_held = c.held
+    return BackboneSpec(
+        arch="qwen3_next", config=c, layers=layers, norm=model.norm,
+        embed_tokens=model.embed_tokens, lm_head=model.lm_head,
+        rope_cos=model.rope_cos, rope_sin=model.rope_sin,
+        attn_bias=False,
+        moe={"num_experts": int(g0.num_experts), "top_k": int(g0.k),
+             "norm_topk": bool(g0.norm_topk_prob),
+             "capacity_factor": float(g0.capacity_factor),
+             "shared": True, "shared_gate": True,
+             "expert_lo": int(lo), "experts_held": int(n_held)},
+        hybrid=HybridArch(
+            kinds=tuple(l.kind for l in layers),
+            rotary_dim=int(c.rotary_dim),
+            linear_num_key_heads=int(c.linear_num_key_heads),
+            linear_num_value_heads=int(c.linear_num_value_heads),
+            linear_key_head_dim=int(c.linear_key_head_dim),
+            linear_value_head_dim=int(c.linear_value_head_dim),
+            linear_conv_kernel_dim=int(c.linear_conv_kernel_dim),
+            conv_channels=int(c.conv_channels),
+            zero_centred_norm=True),
+        layer_weights=model.serving_layer_weights())
+
+
 register_backbone("llama", _is_llama, _build_llama)
+# the more specific shape first: a hybrid also has ``layers`` + ``mlp``
+register_backbone("qwen3_next", _is_qwen3_next, _build_qwen3_next)
 register_backbone("qwen2_moe", _is_qwen2_moe, _build_qwen2_moe)

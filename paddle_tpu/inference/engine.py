@@ -152,16 +152,41 @@ class GenRequest:
         # position to prefill, and the submit time TTFT measures from
         self.pf_pos = 0
         self.t_submit: Optional[float] = None
+        # resume by recompute on the unified path: the token history
+        # (prompt + generated but the last) re-prefills through the
+        # step's chunk stream; the token it samples at the end is the
+        # one the request already has, and is not delivered again
+        self.replay: Optional[List[int]] = None
         # speculative decoding: the request's DRAFT KV slot in the
         # engine's second paged cache — attached lazily at its first
         # speculative window, released on retire/suspend/abort
         self.draft_slot: Optional[int] = None
+
+    @property
+    def pf_seq(self) -> List[int]:
+        """What the chunk stream prefills: the prompt, or the replayed
+        history of a request resumed by recompute."""
+        return self.prompt if self.replay is None else self.replay
 
 
 def _wout(w) -> int:
     """Output width of a stacked weight — fp array [.., in, out] or
     weight-only-int8 (values, scale) pair."""
     return w[0].shape[-1] if isinstance(w, tuple) else w.shape[-1]
+
+
+def _win(w) -> int:
+    """Input width of a weight [.., in, out] (fp array or int8 pair)."""
+    return w[0].shape[-2] if isinstance(w, tuple) else w.shape[-2]
+
+
+# the names of a scanned layer's weights, in stack order (the expert
+# matrices are not scanned: ``_mixed_forward`` closes over them)
+_DENSE_LAYER = ("in_norm", "q", "k", "v", "o", "post_norm", "gate", "up",
+                "down")
+_MOE_LAYER = ("in_norm", "q", "q_bias", "k", "k_bias", "v", "v_bias", "o",
+              "post_norm", "router", "shared_gate", "shared_up",
+              "shared_down", "shared_expert_gate")
 
 
 def _mm(x, w):
@@ -718,12 +743,14 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
                    k_pages, v_pages, k_scales, v_scales,
                    ids, positions, row_tables,
                    q_start, q_len, kv_len, desc_tables,
-                   desc_of_row, off_of_row, key, draw_base=0, *,
+                   desc_of_row, off_of_row, key, draw_base=0,
+                   rec_state=None, conv_state=None, desc_slot=None, *,
                    eps: float, kvh: int, head_dim: int,
                    transpose_head: bool = False,
                    strategy: str = "greedy_search", top_k: int = 0,
                    top_p: float = 1.0, temperature: float = 1.0,
-                   shardings=None, arch=None, return_probs=False):
+                   shardings=None, arch=None, return_probs=False,
+                   hybrid=None):
     """Un-jitted body of ``_paged_mixed_step`` — ALSO the per-step body
     of ``_paged_mixed_window``'s on-device loop, which is what makes
     the scanned window bit-identical to host-chained dispatch: the two
@@ -733,7 +760,25 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
     rows past their descriptor's ``q_len`` (padding) route nowhere,
     and each descriptor is one capacity page-group (``group_start =
     q_start[desc_of_row]``) so split-path prefill chunks rank
-    identically."""
+    identically.
+
+    ONE layer function, ``layer(kind, ...)``: norm -> mixer(kind) ->
+    residual -> norm -> FFN/MoE, the kind STATIC.  ``"full"`` is
+    softmax attention over the KV pages — every layer of the Llama and
+    Qwen2-MoE backbones, scanned over their stacked weights — with
+    what a layer's weights bring along: projection biases, a q/k norm
+    over the head (``q_norm`` / ``k_norm``), rotary on the first
+    ``rope`` -width dims only, an output gate packed beside q (a ``q``
+    projection twice as wide as ``o``'s input).  ``"linear"`` is the
+    Gated-DeltaNet mixer, whose per-slot recurrent state and conv
+    window ride the carry beside the pools.  A ``hybrid`` backbone
+    (``backbone.HybridArch``) gives the kinds; its ``stack`` is one
+    weight dict a layer (``BackboneSpec.layer_weights``: the model's
+    own arrays, so the expert matrices exist once on the device)
+    walked by a Python loop over the period, its KV pools hold the full
+    layers only, and ``rec_state`` / ``conv_state`` (one array a linear
+    layer) plus ``desc_slot`` [S] (each descriptor's sequence slot)
+    come in and go out after the counts."""
     import jax
     import jax.numpy as jnp
 
@@ -773,36 +818,39 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
     # program's outputs: 60 % of the chip's busy time in both serving
     # cells.  What stays scanned is what XLA's own matmuls consume.
     n_layers = k_pages.shape[0]
-    scanned = tuple(stack)
-    if arch is not None:
-        def flat(w):
-            if isinstance(w, tuple):
-                return tuple(flat(a) for a in w)
-            return w.reshape((-1,) + w.shape[2:])
-        egw, euw, edw = (flat(w) for w in scanned[10:13])
-        scanned = scanned[:10] + scanned[13:]
+    if hybrid is None:
+        scanned = tuple(stack)
+        if arch is not None:
+            def flat(w):
+                if isinstance(w, tuple):
+                    return tuple(flat(a) for a in w)
+                return w.reshape((-1,) + w.shape[2:])
+            egw, euw, edw = (flat(w) for w in scanned[10:13])
+            scanned = scanned[:10] + scanned[13:]
+    else:
+        from ..ops.pallas.gated_delta import (
+            causal_conv_step, gdn_inputs, gdn_output, ragged_gated_delta)
+        # the rows' view of the descriptors, for the conv window: each
+        # row's slot, how many of this step's rows of its sequence
+        # precede it (they are contiguous), live rows per slot, and the
+        # slots whose sequence starts in this step
+        n_slots = rec_state[0].shape[0] - 1
+        row_live = off_of_row < jnp.take(q_len, desc_of_row)
+        row_slot = jnp.where(row_live, jnp.take(desc_slot, desc_of_row),
+                             n_slots)
+        far = jnp.iinfo(jnp.int32).max
+        seq_start = jnp.full(n_slots + 1, far, jnp.int32).at[
+            jnp.where(q_len > 0, desc_slot, n_slots)].min(
+            jnp.where(q_len > 0, kv_len, far))
+        row_hist = jnp.where(row_live,
+                             positions - jnp.take(seq_start, row_slot), 0)
+        slot_rows = jnp.zeros(n_slots + 1, jnp.int32).at[row_slot].add(
+            row_live.astype(jnp.int32)).at[n_slots].set(0)
+        slot_fresh = (seq_start == 0) & (slot_rows > 0)
 
-    def layer(carry, xs):
-        hcur, pools = carry                    # the pools whole, [L, ..]
-        li, lp = xs                            # layer index + params
-        if arch is None:
-            iln, qw, kw, vw, ow, pln, gw, uw, dw = lp
-            qb = kb = vb = None
-        else:
-            (iln, qw, qb, kw, kb, vw, vb, ow, pln, rw,
-             sgw, suw, sdw, seg) = lp
-        hn = _nn.rms_norm(hcur, iln, epsilon=eps)
-        nh = _wout(qw) // head_dim
-        qx, kx, vx = _mm(hn, qw), _mm(hn, kw), _mm(hn, vw)
-        if arch is not None and arch.attn_bias:
-            qx, kx, vx = qx + qb, kx + kb, vx + vb
-        q = _tpc(qx.reshape(t, nh, head_dim), shardings, 1)
-        k = _tpc(kx.reshape(t, kvh, head_dim), shardings, 1)
-        v = _tpc(vx.reshape(t, kvh, head_dim), shardings, 1)
-        qf = q.astype(jnp.float32)
-        kf = k.astype(jnp.float32)
-        q = (qf * cos + rotate_half(qf) * sin).astype(q.dtype)
-        k = (kf * cos + rotate_half(kf) * sin).astype(k.dtype)
+    def attend(q, k, v, pools, li):
+        """Append this step's K/V rows to the pools at layer ``li`` and
+        attend every row over its own sequence's pages."""
         if on_tpu:
             # ragged kernel on the STACKED pools at layer ``li``:
             # per-descriptor [P, H, D] output blocks, gathered back to
@@ -853,25 +901,136 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
                 None if p is None else
                 jax.lax.dynamic_update_index_in_dim(p, new, li, 0)
                 for p, new in zip(pools, (kp, vp, ksp, vsp)))
-        attn = _tpc(attn, shardings, 1)
-        hcur = _tpc(hcur + _mm(
-            _tpc(attn.reshape(t, nh * head_dim), shardings), ow),
-            shardings)
-        hn = _nn.rms_norm(hcur, pln, epsilon=eps)
-        if arch is None:
-            ff = _tpc(_nn.silu(_mm(hn, gw)) * _mm(hn, uw),
-                      shardings, 1)
-            return (_tpc(hcur + _mm(_tpc(ff, shardings), dw),
-                         shardings), pools), None
-        ff, cnt = moe_ffn(hn, (rw, egw, euw, edw, sgw, suw, sdw, seg),
-                          arch, moe_live, moe_group, shardings,
-                          expert_base=li * arch.num_experts)
-        return (_tpc(hcur + ff, shardings), pools), cnt
+        return attn, pools
 
-    (x, (k_pages, v_pages, k_scales, v_scales)), cnts = jax.lax.scan(
-        layer, (x, (k_pages, v_pages, k_scales, v_scales)),
-        (jnp.arange(n_layers, dtype=jnp.int32), scanned))
-    x = _nn.rms_norm(x, norm_w, epsilon=eps)
+    def linear_mixer(hn, lp, rec, conv):
+        """Gated DeltaNet over the flat rows: projections, the causal
+        conv over each sequence's window, the ragged recurrence over
+        the descriptors (state read and written where it lies), the
+        gated norm, the output projection."""
+        f32 = jnp.float32
+        hv, cc = hybrid.linear_num_value_heads, hybrid.conv_channels
+        qkvz, ba = _mm(hn, lp["qkvz"]), _mm(hn, lp["ba"])
+        mixed, conv = causal_conv_step(
+            qkvz[:, :cc].astype(f32), lp["conv"].astype(f32), conv,
+            row_slot, row_hist, slot_rows, slot_fresh)
+        q, k, v, g, beta = gdn_inputs(mixed, ba[:, :hv], ba[:, hv:],
+                                      lp["A_log"], lp["dt_bias"], hybrid)
+        o, rec = ragged_gated_delta(
+            q, k, v, g, beta, rec, q_start, q_len, kv_len, desc_slot,
+            page_size=k_pages.shape[3])
+        y = gdn_output(o, qkvz[:, cc:], lp["norm"], eps).astype(hn.dtype)
+        return _mm(y, lp["o"]), rec, conv
+
+    def norm(x, w):
+        if hybrid is not None and hybrid.zero_centred_norm:
+            return _nn.rms_norm_zero_centred(x, w, epsilon=eps)
+        return _nn.rms_norm(x, w, epsilon=eps)
+
+    def rotary(xf):
+        """Half rotation over the first ``rot`` dims of the head (all
+        of them for the homogeneous backbones); float32 in and out."""
+        rot = cos.shape[-1]
+        if rot == head_dim:
+            return xf * cos + rotate_half(xf) * sin
+        xr = xf[..., :rot]
+        return jnp.concatenate(
+            [xr * cos + rotate_half(xr) * sin, xf[..., rot:]], -1)
+
+    def attention_mixer(hn, lp, pools, li):
+        """Softmax attention over the KV pages; what is optional comes
+        with the layer's weights."""
+        nh = _win(lp["o"]) // head_dim
+        gated = _wout(lp["q"]) == 2 * nh * head_dim
+        qx, kx, vx = _mm(hn, lp["q"]), _mm(hn, lp["k"]), _mm(hn, lp["v"])
+        if "q_bias" in lp and arch.attn_bias:
+            qx, kx, vx = (qx + lp["q_bias"], kx + lp["k_bias"],
+                          vx + lp["v_bias"])
+        if gated:
+            qx = qx.reshape(t, nh, 2 * head_dim)
+            qx, gate = qx[..., :head_dim], qx[..., head_dim:]
+        q = _tpc(qx.reshape(t, nh, head_dim), shardings, 1)
+        k = _tpc(kx.reshape(t, kvh, head_dim), shardings, 1)
+        v = _tpc(vx.reshape(t, kvh, head_dim), shardings, 1)
+        if "q_norm" in lp:
+            q, k = norm(q, lp["q_norm"]), norm(k, lp["k_norm"])
+        qf = q.astype(jnp.float32)
+        kf = k.astype(jnp.float32)
+        q = rotary(qf).astype(q.dtype)
+        k = rotary(kf).astype(k.dtype)
+        attn, pools = attend(q, k, v, pools, li)
+        if gated:
+            attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(hn.dtype)
+        attn = _tpc(attn, shardings, 1)
+        return _mm(_tpc(attn.reshape(t, nh * head_dim), shardings),
+                   lp["o"]), pools
+
+    def layer(kind, hcur, pools, li, lp, lin=None):
+        """norm -> mixer(kind) -> residual -> norm -> FFN/MoE.  ``li``
+        indexes the KV pools (and, scanned, the expert stacks); ``lp``
+        is the layer's weights by name (a scanned layer's tuple gets
+        its names here); ``lin`` is a linear layer's (state, conv
+        window)."""
+        if not isinstance(lp, dict):
+            lp = dict(zip(_DENSE_LAYER if arch is None else _MOE_LAYER,
+                          lp))
+        hn = norm(hcur, lp["in_norm"])
+        if kind == "linear":
+            mixed, *lin = linear_mixer(hn, lp, *lin)
+        else:
+            mixed, pools = attention_mixer(hn, lp, pools, li)
+        hcur = _tpc(hcur + mixed, shardings)
+        hn = norm(hcur, lp["post_norm"])
+        if arch is None:
+            ff = _tpc(_nn.silu(_mm(hn, lp["gate"])) * _mm(hn, lp["up"]),
+                      shardings, 1)
+            return (_tpc(hcur + _mm(_tpc(ff, shardings), lp["down"]),
+                         shardings), pools, lin), None
+        if "experts_gate" in lp:        # the layer's own [E_held, ..]
+            experts = (lp["experts_gate"], lp["experts_up"],
+                       lp["experts_down"])
+            base = 0
+        else:                           # every layer's, flattened
+            experts, base = (egw, euw, edw), li * arch.num_experts
+        ff, cnt = moe_ffn(
+            hn, (lp["router"],) + experts + (
+                lp["shared_gate"], lp["shared_up"], lp["shared_down"],
+                lp["shared_expert_gate"]),
+            arch, moe_live, moe_group, shardings, expert_base=base)
+        return (_tpc(hcur + ff, shardings), pools,
+                tuple(lin) if lin else None), cnt
+
+    pools = (k_pages, v_pages, k_scales, v_scales)
+    if hybrid is None:
+        def scan_body(carry, xs):
+            (hcur, pools, _), cnt = layer("full", *carry, *xs)
+            return (hcur, pools), cnt
+        (x, pools), cnts = jax.lax.scan(
+            scan_body, (x, pools),
+            (jnp.arange(n_layers, dtype=jnp.int32), scanned))
+        x = norm(x, norm_w)
+    else:
+        # the period's layers one by one: the KV pools hold the full
+        # layers only (``fi`` counts them), each linear layer has its
+        # own state and conv-window array (``ji``)
+        rec_state, conv_state = list(rec_state), list(conv_state)
+        cnts, fi, ji = [], 0, 0
+        for kind, lp in zip(hybrid.kinds, stack):
+            if kind == "linear":
+                (x, pools, lin), cnt = layer(
+                    kind, x, pools, None, lp,
+                    (rec_state[ji], conv_state[ji]))
+                rec_state[ji], conv_state[ji] = lin
+                ji += 1
+            else:
+                (x, pools, _), cnt = layer(kind, x, pools,
+                                           jnp.int32(fi), lp)
+                fi += 1
+            cnts.append(cnt)
+        cnts = jnp.stack(cnts)
+        x = norm(x, norm_w)
+    k_pages, v_pages, k_scales, v_scales = pools
     logits = _tpc(jnp.matmul(x, head_w.T) if transpose_head
                   else _mm(x, head_w), shardings)
     key, sub = split_step(key)
@@ -884,6 +1043,8 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
         out = (nxt, k_pages, v_pages, k_scales, v_scales, key)
     else:
         out = (nxt, k_pages, v_pages, k_scales, v_scales, key, cnts)
+    if hybrid is not None:
+        out = out + (tuple(rec_state), tuple(conv_state))
     if return_probs:
         # static flag (speculative verify, sampled mode): append the
         # per-row post-filter target distribution — the p surface the
@@ -900,18 +1061,21 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
     __import__("jax").jit,
     static_argnames=("eps", "kvh", "head_dim", "transpose_head",
                      "strategy", "top_k", "top_p", "temperature",
-                     "shardings", "arch", "return_probs"),
-    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales"))
+                     "shardings", "arch", "return_probs", "hybrid"),
+    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales",
+                     "rec_state", "conv_state"))
 def _paged_mixed_step(stack, norm_w, head_w, embed_w, rope,
                       k_pages, v_pages, k_scales, v_scales,
                       ids, positions, row_tables,
                       q_start, q_len, kv_len, desc_tables,
-                      desc_of_row, off_of_row, key, draw_base=0, *,
+                      desc_of_row, off_of_row, key, draw_base=0,
+                      rec_state=None, conv_state=None, desc_slot=None, *,
                       eps: float, kvh: int, head_dim: int,
                       transpose_head: bool = False,
                       strategy: str = "greedy_search", top_k: int = 0,
                       top_p: float = 1.0, temperature: float = 1.0,
-                      shardings=None, arch=None, return_probs=False):
+                      shardings=None, arch=None, return_probs=False,
+                      hybrid=None):
     """ONE compiled program for the whole MIXED prefill+decode batch
     (the ragged unified step): a flat token batch of T rows — every
     active decode slot contributes 1 row, each pending prefill chunk
@@ -935,35 +1099,44 @@ def _paged_mixed_step(stack, norm_w, head_w, embed_w, rope,
     writes land in the reserved pad page.  Returns (next_token [T],
     k_pages', v_pages', k_scales', v_scales', key') — the key chains
     across host-driven multi-token windows.  With an MoE ``arch`` the
-    return gains a trailing routed-token counts [L, E]."""
+    return gains a trailing routed-token counts [L, E]; with a
+    ``hybrid`` backbone the per-slot recurrent state and conv-window
+    arrays (``rec_state`` / ``conv_state``, donated and aliased like
+    the pools) follow the counts, and ``desc_slot`` [S] names each
+    descriptor's sequence slot."""
     return _mixed_forward(
         stack, norm_w, head_w, embed_w, rope,
         k_pages, v_pages, k_scales, v_scales,
         ids, positions, row_tables, q_start, q_len, kv_len,
         desc_tables, desc_of_row, off_of_row, key, draw_base,
+        rec_state, conv_state, desc_slot,
         eps=eps, kvh=kvh, head_dim=head_dim,
         transpose_head=transpose_head, strategy=strategy,
         top_k=top_k, top_p=top_p, temperature=temperature,
-        shardings=shardings, arch=arch, return_probs=return_probs)
+        shardings=shardings, arch=arch, return_probs=return_probs,
+        hybrid=hybrid)
 
 
 @functools.partial(
     __import__("jax").jit,
     static_argnames=("eps", "kvh", "head_dim", "transpose_head",
                      "strategy", "top_k", "top_p", "temperature",
-                     "n_steps", "shardings", "arch"),
-    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales"))
+                     "n_steps", "shardings", "arch", "hybrid"),
+    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales",
+                     "rec_state", "conv_state"))
 def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
                         k_pages, v_pages, k_scales, v_scales,
                         ids, positions, row_tables,
                         q_start, q_len, kv_len, desc_tables,
                         desc_of_row, off_of_row, key, draw_base,
-                        eos_ids, budgets, n_rows, *,
-                        eps: float, kvh: int, head_dim: int,
+                        eos_ids, budgets, n_rows,
+                        rec_state=None, conv_state=None, desc_slot=None,
+                        *, eps: float, kvh: int, head_dim: int,
                         transpose_head: bool = False,
                         strategy: str = "greedy_search", top_k: int = 0,
                         top_p: float = 1.0, temperature: float = 1.0,
-                        n_steps: int = 2, shardings=None, arch=None):
+                        n_steps: int = 2, shardings=None, arch=None,
+                        hybrid=None):
     """The unified path's ON-DEVICE decode window: up to ``n_steps``
     pure-decode steps of ``_mixed_forward`` — attend+append (the
     ragged kernel, aliases intact), sample, feed-back — chained in a
@@ -986,18 +1159,27 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
     v_pages', k_scales', v_scales', key') — plus a trailing
     routed-token counts [L, E] with an MoE ``arch`` (accumulated over
     the whole window, retired rows included, exactly like the
-    host-chained path's per-step accumulation)."""
+    host-chained path's per-step accumulation).  A ``hybrid``
+    backbone's recurrent state and conv windows ride the loop's carry
+    like the pools and come back after the counts."""
     import jax
     import jax.numpy as jnp
 
     t = ids.shape[0]
     live = jnp.arange(t) < n_rows
+    # decode row i is descriptor i; a backbone may have fewer
+    # descriptors than rows
+    live_desc = live if kv_len.shape[0] == t else \
+        jnp.arange(kv_len.shape[0]) < n_rows
     toks0 = jnp.zeros((n_steps, t), jnp.int32)
     state0 = (ids, positions, kv_len, k_pages, v_pages, k_scales,
               v_scales, key)
     if arch is not None:
         state0 = state0 + (jnp.zeros(
-            (stack[0].shape[0], arch.num_experts), jnp.int32),)
+            (len(stack) if hybrid is not None else stack[0].shape[0],
+             arch.num_experts), jnp.int32),)
+    if hybrid is not None:
+        state0 = state0 + (rec_state, conv_state)
     carry0 = (jnp.zeros((), jnp.int32), state0, toks0,
               jnp.logical_not(live), jnp.zeros(t, jnp.int32))
 
@@ -1011,15 +1193,17 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
         (ids, positions, kv_len, k_pages, v_pages, k_scales, v_scales,
          key) = state[:8]
         cacc = state[8] if arch is not None else None
+        rec, conv = state[9:11] if hybrid is not None else (None, None)
         res = _mixed_forward(
             stack, norm_w, head_w, embed_w, rope,
             k_pages, v_pages, k_scales, v_scales,
             ids, positions, row_tables, q_start, q_len, kv_len,
             desc_tables, desc_of_row, off_of_row, key, draw_base,
+            rec, conv, desc_slot,
             eps=eps, kvh=kvh, head_dim=head_dim,
             transpose_head=transpose_head, strategy=strategy,
             top_k=top_k, top_p=top_p, temperature=temperature,
-            shardings=shardings, arch=arch)
+            shardings=shardings, arch=arch, hybrid=hybrid)
         (nxt, k_pages, v_pages, k_scales, v_scales, key) = res[:6]
         nxt = nxt.astype(jnp.int32)
         toks = jax.lax.dynamic_update_slice(toks, nxt[None], (si, 0))
@@ -1033,11 +1217,13 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
         # rows keep position 0 / the pad table
         ids = jnp.where(live, nxt, ids)
         positions = jnp.where(live, positions + 1, positions)
-        kv_len = jnp.where(live, kv_len + 1, kv_len)
+        kv_len = jnp.where(live_desc, kv_len + 1, kv_len)
         state = (ids, positions, kv_len, k_pages, v_pages, k_scales,
                  v_scales, key)
         if arch is not None:
             state = state + (cacc + res[6],)
+        if hybrid is not None:
+            state = state + res[7:9]
         return (si + 1, state, toks, done, emitted)
 
     si, state, toks, done, emitted = jax.lax.while_loop(
@@ -1047,7 +1233,7 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
         return (toks, emitted, si, k_pages, v_pages, k_scales,
                 v_scales, key)
     return (toks, emitted, si, k_pages, v_pages, k_scales, v_scales,
-            key, state[8])
+            key) + state[8:]
 
 
 class LLMEngine:
@@ -1063,7 +1249,7 @@ class LLMEngine:
                  kv_dtype: Optional[str] = None,
                  weight_dtype: Optional[str] = None,
                  enable_metrics: bool = True,
-                 enable_prefix_caching: bool = True,
+                 enable_prefix_caching: Optional[bool] = None,
                  swap_pool_pages: Optional[int] = None,
                  unified_step: bool = True,
                  prefill_token_budget: Optional[int] = None,
@@ -1113,7 +1299,6 @@ class LLMEngine:
         self.max_len = max_len
         self.kv_dtype = kv_dtype
         self.weight_dtype = weight_dtype
-        self.enable_prefix_caching = bool(enable_prefix_caching)
         # ragged unified step: ONE compiled program serves every mixed
         # prefill+decode batch.  The STATIC prefill-token budget sizes
         # the flat batch (T = max_seqs + budget rows); the runtime
@@ -1142,6 +1327,56 @@ class LLMEngine:
         self.kvh = c.num_key_value_heads
         self.head_dim = c.hidden_size // c.num_attention_heads
         layers = spec.layers
+        # a backbone whose layers are of several kinds (linear-attention
+        # layers with a per-slot recurrent state beside full-attention
+        # layers over KV pages) is served by the unified step only, and
+        # what that path does not carry for it yet is refused HERE, one
+        # clear error each, never silently
+        self._hybrid = hy = spec.hybrid
+        if hy is not None:
+            def refuse(bad, what, why):
+                if bad:
+                    raise ValueError(
+                        f"LLMEngine({spec.arch}): {what} is not "
+                        f"supported for a backbone with linear-attention "
+                        f"layers — {why}")
+            refuse(enable_prefix_caching, "enable_prefix_caching=True",
+                   "a prefix hit would need the recurrent state at the "
+                   "prefix boundary, which the cache does not snapshot "
+                   "(it builds with prefix caching off)")
+            refuse(not unified_step, "unified_step=False",
+                   "only the unified mixed step and its decode window "
+                   "have the per-kind layer function")
+            refuse(mesh is not None, "mesh=",
+                   "the recurrent state pools and the DeltaNet mixer "
+                   "have no tensor-parallel plan")
+            refuse(draft_model is not None, "draft_model=",
+                   "speculative verify cannot roll a recurrent state "
+                   "back")
+            refuse(kv_dtype == "int8", "kv_dtype='int8'",
+                   "the gated-attention layers' int8 pools are not "
+                   "carried through")
+            refuse(weight_dtype == "int8", "weight_dtype='int8'",
+                   "the per-layer weight dicts carry no int8 scales")
+            refuse(not moe_dropless, "moe_dropless=False",
+                   "capacity-factor dispatch over a held expert share "
+                   "is not carried through")
+            self.head_dim = int(c.head_dim)
+            enable_prefix_caching = False
+            # descriptors a step.  A step's live ones are at most one a
+            # slot (a decode row, or the chunk that ends a prompt), the
+            # whole pages the budget holds, a chunk that starts
+            # mid-page and one the budget cuts: max_seqs + budget/P + 2.
+            # One more stays dead, for the padding rows.  The ragged
+            # kernel builds a page of row blocks for EVERY descriptor,
+            # so at 16k of context and head 256 one a row (the other
+            # backbones' T) would move a gigabyte a step for
+            # descriptors that are never used.
+            self._desc_cap = max_seqs + 3 + \
+                -(-self._pf_budget_static // page_size)
+        self.enable_prefix_caching = True \
+            if enable_prefix_caching is None \
+            else bool(enable_prefix_caching)
         # freeze the MoE router geometry into ONE hashable static jit
         # argument — None keeps every Llama program trace byte
         # identical to the pre-seam engine
@@ -1163,7 +1398,9 @@ class LLMEngine:
                 capacity=cap, shared=bool(m["shared"]),
                 shared_gate=bool(m["shared_gate"]),
                 attn_bias=bool(spec.attn_bias),
-                dispatch=moe_dispatch)
+                dispatch=moe_dispatch,
+                expert_lo=int(m.get("expert_lo", 0)),
+                experts_held=int(m.get("experts_held", 0)))
             if cap and unified_step:
                 # capacity ranks are defined per page-group, so the
                 # unified planner packs WHOLE page chunks in this
@@ -1208,10 +1445,11 @@ class LLMEngine:
         self.cache = PagedKVCache(
             n_pages=n_pages, page_size=page_size, n_kv_heads=self.kvh,
             head_dim=self.head_dim, max_seqs=max_seqs, max_len=max_len,
-            dtype=dtype, num_layers=len(layers),
+            dtype=dtype,
+            num_layers=len(layers) if hy is None else hy.n_full,
             kv_dtype="int8" if kv_dtype == "int8" else None,
             swap_pool_pages=swap_pool_pages,
-            shardings=self._shardings)
+            shardings=self._shardings, state_spec=hy)
 
         def stackp(get):
             return jnp.stack([get(l).value for l in layers])
@@ -1234,7 +1472,12 @@ class LLMEngine:
                 return quantize_absmax_raw(ws, axis=1)
             return ws
 
-        if self._arch is None:
+        if hy is not None:
+            # one weight dict a layer, the model's own arrays: nothing
+            # is stacked, so the expert matrices exist ONCE on the
+            # device (a second, stacked copy would not fit beside them)
+            self._stack = spec.layer_weights
+        elif self._arch is None:
             self._stack = (
                 stackp(lambda l: l.input_layernorm.weight),
                 stackw(lambda l: l.self_attn.q_proj),
@@ -1389,6 +1632,15 @@ class LLMEngine:
             self._moe_counts = np.zeros(
                 (len(layers), self._arch.num_experts), np.int64)
             self._moe_dropped = 0
+            self._expert_label_values = None     # set by _init_metrics
+            # slots routed to experts this engine does not hold (an
+            # expert share): counted, computed by no one here
+            self._moe_absent = 0
+        # what the linear layers' recurrence was given, per step: rows
+        # by kind and live descriptors (host counters, like the prefix
+        # stats — the registry series mirror them)
+        self.linear_stats = {"prefill_rows": 0, "decode_rows": 0,
+                             "descriptors": 0, "state_snapshots": 0}
         self._init_metrics(enable_metrics)
         # compile-watch registration: this engine's three jit entry
         # points and their warmup allowances (the split decode program
@@ -1457,6 +1709,12 @@ class LLMEngine:
             # contract covers both modes.
             "spec": None,
         }
+        if hy is not None:
+            # TOKEN-AFFECTING: the layer pattern and the held share
+            self._capsule_fp["hybrid"] = {
+                "kinds": list(hy.kinds),
+                "expert_lo": self._arch.expert_lo,
+                "experts_held": self._arch.n_held}
         self._spec = None
         if draft_model is not None:
             self._init_spec(draft_model, spec_k, dtype, page_size,
@@ -1735,14 +1993,49 @@ class LLMEngine:
                 "capacity-dropped slots are excluded (see "
                 "llm_engine_expert_dropped_tokens_total).",
                 ("engine", "layer", "expert"))
+            self._expert_label_values = [
+                [(eid, str(l), str(e))
+                 for e in range(self._arch.num_experts)]
+                for l in range(self._moe_counts.shape[0])]
             self._metrics["expert_dropped"] = reg.counter(
                 "llm_engine_expert_dropped_tokens_total",
                 "Routed token-slots dropped by the capacity factor "
                 "(always 0 dropless).", lbl).labels(eid)
+            self._metrics["expert_absent"] = reg.counter(
+                "llm_engine_expert_absent_slots_total",
+                "Routed token-slots whose expert lies outside the "
+                "share this engine holds (they add +0 here; the "
+                "deployment's other chips compute them).",
+                lbl).labels(eid)
             self._metrics["expert_imbalance"] = reg.gauge(
                 "llm_engine_expert_imbalance",
                 "Max/mean cumulative per-expert routed load across "
                 "layers (the MoE balance SLO; 1.0 = uniform).",
+                lbl).labels(eid)
+        if self._hybrid is not None:
+            rows = reg.counter(
+                "llm_engine_linear_rows_total",
+                "Token rows handed to the linear-attention recurrence, "
+                "summed over linear layers, by kind.",
+                ("engine", "kind"))
+            self._metrics["linear_rows_prefill"] = rows.labels(
+                eid, "prefill")
+            self._metrics["linear_rows_decode"] = rows.labels(
+                eid, "decode")
+            self._metrics["linear_descriptors"] = reg.counter(
+                "llm_engine_linear_descriptors_total",
+                "Live step descriptors handed to the linear-attention "
+                "recurrence, summed over linear layers (each reads and "
+                "writes one slot's state).", lbl).labels(eid)
+            self._metrics["state_bytes"] = reg.gauge(
+                "llm_engine_state_bytes",
+                "Device bytes of the per-slot recurrent state and "
+                "conv-window pools.", lbl).labels(eid)
+            self._metrics["state_bytes"].set(self.cache.state_bytes())
+            self._metrics["state_snapshots"] = reg.counter(
+                "llm_engine_state_snapshots_total",
+                "Per-slot recurrent-state snapshots moved between the "
+                "device and the host (suspend, resume, export).",
                 lbl).labels(eid)
         # compile-count gauges are process-global (the jit caches are),
         # unlabeled: any drift past 1 means a recompile regression —
@@ -1786,11 +2079,22 @@ class LLMEngine:
         self._moe_counts += cnt
         dropped = int(routed_slots) * cnt.shape[0] - int(cnt.sum())
         self._moe_dropped += dropped
+        absent = 0
+        if self._arch.experts_held:
+            lo = self._arch.expert_lo
+            absent = int(cnt.sum() - cnt[:, lo:lo + self._arch.n_held]
+                         .sum())
+            self._moe_absent += absent
         if self._metrics is not None:
-            fam = self._metrics["expert_tokens"]
-            eid = self.engine_id
-            for l, e in zip(*np.nonzero(cnt)):
-                fam.labels(eid, str(l), str(e)).inc(int(cnt[l, e]))
+            if absent:
+                self._metrics["expert_absent"].inc(absent)
+            # hundreds of labelled counts a dispatch (L x E): one lock,
+            # label tuples made once
+            names = self._expert_label_values
+            ls, es = np.nonzero(cnt)
+            self._metrics["expert_tokens"].inc_many(
+                (names[l][e], v) for l, e, v in zip(
+                    ls.tolist(), es.tolist(), cnt[ls, es].tolist()))
             if dropped:
                 self._metrics["expert_dropped"].inc(dropped)
             tot = self._moe_counts.sum(axis=0).astype(np.float64)
@@ -2298,6 +2602,11 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
+        enforce(self._hybrid is None,
+                "add_request prefills through the split prefill "
+                "program, which a backbone with linear-attention "
+                "layers does not have: admit with begin_request "
+                "(Scheduler(chunked_prefill=True) does)")
         t_admit = time.perf_counter()
         enforce(rid not in self.requests, f"duplicate request id {rid!r}")
         enforce(max_new_tokens >= 1, "max_new_tokens must be >= 1")
@@ -2703,6 +3012,10 @@ class LLMEngine:
             P = self.cache.page_size
             maxp = self.cache.page_table.shape[1]
             t_cap = self.max_seqs + self._pf_budget_static
+            hy = self._hybrid
+            # descriptors: one a row, or the hybrid backbone's cap (its
+            # last descriptor stays dead: the padding rows' own)
+            s_cap = t_cap if hy is None else self._desc_cap
             batch = list(self._active)
             n = len(batch)
 
@@ -2729,11 +3042,12 @@ class LLMEngine:
             cursor, desc_i, used = n, n, 0
             stop = False
             for req in self._prefilling:
-                plen = len(req.prompt)
+                plen = len(req.pf_seq)
                 pos = req.pf_pos
                 while pos < plen and used < budget:
                     chunk = min(P - pos % P, plen - pos)
-                    if whole_chunks and used + chunk > budget:
+                    if (whole_chunks and used + chunk > budget) or \
+                            (hy is not None and desc_i >= s_cap - 1):
                         stop = True
                         break
                     cl = min(chunk, budget - used)
@@ -2773,14 +3087,25 @@ class LLMEngine:
             ids = np.zeros(t_cap, np.int32)
             positions = np.zeros(t_cap, np.int32)
             row_tables = np.zeros((t_cap, maxp), np.int32)
-            q_start = np.zeros(t_cap, np.int32)
-            q_len = np.zeros(t_cap, np.int32)
-            kv_len = np.zeros(t_cap, np.int32)
-            desc_tables = np.zeros((t_cap, maxp), np.int32)
+            q_start = np.zeros(s_cap, np.int32)
+            q_len = np.zeros(s_cap, np.int32)
+            kv_len = np.zeros(s_cap, np.int32)
+            desc_tables = np.zeros((s_cap, maxp), np.int32)
             # padding rows point at their own (q_len == 0) descriptor,
             # whose kernel output block is zeroed — never garbage
             desc_of_row = np.arange(t_cap, dtype=np.int32)
             off_of_row = np.zeros(t_cap, np.int32)
+            if hy is not None:
+                # fewer descriptors than rows: decode row i is
+                # descriptor i, every other row the last (dead) one
+                # until a chunk claims it; and each descriptor's
+                # sequence slot, for the recurrent state (unused
+                # descriptors: the pad slot)
+                desc_of_row[n:] = s_cap - 1
+                desc_slot = np.full(s_cap, self.max_seqs, np.int32)
+                desc_slot[:n] = slots
+                for req, pos, cl, row0, d in plan:
+                    desc_slot[d] = req.slot
             if n:
                 ids[:n] = [r.out[-1] for r in batch]
                 lens = self.cache.seq_lens[slots]
@@ -2792,7 +3117,7 @@ class LLMEngine:
                 desc_tables[:n] = row_tables[:n]
             for req, pos, cl, row0, d in plan:
                 tbl = self.cache.page_table[req.slot]
-                ids[row0:row0 + cl] = req.prompt[pos:pos + cl]
+                ids[row0:row0 + cl] = req.pf_seq[pos:pos + cl]
                 positions[row0:row0 + cl] = np.arange(pos, pos + cl)
                 row_tables[row0:row0 + cl] = tbl
                 q_start[d] = row0
@@ -2814,6 +3139,14 @@ class LLMEngine:
         with _phase("engine.step.launch"):
             self._key, sub = jax.random.split(self._key)
             key = sub
+
+        def lin_args():
+            """The second kind of state, handed over whole (donated)."""
+            if hy is None:
+                return {}
+            return dict(rec_state=self.cache.rec_state,
+                        conv_state=self.cache.conv_state,
+                        desc_slot=jnp.asarray(desc_slot), hybrid=hy)
         t_win = time.perf_counter()
         if window:
             with _phase("engine.step.launch"):
@@ -2840,10 +3173,14 @@ class LLMEngine:
                     top_k=self.top_k, top_p=self.top_p,
                     temperature=self.temperature,
                     n_steps=nsteps,
-                    shardings=self._shardings, arch=self._arch)
+                    shardings=self._shardings, arch=self._arch,
+                    **lin_args())
                 (toks_d, _, steps_d, self.cache.k_pages,
                  self.cache.v_pages, self.cache.k_scales,
                  self.cache.v_scales, key) = res[:8]
+                if hy is not None:
+                    self.cache.rec_state, self.cache.conv_state = \
+                        res[9:11]
             with _phase("engine.step.wait"):
                 steps_done = int(jax.device_get(steps_d))
             if self._arch is not None:
@@ -2881,10 +3218,13 @@ class LLMEngine:
                         top_k=self.top_k, top_p=self.top_p,
                         temperature=self.temperature,
                         shardings=self._shardings,
-                        arch=self._arch)
+                        arch=self._arch, **lin_args())
                     (nxt, self.cache.k_pages, self.cache.v_pages,
                      self.cache.k_scales, self.cache.v_scales,
                      key) = res[:6]
+                    if hy is not None:
+                        self.cache.rec_state, self.cache.conv_state = \
+                            res[7:9]
                 if self._arch is not None:
                     with _phase("engine.step.moe_counts"):
                         # live rows this dispatch: n decode slots + the
@@ -2939,8 +3279,15 @@ class LLMEngine:
                 req.pf_pos = pos + cl
             for req, last_row in finishing:
                 first = int(toks_all[0][last_row])
-                plen = len(req.prompt)
+                plen = len(req.pf_seq)
                 self.cache.set_len(req.slot, plen)
+                if req.replay is not None:
+                    # a recompute-resume: state and pages are rebuilt,
+                    # the sampled token is the one it already holds
+                    req.replay = None
+                    self._prefilling.remove(req)
+                    self._active.append(req)
+                    continue
                 if self.enable_prefix_caching:
                     self.cache.register_prefix(req.slot, req.prompt,
                                                upto=(plen // P) * P)
@@ -2999,6 +3346,20 @@ class LLMEngine:
                 m["mixed_decode_slots"].set(n)
                 m["mixed_prefill_tokens"].set(used)
                 self._record_compiles()
+            if hy is not None:
+                # what the recurrence was given: the decode rows of
+                # every step of the window, the packed prefill rows,
+                # one live descriptor each decode row and chunk
+                st, nl = self.linear_stats, hy.n_linear
+                dec, pre = nl * n * steps_done, nl * used
+                st["decode_rows"] += dec
+                st["prefill_rows"] += pre
+                st["descriptors"] += nl * (n * steps_done + len(plan))
+                if self._metrics is not None:
+                    m["linear_rows_decode"].inc(dec)
+                    m["linear_rows_prefill"].inc(pre)
+                    m["linear_descriptors"].inc(
+                        nl * (n * steps_done + len(plan)))
         return out
 
     def has_work(self) -> bool:
@@ -3065,6 +3426,7 @@ class LLMEngine:
             req.slot = None
             req.suspended = True
             req.pf_pos = 0
+            req.replay = None
             if self._metrics is not None:
                 self._metrics["suspended"].inc()
                 self._metrics["queue_depth"].set(len(self._active))
@@ -3074,10 +3436,14 @@ class LLMEngine:
         # re-prefill at the next speculative window (lazy re-attach)
         # than to hold pages or pool space for
         self._spec_release(req)
-        with _tracing.span("engine.swap_out") as sp:
+        with _tracing.span("engine.swap_out") as sp, \
+                _phase("engine.state.snapshot"):
+            # (the phase: with a recurrent state the swap also copies
+            # the slot's state and conv windows to the host)
             req.swap_handle = self.cache.swap_out(req.slot)
             sp.set_attr("rid", str(rid))
             sp.set_attr("armed", req.swap_handle is not None)
+        self._note_state_snapshot(req.swap_handle is not None)
         req.slot = None
         req.suspended = True
         _capsule.get_capsule_store().event(
@@ -3128,9 +3494,11 @@ class LLMEngine:
             return "recompute"
         path = None
         if req.swap_handle is not None:
-            with _tracing.span("engine.swap_in") as sp:
+            with _tracing.span("engine.swap_in") as sp, \
+                    _phase("engine.state.restore"):
                 sp.set_attr("rid", str(rid))
                 slot = self.cache.swap_in(req.swap_handle, total)
+            self._note_state_snapshot(slot is not None)
             req.swap_handle = None             # consumed either way
             if slot is not None:
                 # KV restored byte-exact; length = prompt + generated
@@ -3138,6 +3506,20 @@ class LLMEngine:
                 # input — its KV is appended by the next step)
                 self.cache.set_len(slot, plen + len(req.out) - 1)
                 path = "swap_in"
+        if path is None and self._hybrid is not None:
+            # no split programs for this backbone: the history
+            # re-prefills through the unified step's chunk stream
+            # (state from zero, pages rewritten), then decode goes on
+            req.slot = self.cache.allocate(total)
+            req.replay = list(req.prompt) + list(req.out[:-1])
+            req.pf_pos = 0
+            req.suspended = False
+            self._prefilling.append(req)
+            _capsule.get_capsule_store().event(rid, "resume:recompute")
+            if self._metrics is not None:
+                self._metrics["resumed"].labels(
+                    self.engine_id, "recompute").inc()
+            return "recompute"
         if path is None:
             with RecordEvent("llm_engine.resume_recompute"):
                 slot = self._recompute_resume(req)
@@ -3150,6 +3532,14 @@ class LLMEngine:
             self._metrics["resumed"].labels(self.engine_id, path).inc()
             self._metrics["queue_depth"].set(len(self._active))
         return path
+
+    def _note_state_snapshot(self, moved: bool):
+        """One recurrent-state snapshot went to the host or came back
+        (backbones with linear layers only)."""
+        if moved and self._hybrid is not None:
+            self.linear_stats["state_snapshots"] += 1
+            if self._metrics is not None:
+                self._metrics["state_snapshots"].inc()
 
     def _recompute_resume(self, req):
         """Swapless resume: re-derive the suspended request's KV from
@@ -3420,10 +3810,21 @@ class LLMEngine:
                 "dispatch": self._arch.dispatch,
                 "shared_experts": self._arch.shared,
                 "expert_tokens": [int(v) for v in tot],
+                "expert_lo": self._arch.expert_lo,
+                "experts_held": self._arch.n_held,
+                "absent_slots": int(self._moe_absent),
                 "dropped_tokens": int(self._moe_dropped),
                 "imbalance": (float(tot.max() / tot.mean())
                               if tot.sum() else 0.0),
             }
+        if self._hybrid is not None:
+            hy = self._hybrid
+            snap["linear"] = dict(
+                self.linear_stats, layers=hy.n_linear,
+                full_layers=hy.n_full,
+                state_bytes=self.cache.state_bytes(),
+                state_bytes_per_slot=(self.cache.state_bytes()
+                                      // (self.max_seqs + 1)))
         if self._spec is not None:
             # speculative acceptance plane (host counters — present
             # with metrics off too): proposed counts DRAFT tokens

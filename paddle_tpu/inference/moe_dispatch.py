@@ -52,7 +52,15 @@ class MoEArch(NamedTuple):
     """Hashable static-jit MoE dispatch configuration.  ``capacity`` is
     the per-page-group per-expert slot cap (0 = dropless); ``dispatch``
     is ``"grouped"`` or ``"dense"`` (bit-identical on CPU — excluded
-    from the capsule fingerprint like tp)."""
+    from the capsule fingerprint like tp).
+
+    ``num_experts`` is the ROUTER's width.  An expert layer that holds
+    a share of them is told which: ``experts_held`` experts starting at
+    ``expert_lo`` (0 held = all of them).  The router still scores all
+    ``num_experts``, top-k and the renormalisation run over all k, and a
+    slot whose expert lies outside ``[expert_lo, expert_lo +
+    experts_held)`` computes nothing here and adds +0 to the combine —
+    on one chip the layer runs without its exchange."""
     num_experts: int
     top_k: int
     norm_topk: bool
@@ -61,6 +69,12 @@ class MoEArch(NamedTuple):
     shared_gate: bool
     attn_bias: bool
     dispatch: str
+    expert_lo: int = 0
+    experts_held: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 def _mm(x, w):
@@ -152,8 +166,11 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
     loop can use them where they lie.
 
     Returns ``(ffn_out [T, H], counts [E] int32)`` — counts are the
-    KEPT routed slots per expert (the observability plane's per-expert
-    load; dropless ⇒ sum == live·k)."""
+    KEPT routed slots per expert of the router's width (the
+    observability plane's per-expert load; dropless ⇒ sum == live·k).
+    With a held share (``arch.experts_held``) the counts of experts
+    outside it are the slots routed to the absent chips: counted, not
+    computed."""
     import jax
     import jax.numpy as jnp
 
@@ -164,6 +181,9 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
     rw, egw, euw, edw, sgw, suw, sdw, seg = mw
     t, h = hn.shape
     e, k = arch.num_experts, arch.top_k
+    # the experts whose matrices are here: all ``e``, or a share
+    n_held, lo = arch.n_held, arch.expert_lo
+    share = n_held != e
     f32 = jnp.float32
     xf = hn.astype(f32)
 
@@ -198,26 +218,33 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
         # dropless — or decode rows (singleton groups): top_k returns
         # distinct experts, so every in-group rank is 0 < capacity
         keep = live_slot
-    row_expert = jnp.where(keep, eidx, e)                   # e = dropped
+    if share:
+        # slots routed to experts held elsewhere take the dropped lane;
+        # the held ones index the stacks from ``expert - lo``
+        here = keep & (eidx >= lo) & (eidx < lo + n_held)
+        row_expert = jnp.where(here, eidx - lo, n_held)
+    else:
+        here = keep
+        row_expert = jnp.where(keep, eidx, e)               # e = dropped
     counts = jnp.sum(
         jax.nn.one_hot(eidx, e, dtype=jnp.int32)
         * keep[:, None].astype(jnp.int32), axis=0)          # [E]
 
     if arch.dispatch == "grouped":
         on_tpu = is_compiled_with_tpu()
-        tm = _auto_tm(e, t * k) if on_tpu else 8
+        tm = _auto_tm(n_held, t * k) if on_tpu else 8
         order, dest, valid_sorted, tile_expert, gcounts, m_pad = \
-            make_dropless_plan_rows(row_expert, e, tm)
+            make_dropless_plan_rows(row_expert, n_held, tm)
         xs = jnp.zeros((m_pad, h), f32).at[dest].set(
             xf[order // k], mode="drop")
         hg = _gmm_apply(xs, egw, tile_expert, gcounts, tm, on_tpu,
-                        shardings, expert_base, e)
+                        shardings, expert_base, n_held)
         hu = _gmm_apply(xs, euw, tile_expert, gcounts, tm, on_tpu,
-                        shardings, expert_base, e)
+                        shardings, expert_base, n_held)
         hs = (jax.nn.silu(hg.astype(f32))
               * hu.astype(f32)).astype(xs.dtype)
         ys = _gmm_apply(hs, edw, tile_expert, gcounts, tm, on_tpu,
-                        shardings, expert_base, e)
+                        shardings, expert_base, n_held)
         dest_safe = jnp.minimum(dest, m_pad - 1)
         y_sorted = jnp.where(valid_sorted[:, None],
                              ys[dest_safe].astype(f32), 0.0)
@@ -225,14 +252,14 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
     else:
         # dense per-expert reference: the same row-wise contractions
         # on the unsorted slot rows, dropped slots zeroed after
-        safe = jnp.minimum(eidx, e - 1) + expert_base
+        safe = jnp.clip(eidx - lo, 0, n_held - 1) + expert_base
         xdup = jnp.repeat(xf, k, axis=0)                    # [T*k, H]
         hg = _expert_rows_mm(xdup, egw, safe)
         hu = _expert_rows_mm(xdup, euw, safe)
         hs = (jax.nn.silu(hg.astype(f32))
               * hu.astype(f32)).astype(xdup.dtype)
         ys = _expert_rows_mm(hs, edw, safe)
-        y = jnp.where(keep[:, None], ys.astype(f32), 0.0)
+        y = jnp.where(here[:, None], ys.astype(f32), 0.0)
 
     out = jnp.einsum("tk,tkh->th", gate_vals.astype(f32),
                      y.reshape(t, k, h))                    # [T, H]

@@ -76,6 +76,20 @@ def _chain_hash(prev: bytes, tokens) -> bytes:
     return h.digest()
 
 
+def _put_slot(pool, slot, value):
+    """``pool[slot] = value`` in place (the pool is donated)."""
+    global _PUT_SLOT
+    if _PUT_SLOT is None:
+        import jax
+        _PUT_SLOT = jax.jit(
+            lambda p, s, v: p.at[s].set(v.astype(p.dtype)),
+            donate_argnums=(0,))
+    return _PUT_SLOT(pool, np.int32(slot), value)
+
+
+_PUT_SLOT = None
+
+
 class _SwapEntry:
     """Host-side record of one swapped-out sequence: per written page
     either ("data", j) — row j of the host arrays holds a private
@@ -83,11 +97,14 @@ class _SwapEntry:
     re-pin through the index at swap-in time."""
 
     __slots__ = ("plan", "k_host", "v_host", "k_scale_host",
-                 "v_scale_host", "n_host_pages")
+                 "v_scale_host", "n_host_pages", "state")
 
     def __init__(self, plan, k_host, v_host, k_scale_host,
-                 v_scale_host):
+                 v_scale_host, state=None):
         self.plan = plan
+        # (rec [L_lin, Hv, dk, dv], conv [L_lin, K-1, C]) host copies of
+        # the slot's recurrent state, where the cache holds one
+        self.state = state
         self.k_host = k_host
         self.v_host = v_host
         self.k_scale_host = k_scale_host
@@ -100,7 +117,8 @@ class PagedKVCache:
                  head_dim: int, max_seqs: int, max_len: int,
                  dtype=np.float32, num_layers: int = 1,
                  kv_dtype: Optional[str] = None,
-                 swap_pool_pages: int = 0, shardings=None):
+                 swap_pool_pages: int = 0, shardings=None,
+                 state_spec=None):
         import jax.numpy as jnp
         enforce(kv_dtype in (None, "int8"),
                 f"unsupported kv_dtype {kv_dtype!r} (None or 'int8')")
@@ -145,6 +163,33 @@ class PagedKVCache:
             if self.k_scales is not None:
                 self.k_scales = shardings.put(self.k_scales, 1)
                 self.v_scales = shardings.put(self.v_scales, 1)
+        # A SECOND kind of per-request state, for backbones with
+        # linear-attention layers (``state_spec``: backbone.HybridArch):
+        # per linear layer one float32 recurrent state per slot
+        # [max_seqs + 1, Hv, dk, dv] and the conv window of the last
+        # K - 1 inputs [max_seqs + 1, K - 1, C].  They are indexed by
+        # the SLOT (not by pages), live and die with it, ride the step
+        # programs' carry whole and are aliased in and out like the
+        # pools.  Nothing here zeroes them: a sequence whose first
+        # descriptor has ``kv_len == 0`` reads its state as zeros in
+        # the program (traced data), and the last slot is the pad slot
+        # that takes dead rows' writes.  One array a layer, so a layer
+        # loop uses each where it lies.
+        self.state_spec = state_spec
+        self.rec_state = self.conv_state = None
+        if state_spec is not None:
+            enforce(shardings is None,
+                    "recurrent state pools are not sharded over a mesh")
+            sp = state_spec
+            self.rec_state = tuple(
+                jnp.zeros((max_seqs + 1, sp.linear_num_value_heads,
+                           sp.linear_key_head_dim,
+                           sp.linear_value_head_dim), jnp.float32)
+                for _ in range(sp.n_linear))
+            self.conv_state = tuple(
+                jnp.zeros((max_seqs + 1, sp.linear_conv_kernel_dim - 1,
+                           sp.conv_channels), dtype)
+                for _ in range(sp.n_linear))
         self._free = list(range(n_pages - 1, 0, -1))   # page 0 = pad
         self._pages: Dict[int, List[int]] = {}
         self._lens = np.zeros(max_seqs, np.int32)
@@ -430,8 +475,9 @@ class PagedKVCache:
                     vs_host = np.asarray(jax.device_get(
                         self.v_scales[:, :, sel]))
             handle = next(self._swap_ids)
-            self._swap[handle] = _SwapEntry(plan, k_host, v_host,
-                                            ks_host, vs_host)
+            self._swap[handle] = _SwapEntry(
+                plan, k_host, v_host, ks_host, vs_host,
+                state=self.snapshot_state(slot))
             self._swap_used += len(data_pages)
             self._m_swap_out.inc(len(data_pages))
             self._m_swap_pool.set(self._swap_used)
@@ -517,6 +563,7 @@ class PagedKVCache:
                     jnp.asarray(entry.k_scale_host[:, :, src]))
                 self.v_scales = self.v_scales.at[:, :, sel].set(
                     jnp.asarray(entry.v_scale_host[:, :, src]))
+        self.restore_state(slot, entry.state)
         self._used[slot] = True
         self._pages[slot] = pages
         self._lens[slot] = 0                   # caller set_len()s
@@ -527,6 +574,41 @@ class PagedKVCache:
         self._m_swap_pool.set(self._swap_used)
         self._track_pages()
         return slot
+
+    # -- the recurrent state's snapshots ----------------------------------------
+    def state_bytes(self) -> int:
+        """Device bytes of the recurrent-state and conv-window pools."""
+        if self.rec_state is None:
+            return 0
+        return sum(int(a.nbytes) for a in self.rec_state
+                   + self.conv_state)
+
+    def snapshot_state(self, slot: int):
+        """Host copy of one slot's recurrent state over all linear
+        layers, ``None`` where the cache holds none."""
+        import jax
+        if self.rec_state is None:
+            return None
+        rec = np.stack([np.asarray(jax.device_get(a[slot]))
+                        for a in self.rec_state])
+        conv = np.stack([np.asarray(jax.device_get(a[slot]))
+                         for a in self.conv_state])
+        return rec, conv
+
+    def restore_state(self, slot: int, snap):
+        """Write a snapshot into ``slot`` of the pools, in place."""
+        if snap is None:
+            return
+        enforce(self.rec_state is not None,
+                "a swap entry carries a recurrent state; this cache "
+                "holds none")
+        rec, conv = snap
+        self.rec_state = tuple(
+            _put_slot(a, slot, rec[i])
+            for i, a in enumerate(self.rec_state))
+        self.conv_state = tuple(
+            _put_slot(a, slot, conv[i])
+            for i, a in enumerate(self.conv_state))
 
     def drop_swap(self, handle: Optional[int]) -> bool:
         """Free a swap entry without restoring it (the abort path for
@@ -548,7 +630,13 @@ class PagedKVCache:
     def _swap_geometry(self) -> dict:
         """The shape contract a migration blob must match: mismatched
         geometry would reinterpret page bytes, so import refuses it."""
+        sp = self.state_spec
         return {"page_size": self.page_size,
+                "state": None if sp is None else [
+                    sp.n_linear, sp.linear_num_value_heads,
+                    sp.linear_key_head_dim, sp.linear_value_head_dim,
+                    sp.linear_conv_kernel_dim - 1, sp.conv_channels,
+                    str(np.dtype(self.conv_state[0].dtype))],
                 "num_layers": self.num_layers,
                 "n_kv_heads": int(self.k_pages.shape[1]),
                 "head_dim": int(self.k_pages.shape[-1]),
@@ -623,6 +711,11 @@ class PagedKVCache:
             if ks_host is not None:
                 arrays["k_scale_host"] = ks_host
                 arrays["v_scale_host"] = vs_host
+        if entry.state is not None:
+            # the conv window travels as float32 (npz knows no bf16;
+            # the upcast is exact)
+            arrays["rec_state"] = entry.state[0]
+            arrays["conv_state"] = entry.state[1].astype(np.float32)
         buf = io.BytesIO()
         np.savez(buf, **arrays)
         self._m_swap_export.inc(meta["n_host_pages"])
@@ -648,6 +741,8 @@ class PagedKVCache:
             v_host = z["v_host"] if "v_host" in z else None
             ks_host = z["k_scale_host"] if "k_scale_host" in z else None
             vs_host = z["v_scale_host"] if "v_scale_host" in z else None
+            state = (z["rec_state"], z["conv_state"]) \
+                if "rec_state" in z else None
         n_host = int(meta["n_host_pages"])
         if not self.swap_pool_pages or \
                 self._swap_used + n_host > self.swap_pool_pages:
@@ -657,7 +752,7 @@ class PagedKVCache:
                 else ("data", int(val)) for kind, val in meta["plan"]]
         handle = next(self._swap_ids)
         self._swap[handle] = _SwapEntry(plan, k_host, v_host,
-                                        ks_host, vs_host)
+                                        ks_host, vs_host, state=state)
         self._swap_used += n_host
         self._m_swap_import.inc(n_host)
         self._m_swap_pool.set(self._swap_used)
@@ -800,7 +895,8 @@ class PagedKVCache:
                 "swap_in_pages": int(self._m_swap_in.value),
                 "swap_exported_pages": int(self._m_swap_export.value),
                 "swap_imported_pages": int(self._m_swap_import.value),
-                "swap_fallbacks": int(self._m_swap_fallback.value)}
+                "swap_fallbacks": int(self._m_swap_fallback.value),
+                "state_bytes": self.state_bytes()}
 
     def memory_rows(self) -> dict:
         """Memory-plane accounting row (observability.introspection):
@@ -813,7 +909,8 @@ class PagedKVCache:
         look 4× cheaper than it is); ``device_bytes_per_shard`` is
         what one chip's HBM actually holds (the /memz capacity-planning
         number), with ``tp`` alongside so the division is auditable."""
-        dev = int(self.k_pages.nbytes) + int(self.v_pages.nbytes)
+        dev = int(self.k_pages.nbytes) + int(self.v_pages.nbytes) \
+            + self.state_bytes()
         if self.k_scales is not None:
             dev += int(self.k_scales.nbytes) + int(self.v_scales.nbytes)
         tp = self._shardings.tp if self._shardings is not None else 1

@@ -146,6 +146,22 @@ class Counter(_Metric):
         with self._lock:
             self._value += n
 
+    def inc_many(self, items):
+        """``labels(*values).inc(n)`` for many label sets of a family
+        under ONE lock acquisition: ``items`` yields ``(label value
+        tuple — strings, as ``labels()`` would make them —, n)``.  For
+        hot paths that fold hundreds of labelled counts at once (the
+        engine's per-(layer, expert) load after every dispatch)."""
+        with self._lock:
+            kids = self._children
+            for values, n in items:
+                child = kids.get(values)
+                if child is None:
+                    child = self._new_child()
+                    child._lock = self._lock
+                    kids[values] = child
+                child._value += n
+
     @property
     def value(self) -> float:
         """This child's count; on a labeled family, the total across

@@ -202,6 +202,16 @@ def rms_norm(x, weight=None, epsilon=1e-6, axis=-1):
     return out
 
 
+def rms_norm_zero_centred(x, weight, epsilon=1e-6):
+    """RMSNorm whose stored weight is zero-centred (the scale is ``1 +
+    weight``) and whose product is taken in float32 before the cast
+    back — the Qwen3-Next family's norm, over the last axis."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + epsilon) \
+        * (1.0 + weight.astype(jnp.float32))
+    return y.astype(x.dtype)
+
+
 def group_norm(x, num_groups, weight=None, bias=None, epsilon=1e-5,
                data_format="NCHW"):
     if data_format == "NHWC":

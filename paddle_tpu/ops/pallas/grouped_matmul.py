@@ -416,16 +416,27 @@ def _auto_tm(e: int, n_rows: int) -> int:
     """Measured (v5e, round 4) row-tile table.  Big tiles win until
     per-expert padding dominates: at 8 experts (qwen2 shape, F=704)
     tm=512 with full-K blocks is best (26.9 TF/s, 1.36x XLA's dense
-    einsum); at 64 experts (DeepSeekMoE shape, H=2048, F=1408) the r5 sweep
-    moved the pick to tm=256 (whose smaller tile frees VMEM for a
+    comparator); at 64 experts (DeepSeekMoE-16B widths) tm=256 halves
+    tm=512's padding bound and runs 16.6 vs 11.2 TF/s (tm=256 with a
     full-K=2048 block: 140 TF/s vs tm=384/tk=1024's 121 and tm=512's
     80); the round-3 heuristic's tm=128 was 1.39x SLOWER than the
     dense comparator.  Tiny buffers fall back so the padding bound stays
-    sane."""
+    sane.
+
+    Every expert's rows are padded to a whole tile, so the tile halves
+    while ``e`` tiles would outnumber the rows; how far depends on how
+    many experts share them.  Up to 64 experts the tile stops at 128
+    (the table above); past that the padding is the buffer — 256 held
+    experts x 11 live rows each at tm=128 made 38.5k rows of 5.8k, every
+    ``gmm`` call reading and writing 13 x the rows it needs — and it
+    stops at 32 (my chip runs, PR 28: a serving step 49.7 -> 42.9 ms;
+    float32 rows tile by 8, and the kernel is bound by the expert
+    weights it streams either way)."""
     tm = 512 if e <= 16 else 256
-    while tm > 128 and e * tm > n_rows:
+    floor = 128 if e <= 64 else 32
+    while tm > floor and e * tm > n_rows:
         tm //= 2
-    return max(tm, 128)
+    return tm
 
 
 def _gate_up(xs, wg, wu, tile_expert, counts, *, tm, interpret, act):

@@ -436,6 +436,11 @@ class HTTPFrontend:
             # snapshot, router targets federate via /fleetz)
             "moe": eng.get("moe") if isinstance(eng, dict)
             else snap.get("moe"),
+            # linear-attention layers: what the recurrence was given,
+            # the state pools' bytes and snapshots (None for backbones
+            # whose layers are all softmax attention)
+            "linear": eng.get("linear") if isinstance(eng, dict)
+            else snap.get("linear"),
             # speculative decoding: acceptance headline (None without
             # a draft_model; router targets federate via /fleetz)
             "spec": eng.get("spec") if isinstance(eng, dict)

@@ -122,6 +122,19 @@ def _ragged(h, kvh, quant, layers=None):
     return build
 
 
+def _ragged_head_256(sds):
+    """The hybrid cell's geometry: 16:2 heads x 256, ``maxp`` 128 (16k
+    of context), 576 rows under 71 descriptors, the pool of ONE
+    full-attention layer through the stacked form."""
+    t, s, h, kvh, d, maxp = 576, 71, 16, 2, 256, 128
+    pool = sds((1, kvh, 64 * maxp + 1, 128, d), BF16)
+    new, desc = sds((t, kvh, d), BF16), sds((s,), I32)
+    return (_ragged_at_layer,
+            (sds((t, h, d), BF16), pool, pool, new, new, desc, desc, desc,
+             sds((s, maxp), I32), sds((), I32)),
+            ("ragged_paged_append_attend",))
+
+
 def _decode(append):
     from paddle_tpu.ops.pallas import paged_attention as pa
 
@@ -191,6 +204,7 @@ CASES = {
        for q in (False, True)},
     **{f"ragged_stacked_16_16_{'int8' if q else 'bf16'}":
        _ragged(16, 16, q, layers=4) for q in (False, True)},
+    "ragged_stacked_16_2_head256_maxp128": _ragged_head_256,
     "decode_append_12_4": _decode(True),
     "decode_12_4": _decode(False),
     "moe_ffn_rows_64e_64rows": _moe_rows,
@@ -398,3 +412,99 @@ def test_step_program_moves_no_layer_of_weights_or_pool(
     assert moved[0][0] < limit, (limit, moved[:6])
     # the pools are updated where they lie: donated in, aliased out
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def _hybrid_step_program(sds, window):
+    """``_paged_mixed_step`` / ``_paged_mixed_window`` for the hybrid
+    backbone at the cell ``hybrid_serve_longctx``'s sizes: one period
+    (linear, linear, linear, full) at Qwen3-Next-80B-A3B's widths, 256
+    of 512 experts held, vocabulary 75,968, and the engine settings of
+    the configuration's file (slots, context, page, prompt rows a
+    step)."""
+    import json
+    import os
+    from paddle_tpu.inference import engine as E
+    from paddle_tpu.inference.backbone import HybridArch
+    from paddle_tpu.inference.moe_dispatch import MoEArch
+    h, nh, kvh, d, held, f, vocab = 2048, 16, 2, 256, 256, 512, 75968
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "perfbench", "configs",
+            "qwen3_next_80b_a3b_4l.json"), encoding="utf-8") as fh:
+        eng = json.load(fh)["engine"]
+    slots, max_len, page, budget = (
+        eng["max_seqs"], eng["max_len"], eng["page_size"],
+        eng["prefill_token_budget"])
+    n_pages, maxp = slots * (max_len // page) + 1, max_len // page
+    t = slots + budget
+    hy = HybridArch(kinds=("linear",) * 3 + ("full",), rotary_dim=64,
+                    linear_num_key_heads=16, linear_num_value_heads=32,
+                    linear_key_head_dim=128, linear_value_head_dim=128,
+                    linear_conv_kernel_dim=4, conv_channels=8192,
+                    zero_centred_norm=True)
+
+    def w(*shape):
+        return sds(shape, BF16)
+
+    def layer(kind):
+        lay = dict(
+            in_norm=w(h), post_norm=w(h), router=w(h, 512),
+            experts_gate=w(held, h, f), experts_up=w(held, h, f),
+            experts_down=w(held, f, h), shared_gate=w(h, f),
+            shared_up=w(h, f), shared_down=w(f, h),
+            shared_expert_gate=w(h, 1))
+        if kind == "linear":
+            lay.update(qkvz=w(h, 12288), ba=w(h, 64), conv=w(4, 8192),
+                       A_log=w(32), dt_bias=w(32), norm=w(128),
+                       o=w(4096, h))
+        else:
+            lay.update(q=w(h, nh * 2 * d), k=w(h, kvh * d),
+                       v=w(h, kvh * d), o=w(nh * d, h), q_norm=w(d),
+                       k_norm=w(d))
+        return lay
+    arch = MoEArch(num_experts=512, top_k=10, norm_topk=True, capacity=0,
+                   shared=True, shared_gate=True, attn_bias=False,
+                   dispatch="grouped", expert_lo=0, experts_held=held)
+    pool = sds((1, kvh, n_pages, page, d), BF16)
+    n_desc = slots + 3 + budget // page           # the engine's cap
+    rows, tbl = sds((t,), I32), sds((t, maxp), I32)
+    desc, dtbl = sds((n_desc,), I32), sds((n_desc, maxp), I32)
+    rec = tuple(sds((slots + 1, 32, 128, 128), F32) for _ in range(3))
+    conv = tuple(sds((slots + 1, 3, 8192), BF16) for _ in range(3))
+    args = [tuple(layer(k) for k in hy.kinds), w(h), w(h, vocab),
+            w(vocab, h), (sds((max_len, 64), F32),) * 2, pool, pool,
+            None, None, rows, rows, tbl, desc, desc, desc, dtbl, rows,
+            rows, sds((2,), jnp.uint32), sds((), I32)]
+    kw = dict(eps=1e-6, kvh=kvh, head_dim=d, arch=arch, hybrid=hy)
+    state = 2 * pool.size * 2 + sum(a.size * 4 for a in rec) \
+        + sum(a.size * 2 for a in conv)
+    if window:
+        return E._paged_mixed_window.lower(
+            *args, rows, rows, sds((), I32), rec, conv, desc, n_steps=8,
+            **kw), state
+    return E._paged_mixed_step.lower(*args, rec, conv, desc, **kw), state
+
+
+@pytest.mark.parametrize("window", [False, True],
+                         ids=["mixed_step", "mixed_window"])
+def test_hybrid_step_program_fits_and_updates_its_state_in_place(
+        window, one_chip, no_compile_cache, on_tpu):
+    """The layer loop of several kinds at the new cell's real sizes:
+    the chip's compiler takes it (the ragged kernel at head 256 and
+    ``gmm`` over the held share inside), weights held once plus pools,
+    state and temporaries fit the chip, and BOTH kinds of per-request
+    state — KV pools and the recurrent state / conv windows — are
+    donated in and aliased out."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    lowered, state_bytes = _hybrid_step_program(sds, window)
+    compiled = lowered.compile()
+    ops = _kernels_in(compiled.as_text())
+    for name in ("ragged_paged_append_attend", "gmm"):
+        assert any(name in op for op in ops), (name, ops)
+    mem = compiled.memory_analysis()
+    print("hybrid step program:", mem.argument_size_in_bytes,
+          "B arguments,", mem.temp_size_in_bytes, "B temporaries")
+    assert mem.alias_size_in_bytes >= state_bytes
+    held_once = 4 * 3 * 256 * 2048 * 512 * 2
+    assert held_once < mem.argument_size_in_bytes < 10.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
