@@ -55,7 +55,12 @@ scalars, so ``mixed_compiles() == 1`` across arbitrary batch mixes,
 and a long prompt no longer stalls in-flight decodes (ROADMAP open
 item 2).  ``add_request`` remains the synchronous admission path;
 tokens are bit-identical between the unified and split programs
-(greedy decoding).
+(greedy decoding).  A unified step crosses the host-device boundary
+once each way: its descriptors go up as ONE packed buffer, its tokens,
+``steps_done``, window key and routed counts come back as ONE array
+whose copy is issued at launch (``_packed_mixed_step`` /
+``_packed_mixed_window``; ``llm_engine_host_transfers_total`` over
+``llm_engine_steps_total`` reads 1.0 each way).
 
 On-device decode windows (``scan_decode=True``, default): a
 ``steps_per_sync > 1`` pure-decode window runs as ONE compiled
@@ -92,8 +97,10 @@ reference (``moe_dispatch="dense"``), bit-identical on CPU.  Routing
 descriptors are traced data, so every one-compile invariant above
 survives; the programs additionally return per-layer-per-expert
 routed-token counts feeding the ``llm_engine_expert_tokens_total``
-observability plane.  Capacity-factor dispatch (``moe_dropless=
-False``) drops per page-group deterministically across the
+observability plane (folded on the host one dispatch later, behind the
+next launch: ``_note_expert_counts`` / ``_fold_expert_counts``).
+Capacity-factor dispatch (``moe_dropless=False``) drops per
+page-group deterministically across the
 split/unified/scanned paths (the unified planner packs whole page
 chunks in that mode); decode rows are singleton groups and never
 drop.
@@ -102,6 +109,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import threading
 import time
 from typing import Dict, List, Optional
 
@@ -1103,7 +1111,13 @@ def _paged_mixed_step(stack, norm_w, head_w, embed_w, rope,
     ``hybrid`` backbone the per-slot recurrent state and conv-window
     arrays (``rec_state`` / ``conv_state``, donated and aliased like
     the pools) follow the counts, and ``desc_slot`` [S] names each
-    descriptor's sequence slot."""
+    descriptor's sequence slot.
+
+    The engine's step launches this behind ``_packed_mixed_step``
+    (every int32 input above one upload, ``row_tables`` gathered from
+    ``desc_tables[desc_of_row]``, what the host reads one array);
+    speculative verify, the tests and the compile guards call it as it
+    stands."""
     return _mixed_forward(
         stack, norm_w, head_w, embed_w, rope,
         k_pages, v_pages, k_scales, v_scales,
@@ -1161,7 +1175,9 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
     the whole window, retired rows included, exactly like the
     host-chained path's per-step accumulation).  A ``hybrid``
     backbone's recurrent state and conv windows ride the loop's carry
-    like the pools and come back after the counts."""
+    like the pools and come back after the counts.  The engine launches
+    it behind ``_packed_mixed_window``: one upload in; tokens,
+    ``steps_done``, the window key and the counts out as one array."""
     import jax
     import jax.numpy as jnp
 
@@ -1234,6 +1250,164 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
                 v_scales, key)
     return (toks, emitted, si, k_pages, v_pages, k_scales, v_scales,
             key) + state[8:]
+
+
+# -- one upload in, one read-back out ------------------------------------------
+# What ``LLMEngine._step_mixed`` launches: the two programs above behind
+# a wrapper that takes every host-made int32 input as ONE buffer and
+# gives every host-read result as ONE small int32 array, so a step
+# crosses the host-device boundary once each way.
+
+@functools.lru_cache(maxsize=None)
+def _step_layout(t_cap: int, s_cap: int, maxp: int, hybrid: bool):
+    """Where each int32 input of a unified step lies in the step's one
+    upload: ``(((name, offset, shape), ...), size)``.  The geometry is what
+    the engine observes at construction (rows, descriptors — one a row,
+    or the hybrid backbone's cap —, pages a sequence), all static.
+    ``row_tables`` is not here: it is ``desc_tables[desc_of_row]``, row
+    for row, and the wrappers gather it on the device.  ``fresh`` says
+    whether the dispatch opens a window (its key is split off the
+    engine's) or continues a host-chained one (it is the chain key)."""
+    shapes = [(name, (t_cap,)) for name in (
+        "ids", "positions", "desc_of_row", "off_of_row", "eos_ids",
+        "budgets")]
+    shapes += [(name, (s_cap,)) for name in ("q_start", "q_len", "kv_len")]
+    if hybrid:
+        shapes.append(("desc_slot", (s_cap,)))
+    shapes.append(("desc_tables", (s_cap, maxp)))
+    shapes += [(name, ()) for name in ("draw_base", "n_rows", "fresh")]
+    fields, off = [], 0
+    for name, shape in shapes:
+        fields.append((name, off, shape))
+        off += int(np.prod(shape, dtype=np.int64))
+    return tuple(fields), off
+
+
+def _unpack_step(packed, geom, hybrid: bool):
+    """The upload taken apart at its static offsets (views on the host,
+    slices XLA folds into their consumers on the device)."""
+    fields, size = _step_layout(*geom, hybrid)
+    assert packed.shape == (size,), (packed.shape, size)
+    return {name: packed[off:off + int(np.prod(shape, dtype=np.int64))]
+            .reshape(shape) for name, off, shape in fields}
+
+
+def _descriptor_args(f):
+    """The unpacked upload as the inner programs take it, ``ids`` to
+    ``off_of_row``, with ``row_tables`` gathered from the descriptors'
+    tables (the CPU mirror reads it; the ragged kernel does not)."""
+    import jax.numpy as jnp
+
+    return (f["ids"], f["positions"],
+            jnp.take(f["desc_tables"], f["desc_of_row"], axis=0),
+            f["q_start"], f["q_len"], f["kv_len"], f["desc_tables"],
+            f["desc_of_row"], f["off_of_row"])
+
+
+def _pack_result(toks, steps_done, sub, counts, shardings):
+    """Everything the host reads of a step, as one int32 array: the
+    sampled tokens, ``steps_done``, the window key's two words, the
+    routed counts [L, E] (MoE)."""
+    import jax
+    import jax.numpy as jnp
+
+    parts = [toks.astype(jnp.int32).ravel(),
+             jnp.full((1,), steps_done, jnp.int32),
+             jax.lax.bitcast_convert_type(sub, jnp.int32).ravel()]
+    if counts is not None:
+        parts.append(counts.astype(jnp.int32).ravel())
+    return _tpc(jnp.concatenate(parts), shardings)
+
+
+def _unpack_result(host, n_toks: int, counts_shape):
+    """``_pack_result``'s inverse on the host copy: (tokens [n_toks],
+    steps_done, window-key fingerprint, counts or None)."""
+    words = host[n_toks + 1:n_toks + 3].view(np.uint32)
+    counts = None
+    if counts_shape is not None:
+        counts = host[n_toks + 3:].reshape(counts_shape)
+    return (host[:n_toks], int(host[n_toks]), [int(w) for w in words],
+            counts)
+
+
+@functools.partial(
+    __import__("jax").jit,
+    static_argnames=("geom", "eps", "kvh", "head_dim", "transpose_head",
+                     "strategy", "top_k", "top_p", "temperature",
+                     "shardings", "arch", "hybrid"),
+    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales",
+                     "rec_state", "conv_state"))
+def _packed_mixed_step(stack, norm_w, head_w, embed_w, rope,
+                       k_pages, v_pages, k_scales, v_scales,
+                       packed, key, rec_state=None, conv_state=None, *,
+                       geom, eps: float, kvh: int, head_dim: int,
+                       transpose_head: bool = False,
+                       strategy: str = "greedy_search", top_k: int = 0,
+                       top_p: float = 1.0, temperature: float = 1.0,
+                       shardings=None, arch=None, hybrid=None):
+    """``_paged_mixed_step`` with one transfer each way.  ``packed`` is
+    the step's ONE upload (``_step_layout``), ``key`` the engine's
+    sampling key, which never leaves the device: it is split here, the
+    same bits as ``jax.random.split`` on the host.  Returns (result —
+    ``_pack_result``, the ONE array the host reads —, k_pages',
+    v_pages', k_scales', v_scales', engine key', chain key') plus a
+    hybrid backbone's state arrays; pools and state donated and aliased
+    as in the inner program."""
+    import jax.numpy as jnp
+
+    f = _unpack_step(packed, geom, hybrid is not None)
+    next_key, sub = _sampling.split_step(key)
+    run = jnp.where(f["fresh"] != 0, sub, key)
+    res = _mixed_forward(
+        stack, norm_w, head_w, embed_w, rope,
+        k_pages, v_pages, k_scales, v_scales,
+        *_descriptor_args(f), run, f["draw_base"],
+        rec_state, conv_state, f.get("desc_slot"),
+        eps=eps, kvh=kvh, head_dim=head_dim,
+        transpose_head=transpose_head, strategy=strategy,
+        top_k=top_k, top_p=top_p, temperature=temperature,
+        shardings=shardings, arch=arch, hybrid=hybrid)
+    out = _pack_result(res[0], 1, run,
+                       None if arch is None else res[6], shardings)
+    return (out,) + res[1:5] + (_tpc(next_key, shardings),
+                                _tpc(res[5], shardings)) + res[7:9]
+
+
+@functools.partial(
+    __import__("jax").jit,
+    static_argnames=("geom", "eps", "kvh", "head_dim", "transpose_head",
+                     "strategy", "top_k", "top_p", "temperature",
+                     "n_steps", "shardings", "arch", "hybrid"),
+    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales",
+                     "rec_state", "conv_state"))
+def _packed_mixed_window(stack, norm_w, head_w, embed_w, rope,
+                         k_pages, v_pages, k_scales, v_scales,
+                         packed, key, rec_state=None, conv_state=None, *,
+                         geom, eps: float, kvh: int, head_dim: int,
+                         transpose_head: bool = False,
+                         strategy: str = "greedy_search", top_k: int = 0,
+                         top_p: float = 1.0, temperature: float = 1.0,
+                         n_steps: int = 2, shardings=None, arch=None,
+                         hybrid=None):
+    """``_paged_mixed_window`` with one transfer each way: same upload,
+    same returns as ``_packed_mixed_step`` (the result's tokens are
+    [n_steps, T] flattened, its ``steps_done`` the loop's count)."""
+    f = _unpack_step(packed, geom, hybrid is not None)
+    next_key, sub = _sampling.split_step(key)
+    res = _paged_mixed_window(
+        stack, norm_w, head_w, embed_w, rope,
+        k_pages, v_pages, k_scales, v_scales,
+        *_descriptor_args(f), sub, f["draw_base"],
+        f["eos_ids"], f["budgets"], f["n_rows"],
+        rec_state, conv_state, f.get("desc_slot"),
+        eps=eps, kvh=kvh, head_dim=head_dim,
+        transpose_head=transpose_head, strategy=strategy,
+        top_k=top_k, top_p=top_p, temperature=temperature,
+        n_steps=n_steps, shardings=shardings, arch=arch, hybrid=hybrid)
+    out = _pack_result(res[0], res[2], sub,
+                       None if arch is None else res[8], shardings)
+    return (out,) + res[3:7] + (_tpc(next_key, shardings),
+                                _tpc(res[7], shardings)) + res[9:11]
 
 
 class LLMEngine:
@@ -1420,9 +1594,12 @@ class LLMEngine:
         # mesh is None (the constraints vanish and the programs are
         # the single-chip ones byte for byte).
         self._shardings = None
+        self._step_sharding = None      # the step's upload: replicated
         if mesh is not None:
             from ..distributed.sharding import TPShardings
             self._shardings = TPShardings(mesh, tp_axis)
+            self._step_sharding = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec())
             tp = self._shardings.tp
             nh = c.num_attention_heads
             enforce(tp >= 1 and mesh.shape.get(tp_axis) is not None,
@@ -1636,6 +1813,40 @@ class LLMEngine:
             # slots routed to experts this engine does not hold (an
             # expert share): counted, computed by no one here
             self._moe_absent = 0
+        # dispatches' counts put aside (host numbers already) until
+        # ``_fold_expert_counts`` folds them behind the next launch;
+        # the lock is for a reader on another thread (``/statusz``)
+        self._counts_aside: list = []
+        self._counts_lock = threading.Lock()
+        self.count_folds = {"behind_launch": 0, "at_idle": 0}
+        # the unified step's crossings of the host-device boundary:
+        # uploads and blocking reads (1.0 each a step, mixed or window)
+        self.host_transfers = {"in": 0, "out": 0}
+        # ... and its ONE upload: a host buffer the engine owns, the
+        # descriptors views into it at static offsets.  A step copies
+        # ``blank`` over it and fills in what is live; it is written
+        # only after the step that read it has been read back.
+        hybrid = self._hybrid is not None
+        t_cap = max_seqs + self._pf_budget_static
+        self._step_geom = (t_cap, self._desc_cap if hybrid else t_cap,
+                           self.cache.page_table.shape[1])
+        blank = np.zeros(_step_layout(*self._step_geom, hybrid)[1],
+                         np.int32)
+        f = _unpack_step(blank, self._step_geom, hybrid)
+        # padding rows name a dead (q_len == 0) descriptor, whose
+        # kernel output block is zeroed and whose table is zeros: their
+        # own, or the hybrid's last; unused descriptors the pad slot
+        if hybrid:
+            f["desc_of_row"][:] = self._desc_cap - 1
+            f["desc_slot"][:] = max_seqs
+        else:
+            f["desc_of_row"][:] = np.arange(t_cap)
+        f["eos_ids"][:] = -1
+        f["budgets"][:] = 1
+        self._step_blank = blank
+        self._step_buf = blank.copy()
+        self._step_fields = _unpack_step(self._step_buf, self._step_geom,
+                                         hybrid)
         # what the linear layers' recurrence was given, per step: rows
         # by kind and live descriptors (host counters, like the prefix
         # stats — the registry series mirror them)
@@ -1894,7 +2105,23 @@ class LLMEngine:
         reg = get_registry()
         lbl = ("engine",)
         eid = self.engine_id
+        transfers = reg.counter(
+            "llm_engine_host_transfers_total",
+            "Crossings of the host-device boundary made by unified "
+            "steps: uploads (dir=in) and blocking reads (dir=out); "
+            "over llm_engine_steps_total, 1.0 each way.",
+            ("engine", "dir"))
+        folds = reg.counter(
+            "llm_engine_count_folds_total",
+            "Folds of the routed-expert counts put aside by earlier "
+            "dispatches: behind the next launch (the chip busy under "
+            "them), or at_idle (the engine left without work, or a "
+            "reader asked).", ("engine", "when"))
         self._metrics = {
+            "transfers_in": transfers.labels(eid, "in"),
+            "transfers_out": transfers.labels(eid, "out"),
+            "folds_behind_launch": folds.labels(eid, "behind_launch"),
+            "folds_at_idle": folds.labels(eid, "at_idle"),
             "ttft": reg.histogram(
                 "llm_engine_ttft_seconds",
                 "Time to first token: add_request() entry to the "
@@ -2066,26 +2293,55 @@ class LLMEngine:
         m["window_compiles"].set(self.window_compiles())
 
     def _note_expert_counts(self, counts, routed_slots: int):
-        """Fold one MoE dispatch's routed-token counts ([L, E] device
-        int32) into the host accounting and the registry.
+        """Put one MoE dispatch's routed-token counts ([L, E]: a slice
+        of the unified step's one read-back, or another path's device
+        array, read here) aside for ``_fold_expert_counts``.
         ``routed_slots`` is the number of live (row, top-k) slots the
-        dispatch routed PER LAYER — kept + capacity-dropped — so the
-        drop total is ``routed_slots·L − counts.sum()`` (identically 0
-        dropless).  One device_get per dispatch WINDOW, never per
-        token, same budget discipline as the latency metrics."""
-        import jax
+        dispatch routed PER LAYER — kept + capacity-dropped.  What an
+        earlier dispatch put aside is folded first, behind this one's
+        launch, so the counters are never more than one dispatch
+        behind.  One read per dispatch WINDOW, never per token."""
+        self._fold_expert_counts("behind_launch")
+        cnt = np.asarray(counts, np.int64)
+        with self._counts_lock:
+            self._counts_aside.append((cnt, int(routed_slots)))
 
-        cnt = np.asarray(jax.device_get(counts), np.int64)
-        self._moe_counts += cnt
-        dropped = int(routed_slots) * cnt.shape[0] - int(cnt.sum())
-        self._moe_dropped += dropped
-        absent = 0
-        if self._arch.experts_held:
-            lo = self._arch.expert_lo
-            absent = int(cnt.sum() - cnt[:, lo:lo + self._arch.n_held]
-                         .sum())
-            self._moe_absent += absent
+    def _crossed(self, way: str):
+        """A unified step crossed the host-device boundary: one upload
+        (``"in"``) or one blocking read (``"out"``)."""
+        self.host_transfers[way] += 1
         if self._metrics is not None:
+            self._metrics["transfers_" + way].inc()
+
+    def _fold_expert_counts(self, when: str):
+        """Fold the counts put aside into the host accounting and the
+        registry: ``_moe_counts``, dropped (``routed_slots·L −
+        counts.sum()``, identically 0 dropless) and absent slots, the
+        labelled counters, the imbalance gauge.  Host numbers only, so
+        the loop runs it with the chip busy (``when="behind_launch"``)
+        and a reader on another thread may (``"at_idle"``, like the
+        step that leaves the engine without work)."""
+        if not self._counts_aside:
+            return
+        with self._counts_lock:
+            aside, self._counts_aside = self._counts_aside, []
+            if not aside:
+                return
+            cnt = sum(c for c, _ in aside)
+            dropped = sum(r for _, r in aside) * cnt.shape[0] \
+                - int(cnt.sum())
+            self._moe_counts += cnt
+            self._moe_dropped += dropped
+            absent = 0
+            if self._arch.experts_held:
+                lo = self._arch.expert_lo
+                absent = int(cnt.sum()
+                             - cnt[:, lo:lo + self._arch.n_held].sum())
+                self._moe_absent += absent
+            self.count_folds[when] += 1
+            if self._metrics is None:
+                return
+            self._metrics["folds_" + when].inc()
             if absent:
                 self._metrics["expert_absent"].inc(absent)
             # hundreds of labelled counts a dispatch (L x E): one lock,
@@ -2802,13 +3058,22 @@ class LLMEngine:
         leaves — ``engine.step.plan`` / ``.pack`` / ``.launch`` /
         ``.wait`` / ``.moe_counts`` / ``.merge`` / ``.account`` —
         cover every line of the path, so a capture shows which host
-        stretch the chip idled under."""
+        stretch the chip idled under.  ``.moe_counts`` is the fold of
+        routed-expert counts a dispatch BEFORE put aside: it runs
+        behind a launch, or here, at once, when the step leaves the
+        engine without work."""
         with _phase("engine.step") as sp:
             if self._spec is not None:
-                return self._step_spec(sp)
-            if self.unified_step:
-                return self._step_mixed(sp)
-            return self._step_split(sp)
+                out = self._step_spec(sp)
+            elif self.unified_step:
+                out = self._step_mixed(sp)
+            else:
+                out = self._step_split(sp)
+            if self._counts_aside and not self.has_work():
+                # no launch will follow to fold the last counts behind
+                with _phase("engine.step.moe_counts"):
+                    self._fold_expert_counts("at_idle")
+            return out
 
     def _step_split(self, sp) -> Dict[object, List[int]]:
         """Decode up to ``steps_per_sync`` tokens for every active
@@ -3002,20 +3267,30 @@ class LLMEngine:
         (scan_decode, power-of-two buckets, early exit) or — with
         ``scan_decode=False`` — as host-chained single-token
         dispatches of the mixed program; both orders are bit-identical
-        by construction."""
+        by construction.
+
+        Either way a dispatch crosses the host-device boundary ONCE
+        EACH WAY.  In: every host-made descriptor is written into the
+        engine's one step buffer (``_step_layout``) and uploaded by one
+        ``device_put`` — ``row_tables`` is not, the program gathers it
+        from ``desc_tables[desc_of_row]``; the sampling key stays on
+        the device and is split inside the program.  Out: the program's
+        one small result (tokens, ``steps_done``, the window key's
+        words, the routed counts) starts its copy to the host at launch
+        and ``engine.step.wait`` is one blocking read.  The counts are
+        put aside and folded behind the NEXT launch
+        (``engine.step.moe_counts``: the chip busy under it), or at
+        once by ``step()`` when the engine is left without work."""
         import jax
-        import jax.numpy as jnp
 
         if not self._active and not self._prefilling:
             return {}
         with _phase("engine.step.plan"):
             P = self.cache.page_size
-            maxp = self.cache.page_table.shape[1]
-            t_cap = self.max_seqs + self._pf_budget_static
             hy = self._hybrid
-            # descriptors: one a row, or the hybrid backbone's cap (its
-            # last descriptor stays dead: the padding rows' own)
-            s_cap = t_cap if hy is None else self._desc_cap
+            # rows; descriptors: one a row, or the hybrid backbone's cap
+            # (its last descriptor stays dead: the padding rows' own)
+            t_cap, s_cap, _ = self._step_geom
             batch = list(self._active)
             n = len(batch)
 
@@ -3084,51 +3359,43 @@ class LLMEngine:
                         path="window" if window else "mixed")
 
         with _phase("engine.step.pack"):
-            ids = np.zeros(t_cap, np.int32)
-            positions = np.zeros(t_cap, np.int32)
-            row_tables = np.zeros((t_cap, maxp), np.int32)
-            q_start = np.zeros(s_cap, np.int32)
-            q_len = np.zeros(s_cap, np.int32)
-            kv_len = np.zeros(s_cap, np.int32)
-            desc_tables = np.zeros((s_cap, maxp), np.int32)
-            # padding rows point at their own (q_len == 0) descriptor,
-            # whose kernel output block is zeroed — never garbage
-            desc_of_row = np.arange(t_cap, dtype=np.int32)
-            off_of_row = np.zeros(t_cap, np.int32)
-            if hy is not None:
-                # fewer descriptors than rows: decode row i is
-                # descriptor i, every other row the last (dead) one
-                # until a chunk claims it; and each descriptor's
-                # sequence slot, for the recurrent state (unused
-                # descriptors: the pad slot)
-                desc_of_row[n:] = s_cap - 1
-                desc_slot = np.full(s_cap, self.max_seqs, np.int32)
-                desc_slot[:n] = slots
-                for req, pos, cl, row0, d in plan:
-                    desc_slot[d] = req.slot
+            # the step's ONE upload, written in place: the blank (dead
+            # descriptors, padding rows that name them) copied over
+            # what the last step left, then the live rows
+            np.copyto(self._step_buf, self._step_blank)
+            f = self._step_fields
+            ids, positions = f["ids"], f["positions"]
+            q_start, q_len, kv_len = f["q_start"], f["q_len"], f["kv_len"]
+            desc_tables = f["desc_tables"]
+            desc_of_row, off_of_row = f["desc_of_row"], f["off_of_row"]
+            f["fresh"][...] = 1
+            f["n_rows"][...] = n
             if n:
+                # decode row i is descriptor i, with its slot's table
                 ids[:n] = [r.out[-1] for r in batch]
                 lens = self.cache.seq_lens[slots]
                 positions[:n] = lens
-                row_tables[:n] = self.cache.page_table[slots]
-                q_start[:n] = np.arange(n)
+                desc_of_row[:n] = q_start[:n] = np.arange(n)
                 q_len[:n] = 1
                 kv_len[:n] = lens
-                desc_tables[:n] = row_tables[:n]
+                desc_tables[:n] = self.cache.page_table[slots]
+                if hy is not None:
+                    # each descriptor's sequence slot, for the
+                    # recurrent state
+                    f["desc_slot"][:n] = slots
             for req, pos, cl, row0, d in plan:
-                tbl = self.cache.page_table[req.slot]
                 ids[row0:row0 + cl] = req.pf_seq[pos:pos + cl]
                 positions[row0:row0 + cl] = np.arange(pos, pos + cl)
-                row_tables[row0:row0 + cl] = tbl
                 q_start[d] = row0
                 q_len[d] = cl
                 kv_len[d] = pos
-                desc_tables[d] = tbl
+                desc_tables[d] = self.cache.page_table[req.slot]
                 desc_of_row[row0:row0 + cl] = d
                 off_of_row[row0:row0 + cl] = np.arange(cl)
+                if hy is not None:
+                    f["desc_slot"][d] = req.slot
             if window:
-                eos_ids = np.full(t_cap, -1, np.int32)
-                budgets = np.ones(t_cap, np.int32)
+                eos_ids, budgets = f["eos_ids"], f["budgets"]
                 for i, r in enumerate(batch):
                     if r.eos is not None:
                         eos_ids[i] = r.eos
@@ -3136,115 +3403,75 @@ class LLMEngine:
 
         toks_all = []
         steps_done = nsteps
-        with _phase("engine.step.launch"):
-            self._key, sub = jax.random.split(self._key)
-            key = sub
-
-        def lin_args():
-            """The second kind of state, handed over whole (donated)."""
-            if hy is None:
-                return {}
-            return dict(rec_state=self.cache.rec_state,
-                        conv_state=self.cache.conv_state,
-                        desc_slot=jnp.asarray(desc_slot), hybrid=hy)
-        t_win = time.perf_counter()
+        kw = dict(geom=self._step_geom, eps=self.eps, kvh=self.kvh,
+                  head_dim=self.head_dim, transpose_head=self._tied,
+                  strategy=self.decode_strategy, top_k=self.top_k,
+                  top_p=self.top_p, temperature=self.temperature,
+                  shardings=self._shardings, arch=self._arch, hybrid=hy)
+        name, program = "engine.mixed_step", _packed_mixed_step
         if window:
+            name, program = "engine.mixed_window", _packed_mixed_window
+            kw["n_steps"] = nsteps
+        counts_shape = None if self._arch is None else \
+            self._moe_counts.shape
+        key = self._key
+        t_win = time.perf_counter()
+        # ONE dispatch — or, host-chained (scan_decode off), one a token
+        for si in range(1 if window else nsteps):
             with _phase("engine.step.launch"):
+                # a hybrid backbone's second kind of state (None
+                # otherwise) is handed over whole, donated like the pools
                 res = _insp.watched_call(
-                    "engine.mixed_window", _paged_mixed_window,
+                    name, program,
                     self._stack, self._norm_w, self._head_w,
                     self._embed_w, self._rope,
                     self.cache.k_pages, self.cache.v_pages,
                     self.cache.k_scales, self.cache.v_scales,
-                    jnp.asarray(ids), jnp.asarray(positions),
-                    jnp.asarray(row_tables),
-                    jnp.asarray(q_start), jnp.asarray(q_len),
-                    jnp.asarray(kv_len),
-                    jnp.asarray(desc_tables),
-                    jnp.asarray(desc_of_row),
-                    jnp.asarray(off_of_row), key,
-                    jnp.int32(0),
-                    jnp.asarray(eos_ids),
-                    jnp.asarray(budgets), jnp.int32(n),
-                    eps=self.eps, kvh=self.kvh,
-                    head_dim=self.head_dim,
-                    transpose_head=self._tied,
-                    strategy=self.decode_strategy,
-                    top_k=self.top_k, top_p=self.top_p,
-                    temperature=self.temperature,
-                    n_steps=nsteps,
-                    shardings=self._shardings, arch=self._arch,
-                    **lin_args())
-                (toks_d, _, steps_d, self.cache.k_pages,
-                 self.cache.v_pages, self.cache.k_scales,
-                 self.cache.v_scales, key) = res[:8]
+                    jax.device_put(self._step_buf, self._step_sharding),
+                    key, self.cache.rec_state, self.cache.conv_state,
+                    **kw)
+                (out_d, self.cache.k_pages, self.cache.v_pages,
+                 self.cache.k_scales, self.cache.v_scales, next_key,
+                 key) = res[:7]
                 if hy is not None:
-                    self.cache.rec_state, self.cache.conv_state = \
-                        res[9:11]
-            with _phase("engine.step.wait"):
-                steps_done = int(jax.device_get(steps_d))
-            if self._arch is not None:
+                    self.cache.rec_state, self.cache.conv_state = res[7:9]
+                if si == 0:
+                    self._key = next_key
+                # the copy follows the program on the device's own queue
+                out_d.copy_to_host_async()
+                self._crossed("in")
+            if self._counts_aside:
+                # the dispatch before this one's counts, the chip busy
                 with _phase("engine.step.moe_counts"):
-                    self._note_expert_counts(
-                        res[8], n * self._arch.top_k * steps_done)
+                    self._fold_expert_counts("behind_launch")
             with _phase("engine.step.wait"):
-                toks_np = np.asarray(jax.device_get(toks_d))
-            toks_all = [toks_np[j] for j in range(steps_done)]
-        else:
-            for si in range(nsteps):
-                with _phase("engine.step.launch"):
-                    res = _insp.watched_call(
-                        "engine.mixed_step", _paged_mixed_step,
-                        self._stack, self._norm_w,
-                        self._head_w, self._embed_w,
-                        self._rope,
-                        self.cache.k_pages, self.cache.v_pages,
-                        self.cache.k_scales,
-                        self.cache.v_scales,
-                        jnp.asarray(ids),
-                        jnp.asarray(positions),
-                        jnp.asarray(row_tables),
-                        jnp.asarray(q_start),
-                        jnp.asarray(q_len),
-                        jnp.asarray(kv_len),
-                        jnp.asarray(desc_tables),
-                        jnp.asarray(desc_of_row),
-                        jnp.asarray(off_of_row), key,
-                        jnp.int32(0),
-                        eps=self.eps, kvh=self.kvh,
-                        head_dim=self.head_dim,
-                        transpose_head=self._tied,
-                        strategy=self.decode_strategy,
-                        top_k=self.top_k, top_p=self.top_p,
-                        temperature=self.temperature,
-                        shardings=self._shardings,
-                        arch=self._arch, **lin_args())
-                    (nxt, self.cache.k_pages, self.cache.v_pages,
-                     self.cache.k_scales, self.cache.v_scales,
-                     key) = res[:6]
-                    if hy is not None:
-                        self.cache.rec_state, self.cache.conv_state = \
-                            res[7:9]
-                if self._arch is not None:
-                    with _phase("engine.step.moe_counts"):
-                        # live rows this dispatch: n decode slots + the
-                        # packed prefill tokens (used == 0 past the
-                        # first step — multi-step windows are pure
-                        # decode)
-                        self._note_expert_counts(
-                            res[6],
-                            (n + (used if si == 0 else 0))
-                            * self._arch.top_k)
-                with _phase("engine.step.wait"):
-                    nxt = np.asarray(jax.device_get(nxt))
-                toks_all.append(nxt)
-                if si + 1 < nsteps:
-                    with _phase("engine.step.pack"):
-                        # host-chained window (pure decode): feed each
-                        # slot's sampled token back as the next input
-                        ids[:n] = nxt[:n]
-                        positions[:n] += 1
-                        kv_len[:n] += 1
+                toks, done, words, counts = _unpack_result(
+                    jax.device_get(out_d), nsteps * t_cap if window
+                    else t_cap, counts_shape)
+                self._crossed("out")
+                if si == 0:
+                    sub_words = words
+                if window:
+                    steps_done = done
+                    toks = toks.reshape(nsteps, t_cap)
+                    toks_all = [toks[j] for j in range(steps_done)]
+                else:
+                    toks_all.append(toks)
+                if counts is not None:
+                    # live rows this dispatch: n decode slots, every
+                    # step of a window, + the packed prefill tokens
+                    # (multi-step windows are pure decode)
+                    self._note_expert_counts(
+                        counts, (n * done + used) * self._arch.top_k)
+            if si + 1 < nsteps and not window:
+                with _phase("engine.step.pack"):
+                    # host-chained window (pure decode): feed each
+                    # slot's sampled token back as the next input,
+                    # under the chain key
+                    ids[:n] = toks[:n]
+                    positions[:n] += 1
+                    kv_len[:n] += 1
+                    f["fresh"][...] = 0
         dt_win = time.perf_counter() - t_win
 
         with _phase("engine.step.merge"):
@@ -3321,8 +3548,7 @@ class LLMEngine:
                 rows = {r.rid: i for i, r in enumerate(batch)}
                 for req, last_row in finishing:
                     rows[req.rid] = int(last_row)
-                cs.on_window(out, _sampling.key_fingerprint(sub), nsteps,
-                             steps_done,
+                cs.on_window(out, sub_words, nsteps, steps_done,
                              "mixed_window" if window else "mixed_step",
                              rows=rows)
             # TPOT over-count fix: only DELIVERED decode positions
@@ -3737,6 +3963,7 @@ class LLMEngine:
         live under."""
         return _paged_decode_step._cache_size() + \
             _paged_mixed_step._cache_size() + \
+            _packed_mixed_step._cache_size() + \
             LLMEngine.window_compiles()
 
     @staticmethod
@@ -3747,11 +3974,16 @@ class LLMEngine:
         data) plus, with ``scan_decode``, one mixed-window program per
         power-of-two window bucket — bounded by the CompileWatch
         allowances declared at engine construction
-        (bit_length(steps_per_sync) − 1 buckets).  Like the other
-        counters this reads a process-global jit cache: assert deltas,
-        not absolutes, when several geometries share the process."""
-        return _paged_mixed_step._cache_size() + \
-            _paged_mixed_window._cache_size()
+        (bit_length(steps_per_sync) − 1 buckets).  What is counted is
+        what is launched: the one-transfer wrappers
+        (``_packed_mixed_step`` / ``_packed_mixed_window``) and the
+        inner step program where it is dispatched bare (speculative
+        verify).  Like the other counters this reads a process-global
+        jit cache: assert deltas, not absolutes, when several
+        geometries share the process."""
+        return _packed_mixed_step._cache_size() + \
+            _paged_mixed_step._cache_size() + \
+            _packed_mixed_window._cache_size()
 
     @staticmethod
     def window_compiles() -> int:
@@ -3762,7 +3994,7 @@ class LLMEngine:
         off or steps_per_sync == 1 (the degenerate window IS the plain
         step program)."""
         return _paged_decode_window._cache_size() + \
-            _paged_mixed_window._cache_size()
+            _packed_mixed_window._cache_size()
 
     def metrics_snapshot(self) -> dict:
         """One JSON-able dict with everything an operator tunes
@@ -3771,6 +4003,9 @@ class LLMEngine:
         invariants.  Works with ``enable_metrics=False`` too (the
         registry-backed series are then absent; compile counts and
         page stats are always available)."""
+        # the counts a running engine has put aside are host numbers
+        # already: a reader folds them first and touches no device
+        self._fold_expert_counts("at_idle")
         seen = self.prefix_stats["hit_tokens"] + \
             self.prefix_stats["miss_tokens"]
         snap = {
@@ -3790,6 +4025,8 @@ class LLMEngine:
             "prefilling_requests": len(self._prefilling),
             "suspended_requests": self.suspended_count(),
             "free_slots": self.free_slots(),
+            "host_transfers": dict(self.host_transfers),
+            "count_folds": dict(self.count_folds),
             "prefix_caching": dict(
                 self.prefix_stats,
                 enabled=self.enable_prefix_caching,

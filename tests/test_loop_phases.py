@@ -10,6 +10,10 @@ Contracts under test:
   ``Scheduler`` leaves, on ONE thread line, ``sched.step`` >
   ``engine.step`` > its leaves: nested, not overlapping, covering their
   parent but for microseconds, with the step's attributes;
+* an MoE engine's ``engine.step.moe_counts`` is the fold of the step
+  BEFORE's counts, behind this step's launch (ISSUE 29), there is one
+  ``engine.step.wait`` a step, and every phase the benchmark's
+  ``engine.idle_*`` specs sum is still written;
 * the counters at the same boundary (``steps``, ``step_prefill_tokens``)
   are exact for a fixed request list on the mixed, window and split
   paths.
@@ -174,6 +178,58 @@ def test_a_capture_shows_the_loops_phases_nested_on_one_line(model,
     # spans' own cost (a descheduled thread may stretch a few)
     assert statistics.median(eng_self) < 1e6      # ns
     assert statistics.median(sched_self) < 1e6
+
+
+def test_the_counts_fold_behind_the_next_steps_launch(tmp_path):
+    """The loop thread's line of an MoE engine: step N's routed counts
+    come back in step N's one read (``engine.step.wait``, once a step)
+    and are folded in step N+1, after its launch and before its wait —
+    the chip busy under them — but for the last step's, folded at once
+    when the engine is left without work."""
+    import json
+    import pathlib
+    from paddle_tpu.models.qwen2_moe import (Qwen2MoeForCausalLM,
+                                             qwen2_moe_tiny_config)
+    paddle.seed(0)
+    moe = Qwen2MoeForCausalLM(qwen2_moe_tiny_config())
+    moe.eval()
+    sched = Scheduler(LLMEngine(moe, max_seqs=4, max_len=64, page_size=8,
+                                steps_per_sync=4,
+                                enable_prefix_caching=False),
+                      max_queue=8, chunked_prefill=True)
+    sched.submit("warm", [1, 2, 3], max_new_tokens=6)
+    sched.run_until_idle()                    # compiles stay outside
+    for i, p in enumerate(PROMPTS):
+        sched.submit(f"r{i}", p, max_new_tokens=9)
+    lines = _capture(tmp_path, sched.run_until_idle)
+    (evs,) = [ln for ln in lines if any(e[0] == "sched.step" for e in ln)]
+    esteps = sorted((e for e in evs if e[0] == "engine.step"),
+                    key=lambda e: e[1])
+    leaves = ENGINE_LEAVES | {"engine.step.moe_counts"}
+    assert len(esteps) >= 6
+    for i, es in enumerate(esteps):
+        names = [k[0] for k in _children(evs, es, leaves)]
+        want = ["engine.step.plan", "engine.step.pack",
+                "engine.step.launch", "engine.step.moe_counts",
+                "engine.step.wait", "engine.step.merge",
+                "engine.step.account"]
+        if i == 0:                            # nothing put aside yet
+            want.remove("engine.step.moe_counts")
+        if i == len(esteps) - 1:              # its own, at idle
+            want.append("engine.step.moe_counts")
+        assert names == want, (i, names)
+    folds = sched.engine.count_folds
+    assert folds["at_idle"] >= 1 and \
+        folds["behind_launch"] >= len(esteps) - 1
+    # what the benchmark's idle metrics sum is what the loop writes
+    written = {e[0] for e in evs}
+    specs = pathlib.Path(__file__).parent.parent / "perfbench" / \
+        "layer_metrics"
+    summed = set()
+    for spec in specs.glob("engine.idle_*.json"):
+        summed.update(json.loads(spec.read_text()).get("spans", ()))
+    assert {"engine.step.wait", "engine.step.moe_counts",
+            "engine.step.launch"} <= summed <= written
 
 
 def test_the_front_ends_loop_adds_cmds_poll_and_wait(model, tmp_path):

@@ -40,8 +40,8 @@ def spy_on_the_logits():
     def spy(logits, *a, **kw):
         jax.debug.callback(keep, logits)
         return real(logits, *a, **kw)
-    COMPILED.update(step=E._paged_mixed_step._cache_size(),
-                    window=E._paged_mixed_window._cache_size())
+    COMPILED.update(step=E._packed_mixed_step._cache_size(),
+                    window=E._packed_mixed_window._cache_size())
     mp = pytest.MonkeyPatch()
     mp.setattr(sampling, "sample_logits", spy)
     yield
@@ -331,7 +331,7 @@ def test_one_mixed_step_program_and_the_declared_window_buckets(tiny):
         if i % 2:
             run(eng)
     run(eng)
-    assert E._paged_mixed_step._cache_size() - COMPILED["step"] == 1
-    assert E._paged_mixed_window._cache_size() - COMPILED["window"] == 3
+    assert E._packed_mixed_step._cache_size() - COMPILED["step"] == 1
+    assert E._packed_mixed_window._cache_size() - COMPILED["window"] == 3
     assert eng.metrics_snapshot()["prefill_compiles"] == \
         E._paged_prefill_chunk._cache_size()

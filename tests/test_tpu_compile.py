@@ -336,8 +336,9 @@ def _bytes_moved(text):
     return rows
 
 
-def _step_program(sds, moe, window, n_layers=2):
-    """``_paged_mixed_step`` / ``_paged_mixed_window`` at the serving
+def _step_program(sds, moe, window, packed, n_layers=2):
+    """``_paged_mixed_step`` / ``_paged_mixed_window`` — or, ``packed``,
+    the one-transfer wrappers the engine launches — at the serving
     cell's widths (DeepSeekMoE-16B: hidden 2048, 16:16 heads x 128, 64
     experts of 2048 x 1408 + a shared 2816; dense: InternLM2-1.8B's
     16:8 heads, 8192), two layers, the cell's 32 slots x 2048 (513
@@ -377,19 +378,28 @@ def _step_program(sds, moe, window, n_layers=2):
             sds((2,), jnp.uint32), sds((), I32)]
     kw = dict(eps=1e-6, kvh=kvh, head_dim=d, arch=arch)
     if window:
+        kw["n_steps"] = 8
+    if packed:
+        geom = (t, t, maxp)
+        args[9:] = [sds((E._step_layout(*geom, False)[1],), I32),
+                    sds((2,), jnp.uint32)]
+        lowered = (E._packed_mixed_window if window
+                   else E._packed_mixed_step).lower(*args, geom=geom, **kw)
+    elif window:
         args += [rows, rows, sds((), I32)]
-        lowered = E._paged_mixed_window.lower(*args, n_steps=8, **kw)
+        lowered = E._paged_mixed_window.lower(*args, **kw)
     else:
         lowered = E._paged_mixed_step.lower(*args, **kw)
     return lowered, 2 * 2 * pool.size, min(filter(None,
                                                   (smallest, one_pool)))
 
 
+@pytest.mark.parametrize("packed", [False, True], ids=["inner", "packed"])
 @pytest.mark.parametrize("window", [False, True],
                          ids=["mixed_step", "mixed_window"])
 @pytest.mark.parametrize("moe", [True, False], ids=["moe", "dense"])
 def test_step_program_moves_no_layer_of_weights_or_pool(
-        moe, window, one_chip, no_compile_cache, on_tpu):
+        moe, window, packed, one_chip, no_compile_cache, on_tpu):
     """The guard on PR 26's gain.  Before it, the layer scan had every
     layer's expert stacks and K/V pools copied out of their ``[L, ..]``
     stacks for the two custom calls, wrote the pools back into its
@@ -398,10 +408,12 @@ def test_step_program_moves_no_layer_of_weights_or_pool(
     pools ride the carry whole and both kernels index the layer
     themselves: no copy, dynamic-slice or dynamic-update-slice — plain
     or as a fusion — may write as many bytes as one layer's smallest
-    expert stack or one layer's K pool."""
+    expert stack or one layer's K pool.  The wrappers that take one
+    upload and give one read-back (ISSUE 29) are what the engine
+    launches, and are held to the same."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    lowered, pool_bytes, limit = _step_program(sds, moe, window)
+    lowered, pool_bytes, limit = _step_program(sds, moe, window, packed)
     compiled = lowered.compile()
     text = compiled.as_text()
     ops = _kernels_in(text)
@@ -414,8 +426,9 @@ def test_step_program_moves_no_layer_of_weights_or_pool(
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
 
 
-def _hybrid_step_program(sds, window):
-    """``_paged_mixed_step`` / ``_paged_mixed_window`` for the hybrid
+def _hybrid_step_program(sds, window, packed):
+    """``_paged_mixed_step`` / ``_paged_mixed_window`` (``packed``: the
+    one-transfer wrappers the engine launches) for the hybrid
     backbone at the cell ``hybrid_serve_longctx``'s sizes: one period
     (linear, linear, linear, full) at Qwen3-Next-80B-A3B's widths, 256
     of 512 experts held, vocabulary 75,968, and the engine settings of
@@ -478,16 +491,25 @@ def _hybrid_step_program(sds, window):
     state = 2 * pool.size * 2 + sum(a.size * 4 for a in rec) \
         + sum(a.size * 2 for a in conv)
     if window:
+        kw["n_steps"] = 8
+    if packed:
+        geom = (t, n_desc, maxp)
+        args[9:] = [sds((E._step_layout(*geom, True)[1],), I32),
+                    sds((2,), jnp.uint32), rec, conv]
+        return (E._packed_mixed_window if window
+                else E._packed_mixed_step).lower(
+            *args, geom=geom, **kw), state
+    if window:
         return E._paged_mixed_window.lower(
-            *args, rows, rows, sds((), I32), rec, conv, desc, n_steps=8,
-            **kw), state
+            *args, rows, rows, sds((), I32), rec, conv, desc, **kw), state
     return E._paged_mixed_step.lower(*args, rec, conv, desc, **kw), state
 
 
+@pytest.mark.parametrize("packed", [False, True], ids=["inner", "packed"])
 @pytest.mark.parametrize("window", [False, True],
                          ids=["mixed_step", "mixed_window"])
 def test_hybrid_step_program_fits_and_updates_its_state_in_place(
-        window, one_chip, no_compile_cache, on_tpu):
+        window, packed, one_chip, no_compile_cache, on_tpu):
     """The layer loop of several kinds at the new cell's real sizes:
     the chip's compiler takes it (the ragged kernel at head 256 and
     ``gmm`` over the held share inside), weights held once plus pools,
@@ -496,7 +518,7 @@ def test_hybrid_step_program_fits_and_updates_its_state_in_place(
     donated in and aliased out."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    lowered, state_bytes = _hybrid_step_program(sds, window)
+    lowered, state_bytes = _hybrid_step_program(sds, window, packed)
     compiled = lowered.compile()
     ops = _kernels_in(compiled.as_text())
     for name in ("ragged_paged_append_attend", "gmm"):
