@@ -5,8 +5,8 @@ The reference's distributed tests run multi-process on one host with Gloo
 ``--xla_force_host_platform_device_count=8`` so mesh/sharding logic is
 exercised without real chips.  This must run before the first ``import jax``
 anywhere.  The pin holds for the test session only: ``chip_smoke.py``,
-``bench.py`` and ``__graft_entry__.py`` do NOT import this and take the
-platform JAX finds.  A pytest process therefore never holds a chip, and a
+``perfbench.run`` and ``__graft_entry__.py`` do NOT import this and take
+the platform JAX finds.  A pytest process therefore never holds a chip, and a
 child that a test starts with ``JAX_PLATFORMS=tpu`` is the one process
 that may.
 """

@@ -25,11 +25,11 @@ Three backbones register here:
   mixer with a per-slot recurrent state; ``full``: gated softmax
   attention over KV pages), each followed by an expert layer that may
   hold a share of the published experts.  The spec carries the
-  per-layer kind and the held expert range; the engine serves it on
-  the unified path only and REFUSES at construction what it does not
-  carry for layers of several kinds: prefix caching, the split
-  programs (``unified_step=False``), a tp ``mesh=``, a
-  ``draft_model=``, int8 KV or weights, capacity-factor dispatch.
+  per-layer kind and the held expert range; the engine admits it
+  through ``begin_request`` only and REFUSES at construction what it
+  does not carry for layers of several kinds: prefix caching, a tp
+  ``mesh=``, a ``draft_model=``, int8 KV or weights, capacity-factor
+  dispatch.
 
 Unsupported models get ONE clear error listing what would make them
 servable, instead of the old attribute crash.

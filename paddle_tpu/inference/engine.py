@@ -46,35 +46,34 @@ unpreempted run (greedy decoding; the sampling strategy's key stream
 is global per step, so preemption reshuffles it by construction) and
 no new prefill compilations.
 
-Ragged unified step (``unified_step=True``, default): ``step()``
-dispatches ONE compiled mixed-batch program (``_paged_mixed_step``)
-that packs every active decode slot (compacted host-side — retired
-slots cost nothing) plus up to ``prefill_token_budget`` tokens of
-pending ``begin_request`` prefill chunks.  Descriptors are traced
-scalars, so ``mixed_compiles() == 1`` across arbitrary batch mixes,
-and a long prompt no longer stalls in-flight decodes (ROADMAP open
-item 2).  ``add_request`` remains the synchronous admission path;
-tokens are bit-identical between the unified and split programs
-(greedy decoding).  A unified step crosses the host-device boundary
-once each way: its descriptors go up as ONE packed buffer, its tokens,
-``steps_done``, window key and routed counts come back as ONE array
-whose copy is issued at launch (``_packed_mixed_step`` /
-``_packed_mixed_window``; ``llm_engine_host_transfers_total`` over
-``llm_engine_steps_total`` reads 1.0 each way).
+One step loop: ``step()`` dispatches ONE compiled mixed-batch program
+(``_paged_mixed_step``) that packs every active decode slot (compacted
+host-side — retired slots cost nothing) plus up to
+``prefill_token_budget`` tokens of pending ``begin_request`` prefill
+chunks.  Descriptors are traced scalars, so ``mixed_compiles() == 1``
+across arbitrary batch mixes, and a long prompt does not stall
+in-flight decodes.  ``add_request`` remains the synchronous admission
+path (its own chunked-prefill program).  A step crosses the
+host-device boundary once each way: its descriptors go up as ONE packed
+buffer, its tokens, ``steps_done``, window key and routed counts come
+back as ONE array whose copy is issued at launch
+(``_packed_mixed_step`` / ``_packed_mixed_window``;
+``llm_engine_host_transfers_total`` over ``llm_engine_steps_total``
+reads 1.0 each way).
 
-On-device decode windows (``scan_decode=True``, default): a
-``steps_per_sync > 1`` pure-decode window runs as ONE compiled
-``lax.while_loop`` program — attend (ragged Pallas kernel, pools
+On-device decode windows: with no prefill pending, a
+``steps_per_sync > 1`` window runs as ONE compiled ``lax.while_loop``
+program (``_paged_mixed_window``) — attend (ragged Pallas kernel, pools
 aliased in place), sample, KV-append, token feed-back chained
 in-graph — syncing the host only at the window boundary, with early
-exit once every row has hit EOS or its budget (per-row emitted counts
-come back so the host merge stays exact).  Window lengths bucket to
+exit once every row has hit EOS or its budget (``steps_done`` comes
+back so the host merge stays exact).  Window lengths bucket to
 powers of two (one compile per bucket, declared to the CompileWatch
 at construction); the per-step body IS the single-step program's
 body and the key sequence is the same ``inference.sampling``
-``split_step`` chain, so tokens are bit-identical to host-chained
-dispatch on every path — plain, int8 KV, prefix hits,
-preempt→resume, migration.
+``split_step`` chain, so a window's tokens equal the per-token
+stream's (``steps_per_sync=1``) on every path — plain, int8 KV,
+prefix hits, preempt→resume, migration.
 
 Automatic prefix caching (``enable_prefix_caching=``, default on):
 admission looks up the longest cached page-aligned prefix of the
@@ -100,10 +99,10 @@ routed-token counts feeding the ``llm_engine_expert_tokens_total``
 observability plane (folded on the host one dispatch later, behind the
 next launch: ``_note_expert_counts`` / ``_fold_expert_counts``).
 Capacity-factor dispatch (``moe_dropless=False``) drops per
-page-group deterministically across the
-split/unified/scanned paths (the unified planner packs whole page
-chunks in that mode); decode rows are singleton groups and never
-drop.
+page-group deterministically whichever way a prompt was admitted
+(the step's planner packs whole page chunks in that mode, as
+``add_request``'s chunked prefill does); decode rows are singleton
+groups and never drop.
 """
 from __future__ import annotations
 
@@ -434,11 +433,10 @@ def _decode_one_token_fn(stack, norm_w, head_w, embed_w, rope, tables,
                          strategy, top_k, top_p, temperature,
                          draw_base=None, shardings=None, arch=None,
                          live=None, collect_probs=False):
-    """Build the one-token decode body shared by ``_paged_decode_step``
-    (fixed-length window) and ``_paged_decode_window`` (the early-exit
-    scanned window).  ONE definition of the per-step math — embed,
-    rope, fused append+attend, sample, ``split_step`` key chain — is
-    what makes the two programs bit-identical step for step.
+    """Build the one-token decode body of ``_paged_decode_step`` (the
+    fixed-length window recompute resume and capsule replay dispatch)
+    and of the speculative draft's k-token program: embed, rope, fused
+    append+attend, sample, ``split_step`` key chain.
 
     ``draw_base`` (traced int32 scalar) offsets the per-row sampling
     fold: row i draws with ``fold_row(sub, draw_base + i)`` — the live
@@ -655,98 +653,6 @@ def _paged_decode_step(stack, norm_w, head_w, embed_w, rope,
     return toks, k_pages, v_pages, k_scales, v_scales, final[8]
 
 
-@functools.partial(
-    __import__("jax").jit,
-    static_argnames=("eps", "kvh", "head_dim", "transpose_head",
-                     "strategy", "top_k", "top_p", "temperature",
-                     "n_steps", "shardings", "arch"),
-    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales"))
-def _paged_decode_window(stack, norm_w, head_w, embed_w, rope,
-                         k_pages, v_pages, k_scales, v_scales,
-                         tokens, positions, tables, lens, key,
-                         draw_base, eos_ids, budgets, n_live, *,
-                         eps: float, kvh: int, head_dim: int,
-                         transpose_head: bool = False,
-                         strategy: str = "greedy_search", top_k: int = 0,
-                         top_p: float = 1.0, temperature: float = 1.0,
-                         n_steps: int = 2, shardings=None, arch=None):
-    """The split path's ON-DEVICE decode window with EARLY EXIT: up to
-    ``n_steps`` tokens per dispatch (same per-step body as
-    ``_paged_decode_step`` — ``_decode_one_token_fn`` — so the token
-    stream is bit-identical), but a ``lax.while_loop`` stops as soon as
-    every live row has hit its EOS (``eos_ids``, −1 = none) or emitted
-    its remaining budget (``budgets`` = max_new − len(out) at window
-    start).  The host merge loop discards a finished row's surplus
-    tokens either way, so exiting early changes NOTHING observable —
-    it just stops paying for steps no row needs.  Like the host path,
-    rows keep computing (and appending into soon-released pages) while
-    ANY row still runs: per-row masking would change nothing and cost
-    a select on every tensor.
-
-    eos_ids/budgets [B] int32 (pad rows: −1 / 1); ``n_live`` the count
-    of real rows (traced — the compiled shape stays one per n_steps
-    bucket).  Returns (tokens [n_steps, B] — rows ≥ steps_done are
-    zero-filled, the host must slice with steps_done —, emitted [B]
-    int32 per-row delivered-token counts, steps_done, k_pages',
-    v_pages', k_scales', v_scales') — plus a trailing routed-token
-    counts [L, E] int32 with an MoE ``arch`` (rows with window-start
-    ``lens == 0`` route nowhere for the whole window).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    moe_live = None if arch is None else lens > 0
-    one_token = _decode_one_token_fn(
-        stack, norm_w, head_w, embed_w, rope, tables,
-        eps=eps, kvh=kvh, head_dim=head_dim,
-        transpose_head=transpose_head, strategy=strategy, top_k=top_k,
-        top_p=top_p, temperature=temperature, draw_base=draw_base,
-        shardings=shardings, arch=arch, live=moe_live)
-
-    b = tokens.shape[0]
-    live = jnp.arange(b) < n_live
-    state0 = (tokens, positions, lens, k_pages, v_pages, k_scales,
-              v_scales, key)
-    if arch is not None:
-        state0 = state0 + (jnp.zeros(
-            (stack[0].shape[0], arch.num_experts), jnp.int32),)
-    toks0 = jnp.zeros((n_steps, b), jnp.int32)
-    carry0 = (jnp.zeros((), jnp.int32), state0, toks0,
-              jnp.logical_not(live), jnp.zeros(b, jnp.int32))
-
-    def cond(carry):
-        si, _, _, done, _ = carry
-        return jnp.logical_and(si < n_steps,
-                               jnp.logical_not(jnp.all(done)))
-
-    def body(carry):
-        si, state, toks, done, emitted = carry
-        state = one_token(state)
-        nxt = state[0].astype(jnp.int32)
-        toks = jax.lax.dynamic_update_slice(toks, nxt[None], (si, 0))
-        # mirror the host merge EXACTLY: a row emits while not done;
-        # it retires on EOS or on filling its budget (the window never
-        # exceeds the smallest budget, so budget exhaustion can only
-        # land on the window's last step — but the same test keeps the
-        # invariant local instead of trusting the caller)
-        fresh = jnp.logical_not(done)
-        emitted = emitted + fresh.astype(jnp.int32)
-        hit_eos = jnp.logical_and(eos_ids >= 0, nxt == eos_ids)
-        done = jnp.logical_or(
-            done, jnp.logical_and(fresh, jnp.logical_or(
-                hit_eos, emitted >= budgets)))
-        return (si + 1, state, toks, done, emitted)
-
-    si, state, toks, done, emitted = jax.lax.while_loop(
-        cond, body, carry0)
-    (_, _, _, k_pages, v_pages, k_scales, v_scales, _) = state[:8]
-    if arch is None:
-        return (toks, emitted, si, k_pages, v_pages, k_scales,
-                v_scales)
-    return (toks, emitted, si, k_pages, v_pages, k_scales, v_scales,
-            state[8])
-
-
 def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
                    k_pages, v_pages, k_scales, v_scales,
                    ids, positions, row_tables,
@@ -761,13 +667,13 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
                    hybrid=None):
     """Un-jitted body of ``_paged_mixed_step`` — ALSO the per-step body
     of ``_paged_mixed_window``'s on-device loop, which is what makes
-    the scanned window bit-identical to host-chained dispatch: the two
-    paths trace the very same ops in the very same order (see
+    a window's tokens equal the per-token stream's: the two programs
+    trace the very same ops in the very same order (see
     ``_paged_mixed_step`` for the argument contract).  With an MoE
     ``arch`` the return gains a trailing routed-token counts [L, E]:
     rows past their descriptor's ``q_len`` (padding) route nowhere,
     and each descriptor is one capacity page-group (``group_start =
-    q_start[desc_of_row]``) so split-path prefill chunks rank
+    q_start[desc_of_row]``) so ``add_request``'s prefill chunks rank
     identically.
 
     ONE layer function, ``layer(kind, ...)``: norm -> mixer(kind) ->
@@ -1105,8 +1011,9 @@ def _paged_mixed_step(stack, norm_w, head_w, embed_w, rope,
     ``q_len == 0`` marking unused descriptors; desc_tables [S, maxp].
     Dead padding rows carry position 0 and the all-zero table — their
     writes land in the reserved pad page.  Returns (next_token [T],
-    k_pages', v_pages', k_scales', v_scales', key') — the key chains
-    across host-driven multi-token windows.  With an MoE ``arch`` the
+    k_pages', v_pages', k_scales', v_scales', key') — the key after
+    this step's ``split_step``, which the window program's loop carries
+    to its next step.  With an MoE ``arch`` the
     return gains a trailing routed-token counts [L, E]; with a
     ``hybrid`` backbone the per-slot recurrent state and conv-window
     arrays (``rec_state`` / ``conv_state``, donated and aliased like
@@ -1151,19 +1058,19 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
                         top_p: float = 1.0, temperature: float = 1.0,
                         n_steps: int = 2, shardings=None, arch=None,
                         hybrid=None):
-    """The unified path's ON-DEVICE decode window: up to ``n_steps``
+    """The step loop's ON-DEVICE decode window: up to ``n_steps``
     pure-decode steps of ``_mixed_forward`` — attend+append (the
     ragged kernel, aliases intact), sample, feed-back — chained in a
     ``lax.while_loop`` so the whole window is ONE dispatch, with EARLY
     EXIT once every live row has retired (its EOS ``eos_ids[i]``, −1
     for none, or its remaining budget ``budgets[i]``).  The in-graph
-    feedback is exactly the host chain: row < n_rows gets its sampled
-    token as the next input with position/kv_len bumped — including
-    already-retired rows, whose surplus tokens the host merge discards
-    just as it does on the host-chained path (computing them keeps the
-    two paths op-identical; their appends land in pages that release
-    at retirement).  The key chains through ``split_step`` inside the
-    graph — the same sequence the host-chained window derives.
+    feedback is what per-token stepping does on the host: row < n_rows
+    gets its sampled token as the next input with position/kv_len
+    bumped — including already-retired rows, whose surplus tokens the
+    host merge discards (masking them would cost a select on every
+    tensor; their appends land in pages that release at retirement).
+    The key chains through ``split_step`` inside the graph — one
+    ``jax.random.split`` a step (``sampling.window_keys``).
 
     Only pure-decode windows dispatch here (the caller forces
     ``nsteps == 1`` whenever prefill chunks are packed), so q_len is
@@ -1172,8 +1079,7 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
     emitted [T] per-row delivered counts, steps_done, k_pages',
     v_pages', k_scales', v_scales', key') — plus a trailing
     routed-token counts [L, E] with an MoE ``arch`` (accumulated over
-    the whole window, retired rows included, exactly like the
-    host-chained path's per-step accumulation).  A ``hybrid``
+    the whole window, retired rows included).  A ``hybrid``
     backbone's recurrent state and conv windows ride the loop's carry
     like the pools and come back after the counts.  The engine launches
     it behind ``_packed_mixed_window``: one upload in; tokens,
@@ -1229,7 +1135,7 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
         done = jnp.logical_or(
             done, jnp.logical_and(fresh, jnp.logical_or(
                 hit_eos, emitted >= budgets)))
-        # the host-chained feedback, in-graph: live rows advance, pad
+        # the token feed-back, in-graph: live rows advance, pad
         # rows keep position 0 / the pad table
         ids = jnp.where(live, nxt, ids)
         positions = jnp.where(live, positions + 1, positions)
@@ -1265,9 +1171,7 @@ def _step_layout(t_cap: int, s_cap: int, maxp: int, hybrid: bool):
     the engine observes at construction (rows, descriptors — one a row,
     or the hybrid backbone's cap —, pages a sequence), all static.
     ``row_tables`` is not here: it is ``desc_tables[desc_of_row]``, row
-    for row, and the wrappers gather it on the device.  ``fresh`` says
-    whether the dispatch opens a window (its key is split off the
-    engine's) or continues a host-chained one (it is the chain key)."""
+    for row, and the wrappers gather it on the device."""
     shapes = [(name, (t_cap,)) for name in (
         "ids", "positions", "desc_of_row", "off_of_row", "eos_ids",
         "budgets")]
@@ -1275,7 +1179,7 @@ def _step_layout(t_cap: int, s_cap: int, maxp: int, hybrid: bool):
     if hybrid:
         shapes.append(("desc_slot", (s_cap,)))
     shapes.append(("desc_tables", (s_cap, maxp)))
-    shapes += [(name, ()) for name in ("draw_base", "n_rows", "fresh")]
+    shapes += [(name, ()) for name in ("draw_base", "n_rows")]
     fields, off = [], 0
     for name, shape in shapes:
         fields.append((name, off, shape))
@@ -1350,27 +1254,23 @@ def _packed_mixed_step(stack, norm_w, head_w, embed_w, rope,
     sampling key, which never leaves the device: it is split here, the
     same bits as ``jax.random.split`` on the host.  Returns (result —
     ``_pack_result``, the ONE array the host reads —, k_pages',
-    v_pages', k_scales', v_scales', engine key', chain key') plus a
-    hybrid backbone's state arrays; pools and state donated and aliased
-    as in the inner program."""
-    import jax.numpy as jnp
-
+    v_pages', k_scales', v_scales', engine key') plus a hybrid
+    backbone's state arrays; pools and state donated and aliased as in
+    the inner program."""
     f = _unpack_step(packed, geom, hybrid is not None)
     next_key, sub = _sampling.split_step(key)
-    run = jnp.where(f["fresh"] != 0, sub, key)
     res = _mixed_forward(
         stack, norm_w, head_w, embed_w, rope,
         k_pages, v_pages, k_scales, v_scales,
-        *_descriptor_args(f), run, f["draw_base"],
+        *_descriptor_args(f), sub, f["draw_base"],
         rec_state, conv_state, f.get("desc_slot"),
         eps=eps, kvh=kvh, head_dim=head_dim,
         transpose_head=transpose_head, strategy=strategy,
         top_k=top_k, top_p=top_p, temperature=temperature,
         shardings=shardings, arch=arch, hybrid=hybrid)
-    out = _pack_result(res[0], 1, run,
+    out = _pack_result(res[0], 1, sub,
                        None if arch is None else res[6], shardings)
-    return (out,) + res[1:5] + (_tpc(next_key, shardings),
-                                _tpc(res[5], shardings)) + res[7:9]
+    return (out,) + res[1:5] + (_tpc(next_key, shardings),) + res[7:9]
 
 
 @functools.partial(
@@ -1406,8 +1306,7 @@ def _packed_mixed_window(stack, norm_w, head_w, embed_w, rope,
         n_steps=n_steps, shardings=shardings, arch=arch, hybrid=hybrid)
     out = _pack_result(res[0], res[2], sub,
                        None if arch is None else res[8], shardings)
-    return (out,) + res[3:7] + (_tpc(next_key, shardings),
-                                _tpc(res[7], shardings)) + res[9:11]
+    return (out,) + res[3:7] + (_tpc(next_key, shardings),) + res[9:11]
 
 
 class LLMEngine:
@@ -1425,9 +1324,7 @@ class LLMEngine:
                  enable_metrics: bool = True,
                  enable_prefix_caching: Optional[bool] = None,
                  swap_pool_pages: Optional[int] = None,
-                 unified_step: bool = True,
                  prefill_token_budget: Optional[int] = None,
-                 scan_decode: bool = True,
                  mesh=None, tp_axis: str = "tp",
                  moe_dispatch: str = "grouped",
                  moe_dropless: bool = True,
@@ -1453,15 +1350,11 @@ class LLMEngine:
                 f"unsupported weight_dtype {weight_dtype!r}")
         enforce(moe_dispatch in ("grouped", "dense"),
                 f"unsupported moe_dispatch {moe_dispatch!r}")
+        # steps_per_sync > 1: a pure-decode window runs as ONE compiled
+        # while_loop program (attend → sample → KV-append chained
+        # in-graph, early exit when every row retires) whose step body
+        # IS the single-step program's body
         self.steps_per_sync = steps_per_sync
-        # on-device decode windows: steps_per_sync > 1 windows run as
-        # ONE compiled while_loop program (attend → sample → KV-append
-        # chained in-graph, early exit when every row retires) instead
-        # of host-chained single-token dispatches.  Bit-identical by
-        # construction — the window program's step body IS the
-        # single-step program's body.  False restores host chaining
-        # (debugging / A-B benches).
-        self.scan_decode = bool(scan_decode)
         self.last_window_steps = 0
         self.decode_strategy = decode_strategy
         self.top_k = int(top_k)
@@ -1473,13 +1366,12 @@ class LLMEngine:
         self.max_len = max_len
         self.kv_dtype = kv_dtype
         self.weight_dtype = weight_dtype
-        # ragged unified step: ONE compiled program serves every mixed
-        # prefill+decode batch.  The STATIC prefill-token budget sizes
-        # the flat batch (T = max_seqs + budget rows); the runtime
-        # budget (``prefill_token_budget`` attribute) can be lowered
-        # per step — e.g. by a scheduler's decode-latency SLO loop —
-        # without recompiling, since T never changes.
-        self.unified_step = bool(unified_step)
+        # ONE compiled program serves every mixed prefill+decode
+        # batch.  The STATIC prefill-token budget sizes the flat batch
+        # (T = max_seqs + budget rows); the runtime budget
+        # (``prefill_token_budget`` attribute) can be lowered per step
+        # — e.g. by a scheduler's decode-latency SLO loop — without
+        # recompiling, since T never changes.
         self._pf_budget_static = int(prefill_token_budget) \
             if prefill_token_budget is not None else page_size
         enforce(self._pf_budget_static >= 1,
@@ -1487,7 +1379,7 @@ class LLMEngine:
         self.prefill_token_budget = self._pf_budget_static
         self._prefilling: List[GenRequest] = []
         # host-side prefix-cache stats (kept even with metrics off —
-        # the bench and tests read them directly)
+        # the benchmark and tests read them directly)
         self.prefix_stats = {"hit_tokens": 0, "miss_tokens": 0,
                              "shared_pages": 0, "hit_requests": 0,
                              "miss_requests": 0}
@@ -1503,9 +1395,9 @@ class LLMEngine:
         layers = spec.layers
         # a backbone whose layers are of several kinds (linear-attention
         # layers with a per-slot recurrent state beside full-attention
-        # layers over KV pages) is served by the unified step only, and
-        # what that path does not carry for it yet is refused HERE, one
-        # clear error each, never silently
+        # layers over KV pages) is admitted through ``begin_request``
+        # only, and what the step does not carry for it yet is refused
+        # HERE, one clear error each, never silently
         self._hybrid = hy = spec.hybrid
         if hy is not None:
             def refuse(bad, what, why):
@@ -1518,9 +1410,6 @@ class LLMEngine:
                    "a prefix hit would need the recurrent state at the "
                    "prefix boundary, which the cache does not snapshot "
                    "(it builds with prefix caching off)")
-            refuse(not unified_step, "unified_step=False",
-                   "only the unified mixed step and its decode window "
-                   "have the per-kind layer function")
             refuse(mesh is not None, "mesh=",
                    "the recurrent state pools and the DeltaNet mixer "
                    "have no tensor-parallel plan")
@@ -1575,16 +1464,16 @@ class LLMEngine:
                 dispatch=moe_dispatch,
                 expert_lo=int(m.get("expert_lo", 0)),
                 experts_held=int(m.get("experts_held", 0)))
-            if cap and unified_step:
+            if cap:
                 # capacity ranks are defined per page-group, so the
-                # unified planner packs WHOLE page chunks in this
+                # step's planner packs WHOLE page chunks in this
                 # mode — the static budget must fit one
                 enforce(self._pf_budget_static >= page_size,
-                        "capacity-factor MoE with unified_step needs "
+                        "capacity-factor MoE needs "
                         f"prefill_token_budget >= page_size "
                         f"({page_size}) — the planner packs whole "
-                        "page chunks so capacity ranks match the "
-                        "split path")
+                        "page chunks so capacity ranks match "
+                        "add_request's chunked prefill")
         # tensor-parallel serving (``mesh=``): attention heads and MLP
         # hidden shard over the ``tp_axis`` of the given 1-D mesh
         # (distributed.topology.serving_mesh builds one); the paged KV
@@ -1802,7 +1691,7 @@ class LLMEngine:
         self.requests: Dict[object, GenRequest] = {}
         self._active: List[GenRequest] = []
         # host-side per-expert load accounting (kept even with metrics
-        # off — metrics_snapshot()/statusz and the bench read it):
+        # off — metrics_snapshot()/statusz and the benchmark read it):
         # routed-slot counts per (layer, expert) plus the running
         # capacity-drop total (always 0 dropless)
         if self._arch is not None:
@@ -1854,27 +1743,26 @@ class LLMEngine:
                              "descriptors": 0, "state_snapshots": 0}
         self._init_metrics(enable_metrics)
         # compile-watch registration: this engine's three jit entry
-        # points and their warmup allowances (the split decode program
-        # legitimately compiles one power-of-two window bucket per
-        # size, bit_length of steps_per_sync of them; prefill and the
-        # unified mixed step are strictly one-program per geometry).
+        # points and their warmup allowances (the decode program of
+        # recompute resume and capsule replay legitimately compiles
+        # one power-of-two window bucket per size, bit_length of
+        # steps_per_sync of them; prefill and the mixed step are
+        # strictly one-program per geometry).
         # A no-op off one global read when the watch is disabled.
         cw = _insp.get_compile_watch()
         cw.register_program("engine.prefill_chunk")
         cw.register_program("engine.decode_step",
                             expected=int(steps_per_sync).bit_length())
         cw.register_program("engine.mixed_step")
-        # scanned windows: one program per power-of-two window bucket
+        # on-device windows: one program per power-of-two window bucket
         # {2, 4, ..., 2^floor(log2(steps_per_sync))} — the n_steps==1
         # window degenerates to the plain step program above, so the
         # bucket count is bit_length − 1 and ``mixed_compiles()`` stays
         # bounded by DECLARED allowances (a recompile past them is an
         # anomaly the watch flags)
         wb = max(int(steps_per_sync).bit_length() - 1, 0)
-        if self.scan_decode and wb:
-            cw.register_program(
-                "engine.mixed_window" if self.unified_step
-                else "engine.decode_window", expected=wb)
+        if wb:
+            cw.register_program("engine.mixed_window", expected=wb)
         # the paged KV pool (device pages + host swap) as a first-class
         # /memz row; weakly held so a released engine frees its pages
         _insp.register_memory_consumer(
@@ -1889,8 +1777,6 @@ class LLMEngine:
             "page_size": page_size, "n_pages": int(n_pages),
             "max_seqs": max_seqs, "max_len": max_len,
             "steps_per_sync": steps_per_sync,
-            "unified_step": self.unified_step,
-            "scan_decode": self.scan_decode,
             "decode_strategy": decode_strategy,
             "top_k": self.top_k, "top_p": self.top_p,
             "temperature": self.temperature, "seed": seed,
@@ -2052,7 +1938,7 @@ class LLMEngine:
             "head_dim": dc.hidden_size // dc.num_attention_heads,
         }
         # host-side acceptance accounting (kept even with metrics off —
-        # metrics_snapshot()/statusz/the bench read it directly):
+        # metrics_snapshot()/statusz/the benchmark read it directly):
         # ``accepted`` counts surviving DRAFT tokens only; the bonus /
         # correction token rides ``delivered``
         self.spec_stats = {"windows": 0, "proposed": 0, "accepted": 0,
@@ -2096,8 +1982,7 @@ class LLMEngine:
         """Per-engine children in the global registry (label
         engine=<id>), so concurrent engines scrape apart.  Recording is
         a handful of host float-adds per step WINDOW (never per token:
-        TPOT uses the weighted observe), which is what keeps the bench
-        overhead row inside its <=2% budget."""
+        TPOT uses the weighted observe)."""
         self.engine_id = str(next(_ENGINE_IDS))
         self._metrics = None
         if not enabled:
@@ -2283,7 +2168,7 @@ class LLMEngine:
             "llm_engine_window_compiles",
             "Distinct compiled on-device decode-window programs "
             "(expected: at most log2(steps_per_sync) power-of-two "
-            "buckets; 0 with scan_decode off).")
+            "buckets).")
 
     def _record_compiles(self):
         m = self._metrics
@@ -2978,9 +2863,6 @@ class LLMEngine:
         Prefix caching applies as in ``add_request``: cached pages map
         in host-side and the chunk stream starts at the first uncached
         position."""
-        enforce(self.unified_step,
-                "begin_request requires unified_step=True (the split-"
-                "program engine admits synchronously via add_request)")
         enforce(rid not in self.requests, f"duplicate request id {rid!r}")
         enforce(max_new_tokens >= 1, "max_new_tokens must be >= 1")
         req = GenRequest(rid, prompt_ids, max_new_tokens, eos_token_id)
@@ -3038,14 +2920,13 @@ class LLMEngine:
         retires finished requests (streaming callers see every
         intermediate token).
 
-        With ``unified_step=True`` (default) this is the RAGGED MIXED
-        step: one compiled program packs every active decode slot plus
-        up to ``prefill_token_budget`` tokens of pending
-        ``begin_request`` prefill chunks — prefill rides alongside
-        decode instead of stalling it.  Tokens are bit-identical to
-        the split-program path (greedy decoding; the per-row programs
-        agree op for op).  With ``unified_step=False`` the original
-        split decode-only dispatch runs (``_paged_decode_step``).
+        This is the RAGGED MIXED step (``_step_mixed``): one compiled
+        program packs every active decode slot plus up to
+        ``prefill_token_budget`` tokens of pending ``begin_request``
+        prefill chunks — prefill rides alongside decode instead of
+        stalling it — and, with no prefill pending, a
+        ``steps_per_sync`` window of decode steps runs as one
+        on-device loop.
 
         A ``draft_model`` engine routes pure-decode windows through
         the speculative path (``_step_spec``): greedy streams stay
@@ -3063,197 +2944,13 @@ class LLMEngine:
         behind a launch, or here, at once, when the step leaves the
         engine without work."""
         with _phase("engine.step") as sp:
-            if self._spec is not None:
-                out = self._step_spec(sp)
-            elif self.unified_step:
-                out = self._step_mixed(sp)
-            else:
-                out = self._step_split(sp)
+            out = self._step_spec(sp) if self._spec is not None \
+                else self._step_mixed(sp)
             if self._counts_aside and not self.has_work():
                 # no launch will follow to fold the last counts behind
                 with _phase("engine.step.moe_counts"):
                     self._fold_expert_counts("at_idle")
             return out
-
-    def _step_split(self, sp) -> Dict[object, List[int]]:
-        """Decode up to ``steps_per_sync`` tokens for every active
-        request in one device dispatch.  The host only
-        syncs (EOS checks, admission window) once per call, so over a
-        high-latency dispatch path throughput scales with
-        steps_per_sync; the window never exceeds any request's
-        remaining token budget, so page capacity is exact.  With
-        ``scan_decode`` (default) multi-step windows run the early-exit
-        ``_paged_decode_window`` while_loop program; otherwise the
-        fixed-length ``_paged_decode_step`` scan."""
-        import jax
-        import jax.numpy as jnp
-
-        if not self._active:
-            return {}
-        with _phase("engine.step.plan"):
-            batch = list(self._active)
-            n = len(batch)
-            nsteps = min([self.steps_per_sync] +
-                         [r.max_new - len(r.out) for r in batch])
-            nsteps = max(nsteps, 1)
-            # bucket the window to a power of two so ragged remaining
-            # budgets compile at most log2(steps_per_sync) decode
-            # programs (n_steps is a static jit arg), not one per
-            # distinct tail
-            while nsteps & (nsteps - 1):
-                nsteps &= nsteps - 1
-            slots = np.array([r.slot for r in batch])
-            for s in slots:
-                self.cache.extend(int(s), nsteps)
-        sp.set_metadata(decode_slots=n, prefill_tokens=0, nsteps=nsteps,
-                        path="split")
-        window = self.scan_decode and nsteps > 1
-        with _phase("engine.step.pack"):
-            # pad to max_seqs: continuous batching must keep ONE
-            # compiled shape as requests join/leave (dummy rows write
-            # into the reserved pad page 0 with len 0 and are
-            # discarded)
-            pad = self.max_seqs - n
-            tokens = np.array([r.out[-1] for r in batch] + [0] * pad,
-                              np.int32)
-            lens = np.concatenate([self.cache.seq_lens[slots],
-                                   np.zeros(pad, np.int32)])
-            tables = np.concatenate(
-                [self.cache.page_table[slots],
-                 np.zeros((pad,) + self.cache.page_table.shape[1:],
-                          np.int32)])
-            if window:
-                # EOS/budget tracked in-graph — same predicate as the
-                # merge loop below
-                eos_ids = np.full(self.max_seqs, -1, np.int32)
-                budgets = np.ones(self.max_seqs, np.int32)
-                for i, r in enumerate(batch):
-                    if r.eos is not None:
-                        eos_ids[i] = r.eos
-                    budgets[i] = r.max_new - len(r.out)
-
-        with _phase("engine.step.launch"):
-            self._key, sub = jax.random.split(self._key)
-            t_win = time.perf_counter()
-            if window:
-                # on-device window: one while_loop program runs the
-                # whole window, exiting early once every row retired
-                res = _insp.watched_call(
-                    "engine.decode_window", _paged_decode_window,
-                    self._stack, self._norm_w, self._head_w,
-                    self._embed_w, self._rope, self.cache.k_pages,
-                    self.cache.v_pages, self.cache.k_scales,
-                    self.cache.v_scales, jnp.asarray(tokens),
-                    jnp.asarray(lens, np.int32),
-                    jnp.asarray(tables),
-                    jnp.asarray(lens, np.int32), sub,
-                    jnp.int32(0),
-                    jnp.asarray(eos_ids), jnp.asarray(budgets),
-                    jnp.int32(n),
-                    eps=self.eps, kvh=self.kvh,
-                    head_dim=self.head_dim,
-                    transpose_head=self._tied,
-                    strategy=self.decode_strategy,
-                    top_k=self.top_k, top_p=self.top_p,
-                    temperature=self.temperature, n_steps=nsteps,
-                    shardings=self._shardings, arch=self._arch)
-                (toks, _, steps_d, self.cache.k_pages,
-                 self.cache.v_pages, self.cache.k_scales,
-                 self.cache.v_scales) = res[:7]
-                counts = res[7] if self._arch is not None else None
-            else:
-                res = _insp.watched_call(
-                    "engine.decode_step", _paged_decode_step,
-                    self._stack, self._norm_w, self._head_w,
-                    self._embed_w, self._rope, self.cache.k_pages,
-                    self.cache.v_pages, self.cache.k_scales,
-                    self.cache.v_scales, jnp.asarray(tokens),
-                    jnp.asarray(lens, np.int32),
-                    jnp.asarray(tables),
-                    jnp.asarray(lens, np.int32), sub,
-                    jnp.int32(0),
-                    eps=self.eps, kvh=self.kvh,
-                    head_dim=self.head_dim,
-                    transpose_head=self._tied,
-                    strategy=self.decode_strategy,
-                    top_k=self.top_k, top_p=self.top_p,
-                    temperature=self.temperature, n_steps=nsteps,
-                    shardings=self._shardings, arch=self._arch)
-                (toks, self.cache.k_pages, self.cache.v_pages,
-                 self.cache.k_scales, self.cache.v_scales) = res[:5]
-                counts = res[5] if self._arch is not None else None
-        steps_done = nsteps
-        if window:
-            with _phase("engine.step.wait"):
-                steps_done = int(jax.device_get(steps_d))
-        if counts is not None:
-            with _phase("engine.step.moe_counts"):
-                self._note_expert_counts(
-                    counts, n * self._arch.top_k * steps_done)
-        with _phase("engine.step.wait"):
-            # [steps_done, n]
-            toks = np.asarray(jax.device_get(toks))[:steps_done, :n]
-        dt_win = time.perf_counter() - t_win
-
-        with _phase("engine.step.merge"):
-            self.cache.advance(slots, steps_done)
-            self.last_window_steps = steps_done
-            # contract (ADVICE r3): with steps_per_sync > 1 a window
-            # emits up to nsteps tokens per request — return the LIST
-            # of new tokens per rid so streaming callers never lose
-            # intermediates
-            out = {}
-            for i, req in enumerate(batch):
-                new_toks = []
-                for j in range(steps_done):
-                    if req.done:
-                        break
-                    tok = int(toks[j, i])
-                    req.out.append(tok)
-                    new_toks.append(tok)
-                    if (req.eos is not None and tok == req.eos) or \
-                            len(req.out) >= req.max_new:
-                        req.done = True
-                        self.cache.release(req.slot)
-                        self._spec_release(req)
-                        self._active.remove(req)
-                if new_toks:
-                    out[req.rid] = new_toks
-        with _phase("engine.step.account"):
-            # capsule capture: one window record per captured rid —
-            # the forked window key anchors the in-window split_step
-            # chain, so replay reproduces the draws key for key
-            cs = _capsule.get_capsule_store()
-            if cs.enabled and out:
-                cs.on_window(out, _sampling.key_fingerprint(sub), nsteps,
-                             steps_done,
-                             "decode_window" if window
-                             else "decode_step",
-                             rows={r.rid: i for i, r in enumerate(batch)})
-            # TPOT counts only tokens actually DELIVERED to a stream:
-            # a request that retired mid-window stops contributing
-            # positions (the fixed window-boundary over-count), and
-            # the window's per-token wall time is wall / steps
-            # actually run
-            delivered = max((len(v) for v in out.values()), default=0)
-            if delivered:
-                _health.get_health().observe_tpot(dt_win / steps_done,
-                                                  n=delivered)
-            if self._metrics is not None:
-                m = self._metrics
-                # ONE weighted observe per window: value is the wall
-                # time a stream waits per token, count advances by the
-                # window's DELIVERED token positions — O(1) recording
-                # however long the window
-                if delivered:
-                    m["tpot"].observe(dt_win / steps_done, n=delivered)
-                m["steps"].inc()
-                m["generated_tokens"].inc(
-                    sum(len(v) for v in out.values()))
-                m["queue_depth"].set(len(self._active))
-                m["occupancy"].set(n / self.max_seqs)
-                self._record_compiles()
-        return out
 
     def _step_mixed(self, sp) -> Dict[object, List[int]]:
         """The ragged unified step: ONE ``_paged_mixed_step`` dispatch
@@ -3264,16 +2961,15 @@ class LLMEngine:
         so one request may contribute several descriptors).  When no
         prefill is pending, the ``steps_per_sync`` window dispatches
         ONCE as the on-device ``_paged_mixed_window`` program
-        (scan_decode, power-of-two buckets, early exit) or — with
-        ``scan_decode=False`` — as host-chained single-token
-        dispatches of the mixed program; both orders are bit-identical
-        by construction.
+        (power-of-two buckets, early exit), whose tokens are the
+        per-token stream's by construction.
 
-        Either way a dispatch crosses the host-device boundary ONCE
-        EACH WAY.  In: every host-made descriptor is written into the
-        engine's one step buffer (``_step_layout``) and uploaded by one
-        ``device_put`` — ``row_tables`` is not, the program gathers it
-        from ``desc_tables[desc_of_row]``; the sampling key stays on
+        Either way the call is ONE launch, which crosses the
+        host-device boundary ONCE EACH WAY.  In: every host-made
+        descriptor is written into the engine's one step buffer
+        (``_step_layout``) and uploaded by one ``device_put`` —
+        ``row_tables`` is not, the program gathers it from
+        ``desc_tables[desc_of_row]``; the sampling key stays on
         the device and is split inside the program.  Out: the program's
         one small result (tokens, ``steps_done``, the window key's
         words, the routed counts) starts its copy to the host at launch
@@ -3353,7 +3049,7 @@ class LLMEngine:
         # force nsteps == 1): the whole attend → sample → append chain
         # runs as one while_loop program that exits as soon as every
         # row has retired, syncing the host once
-        window = self.scan_decode and nsteps > 1
+        window = nsteps > 1
         sp.set_metadata(decode_slots=n, prefill_tokens=used,
                         nsteps=nsteps,
                         path="window" if window else "mixed")
@@ -3368,7 +3064,6 @@ class LLMEngine:
             q_start, q_len, kv_len = f["q_start"], f["q_len"], f["kv_len"]
             desc_tables = f["desc_tables"]
             desc_of_row, off_of_row = f["desc_of_row"], f["off_of_row"]
-            f["fresh"][...] = 1
             f["n_rows"][...] = n
             if n:
                 # decode row i is descriptor i, with its slot's table
@@ -3401,8 +3096,6 @@ class LLMEngine:
                         eos_ids[i] = r.eos
                     budgets[i] = r.max_new - len(r.out)
 
-        toks_all = []
-        steps_done = nsteps
         kw = dict(geom=self._step_geom, eps=self.eps, kvh=self.kvh,
                   head_dim=self.head_dim, transpose_head=self._tied,
                   strategy=self.decode_strategy, top_k=self.top_k,
@@ -3414,64 +3107,42 @@ class LLMEngine:
             kw["n_steps"] = nsteps
         counts_shape = None if self._arch is None else \
             self._moe_counts.shape
-        key = self._key
         t_win = time.perf_counter()
-        # ONE dispatch — or, host-chained (scan_decode off), one a token
-        for si in range(1 if window else nsteps):
-            with _phase("engine.step.launch"):
-                # a hybrid backbone's second kind of state (None
-                # otherwise) is handed over whole, donated like the pools
-                res = _insp.watched_call(
-                    name, program,
-                    self._stack, self._norm_w, self._head_w,
-                    self._embed_w, self._rope,
-                    self.cache.k_pages, self.cache.v_pages,
-                    self.cache.k_scales, self.cache.v_scales,
-                    jax.device_put(self._step_buf, self._step_sharding),
-                    key, self.cache.rec_state, self.cache.conv_state,
-                    **kw)
-                (out_d, self.cache.k_pages, self.cache.v_pages,
-                 self.cache.k_scales, self.cache.v_scales, next_key,
-                 key) = res[:7]
-                if hy is not None:
-                    self.cache.rec_state, self.cache.conv_state = res[7:9]
-                if si == 0:
-                    self._key = next_key
-                # the copy follows the program on the device's own queue
-                out_d.copy_to_host_async()
-                self._crossed("in")
-            if self._counts_aside:
-                # the dispatch before this one's counts, the chip busy
-                with _phase("engine.step.moe_counts"):
-                    self._fold_expert_counts("behind_launch")
-            with _phase("engine.step.wait"):
-                toks, done, words, counts = _unpack_result(
-                    jax.device_get(out_d), nsteps * t_cap if window
-                    else t_cap, counts_shape)
-                self._crossed("out")
-                if si == 0:
-                    sub_words = words
-                if window:
-                    steps_done = done
-                    toks = toks.reshape(nsteps, t_cap)
-                    toks_all = [toks[j] for j in range(steps_done)]
-                else:
-                    toks_all.append(toks)
-                if counts is not None:
-                    # live rows this dispatch: n decode slots, every
-                    # step of a window, + the packed prefill tokens
-                    # (multi-step windows are pure decode)
-                    self._note_expert_counts(
-                        counts, (n * done + used) * self._arch.top_k)
-            if si + 1 < nsteps and not window:
-                with _phase("engine.step.pack"):
-                    # host-chained window (pure decode): feed each
-                    # slot's sampled token back as the next input,
-                    # under the chain key
-                    ids[:n] = toks[:n]
-                    positions[:n] += 1
-                    kv_len[:n] += 1
-                    f["fresh"][...] = 0
+        with _phase("engine.step.launch"):
+            # a hybrid backbone's second kind of state (None
+            # otherwise) is handed over whole, donated like the pools
+            res = _insp.watched_call(
+                name, program,
+                self._stack, self._norm_w, self._head_w,
+                self._embed_w, self._rope,
+                self.cache.k_pages, self.cache.v_pages,
+                self.cache.k_scales, self.cache.v_scales,
+                jax.device_put(self._step_buf, self._step_sharding),
+                self._key, self.cache.rec_state, self.cache.conv_state,
+                **kw)
+            (out_d, self.cache.k_pages, self.cache.v_pages,
+             self.cache.k_scales, self.cache.v_scales,
+             self._key) = res[:6]
+            if hy is not None:
+                self.cache.rec_state, self.cache.conv_state = res[6:8]
+            # the copy follows the program on the device's own queue
+            out_d.copy_to_host_async()
+            self._crossed("in")
+        if self._counts_aside:
+            # the dispatch before this one's counts, the chip busy
+            with _phase("engine.step.moe_counts"):
+                self._fold_expert_counts("behind_launch")
+        with _phase("engine.step.wait"):
+            toks, steps_done, sub_words, counts = _unpack_result(
+                jax.device_get(out_d), nsteps * t_cap, counts_shape)
+            self._crossed("out")
+            toks_all = toks.reshape(nsteps, t_cap)
+            if counts is not None:
+                # live rows this dispatch: n decode slots, every
+                # step of a window, + the packed prefill tokens
+                # (multi-step windows are pure decode)
+                self._note_expert_counts(
+                    counts, (n * steps_done + used) * self._arch.top_k)
         dt_win = time.perf_counter() - t_win
 
         with _phase("engine.step.merge"):
@@ -3537,7 +3208,7 @@ class LLMEngine:
             # capsule capture after the finishing loop, so prefill-
             # completing first tokens ride the same window record as
             # the decode tokens (the forked key `sub` anchors the whole
-            # window's split_step chain, host-chained or scanned)
+            # window's split_step chain)
             cs = _capsule.get_capsule_store()
             if cs.enabled and out:
                 # per-rid draw rows: decode slots are rows 0..n-1 in
@@ -3733,8 +3404,8 @@ class LLMEngine:
                 self.cache.set_len(slot, plen + len(req.out) - 1)
                 path = "swap_in"
         if path is None and self._hybrid is not None:
-            # no split programs for this backbone: the history
-            # re-prefills through the unified step's chunk stream
+            # no split prefill program for this backbone: the history
+            # re-prefills through the step's chunk stream
             # (state from zero, pages rewritten), then decode goes on
             req.slot = self.cache.allocate(total)
             req.replay = list(req.prompt) + list(req.out[:-1])
@@ -3953,14 +3624,12 @@ class LLMEngine:
 
     @staticmethod
     def decode_compiles() -> int:
-        """Distinct compiled decode-side programs: the split
-        multi-step decode program's window buckets PLUS the unified
-        mixed-step program (the unified path's only decode program —
-        counted here so existing >=1 / unchanged-across-runs checks
-        keep holding on either path) PLUS the scanned on-device window
-        programs — a window-bucket recompile must trip the same
-        unchanged-across-runs assertions the host-chained programs
-        live under."""
+        """Distinct compiled decode-side programs: the multi-step
+        decode program recompute resume and capsule replay dispatch
+        (its window buckets) PLUS the mixed-step program ``step()``
+        launches PLUS the on-device window programs — a window-bucket
+        recompile must trip the same unchanged-across-runs assertions
+        the step program lives under."""
         return _paged_decode_step._cache_size() + \
             _paged_mixed_step._cache_size() + \
             _packed_mixed_step._cache_size() + \
@@ -3968,11 +3637,11 @@ class LLMEngine:
 
     @staticmethod
     def mixed_compiles() -> int:
-        """Distinct compiled unified-path programs: the mixed-step
+        """Distinct compiled step-loop programs: the mixed-step
         program (1 per engine geometry for ANY interleaving of prefill
         chunks and decode slots — every batch-mix input is traced
-        data) plus, with ``scan_decode``, one mixed-window program per
-        power-of-two window bucket — bounded by the CompileWatch
+        data) plus one mixed-window program per power-of-two window
+        bucket — bounded by the CompileWatch
         allowances declared at engine construction
         (bit_length(steps_per_sync) − 1 buckets).  What is counted is
         what is launched: the one-transfer wrappers
@@ -3987,14 +3656,12 @@ class LLMEngine:
 
     @staticmethod
     def window_compiles() -> int:
-        """Distinct compiled ON-DEVICE decode-window programs (both
-        paths' while_loop windows).  Expected: one per power-of-two
-        window bucket actually dispatched — {2, 4, ...,
-        2^floor(log2(steps_per_sync))} at most; 0 when scan_decode is
-        off or steps_per_sync == 1 (the degenerate window IS the plain
-        step program)."""
-        return _paged_decode_window._cache_size() + \
-            _packed_mixed_window._cache_size()
+        """Distinct compiled ON-DEVICE decode-window programs.
+        Expected: one per power-of-two window bucket actually
+        dispatched — {2, 4, ..., 2^floor(log2(steps_per_sync))} at
+        most; 0 when steps_per_sync == 1 (the degenerate window IS the
+        plain step program)."""
+        return _packed_mixed_window._cache_size()
 
     def metrics_snapshot(self) -> dict:
         """One JSON-able dict with everything an operator tunes
@@ -4015,8 +3682,6 @@ class LLMEngine:
             "decode_compiles": self.decode_compiles(),
             "mixed_compiles": self.mixed_compiles(),
             "window_compiles": self.window_compiles(),
-            "unified_step": self.unified_step,
-            "scan_decode": self.scan_decode,
             "last_window_steps": int(self.last_window_steps),
             "prefill_token_budget": int(self.prefill_token_budget),
             "kv_cache": self.cache.metrics_snapshot(),

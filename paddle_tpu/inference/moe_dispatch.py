@@ -23,8 +23,7 @@ Two dispatch modes, BIT-IDENTICAL on CPU by construction:
   bit off-TPU (each row's contraction is independent of every other
   row's placement).
 - ``dense`` — the per-row reference: gather each slot's expert weights
-  and contract row-wise, no sorting.  The A/B comparator for tests and
-  the bench's per-expert-loop baseline.
+  and contract row-wise, no sorting.  The comparator for tests.
 
 Token dropping: ``arch.capacity == 0`` is dropless (every routed slot
 computes).  ``capacity > 0`` is the capacity-factor mode: within each

@@ -3,22 +3,22 @@
 The engine's decode window forks one subkey off the engine key per
 window (``self._key, sub = jax.random.split(self._key)``) and then
 chains INSIDE the window: every step splits the window key once and
-samples with the subkey.  The on-device scanned window
-(``scan_decode=True``) must reproduce the host-chained token stream
-bit for bit, which reduces to reproducing this exact key sequence —
-``jax.random.split`` is deterministic, so "same splits in the same
-order" IS the whole contract.
+samples with the subkey.  The contract: the window's draws equal the
+per-token stream's — what a caller who drives the same window key
+through one ``split_step`` a token on the host would draw — which
+reduces to reproducing this exact key sequence; ``jax.random.split``
+is deterministic, so "same splits in the same order" IS the whole
+contract.
 
-This module is the single home of that derivation: the host-chained
-step, the ``lax.scan``/``while_loop`` window bodies, and the tests all
-derive step keys through ``split_step``, so a drive-by "optimization"
-(folding in a step index, splitting n keys up front, reordering the
-split against the sample) cannot silently fork the two paths.  Note
-what the contract is NOT: keys are not indexed by ABSOLUTE step number
-— step j of a window uses the j-th split of the WINDOW key, so early
-exit inside a window (all rows done) skips splits without perturbing
-the engine key, exactly like the host path which simply stops calling
-``step()``.
+This module is the single home of that derivation: the step program,
+the ``lax.scan``/``while_loop`` window bodies, capsule replay, the
+speculative draft and the tests all derive step keys through
+``split_step``, so a drive-by "optimization" (folding in a step index,
+splitting n keys up front, reordering the split against the sample)
+cannot silently fork them.  Note what the contract is NOT: keys are
+not indexed by ABSOLUTE step number — step j of a window uses the j-th
+split of the WINDOW key, so early exit inside a window (all rows done)
+skips splits without perturbing the engine key.
 
 Per-ROW draws fold the batch row index into the step subkey
 (``fold_row``), so a request's token stream depends on the key chain

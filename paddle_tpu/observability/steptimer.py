@@ -31,8 +31,9 @@ from .metrics import MetricRegistry, get_registry
 
 __all__ = ["StepTimer", "device_peak_flops"]
 
-# peak dense-bf16 FLOP/s by PJRT device_kind substring (bench.py's chip
-# table, duplicated here so the package stays importable standalone)
+# peak dense-bf16 FLOP/s by PJRT device_kind substring (the package
+# stays importable standalone; the benchmark keeps its own table of
+# peaks, perfbench/manifest.py)
 _PEAK_FLOPS = [
     ("v6e", 918e12), ("v6", 918e12), ("v5p", 459e12), ("v5e", 197e12),
     ("v5lite", 197e12), ("v4", 275e12), ("v3", 123e12), ("v2", 46e12),

@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, at one place.
 
 Entry points that compile real-size programs (``chip_smoke.py``,
-``bench.py``, ``__graft_entry__.py``) call ``enable_compile_cache()``
+``__graft_entry__.py``) call ``enable_compile_cache()``
 once before their first compile; tests do not.  Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
 directory is set in code.  Where it is not, the cache lives at ONE fixed

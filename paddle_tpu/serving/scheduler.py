@@ -169,8 +169,7 @@ class Scheduler:
     are never expired by queue timers — only ``cancel`` or their
     deadline at delivery touches them.
 
-    ``chunked_prefill`` (opt-in, requires an engine built with
-    ``unified_step=True``) admits waiting requests through
+    ``chunked_prefill`` (opt-in) admits waiting requests through
     ``LLMEngine.begin_request`` instead of the synchronous
     ``add_request``: the prompt's prefill then rides the ragged
     unified step alongside ongoing decodes, a page-sized chunk per
@@ -200,10 +199,6 @@ class Scheduler:
                 "max_preemptions_per_request must be >= 0")
         enforce(packing_max_overtakes >= 1,
                 "packing_max_overtakes must be >= 1")
-        enforce(not chunked_prefill or getattr(engine, "unified_step",
-                                              False),
-                "chunked_prefill requires an engine with "
-                "unified_step=True")
         enforce(decode_tpot_slo is None or decode_tpot_slo > 0,
                 "decode_tpot_slo must be positive (or None)")
         self.engine = engine
@@ -425,7 +420,7 @@ class Scheduler:
         of waiters, preemption/suspend, migrate-out, abort, the AIMD
         budget decision below — lands BETWEEN decode windows, never
         inside one.  With the engine's on-device windows
-        (``scan_decode``, steps_per_sync > 1) a window is one compiled
+        (steps_per_sync > 1) a window is one compiled
         dispatch of up to steps_per_sync tokens per request; the engine
         returns the full per-request token lists for the window, so the
         streaming contract, retirement, and the PR 5/6/10 bit-exactness
@@ -499,8 +494,8 @@ class Scheduler:
         time of one engine step window; divided by the window's token
         count it approximates decode TPOT.  Windows with prefill
         packed are single dispatches (nsteps == 1) so the
-        approximation is exact where the knob matters; scanned
-        multi-token windows (``scan_decode``) divide by the tokens the
+        approximation is exact where the knob matters; on-device
+        multi-token windows divide by the tokens the
         window actually delivered — the max over
         ``len(step_out[rid])`` — so an early-exited window is costed
         by its real length.  Speculative windows fall out of the same

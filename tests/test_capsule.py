@@ -6,7 +6,7 @@ Contracts under test:
   ``NULL_CAPSULE_STORE`` singleton (identity-asserted) and tokens +
   compile counts are bit-identical with capture off vs armed;
 * a captured request replays bit-exactly (``first_divergence is
-  None``) across the unified x scan engine grid, on int8 KV, after
+  None``) across the steps_per_sync x admission grid, on int8 KV, after
   preempt -> resume on BOTH restore paths (swap-in and recompute),
   and after a cross-replica KV migration (the capsule rides the
   migration package);
@@ -19,12 +19,10 @@ Contracts under test:
   ``POST /v1/replay``, the /statusz capsule block, and SSE framing of
   ``/v1/completions`` sharing one event encoding with chunked NDJSON;
 * ``divergence_audit`` replays sampled capsules on another engine and
-  ``ReplicaRouter.fleet_snapshot()`` federates the store counters;
-* ``bench.bench_history`` folds BENCH_rNN.json snapshots tolerantly.
+  ``ReplicaRouter.fleet_snapshot()`` federates the store counters.
 
 Everything runs JAX_PLATFORMS=cpu on the tiny llama config.
 """
-import importlib.util
 import json
 import http.client
 import re
@@ -94,17 +92,27 @@ def test_null_store_identity_and_disabled_bit_identical(model):
 
 
 # -- replay: engine grid -------------------------------------------------------
-@pytest.mark.parametrize("unified,scan", [(False, False), (False, True),
-                                          (True, False), (True, True)])
-def test_replay_bit_exact_across_grid(model, unified, scan):
-    """The same capsule replays with first_divergence None on every
-    (unified_step x scan_decode) engine path."""
+@pytest.mark.parametrize("sps,admit", [(1, "add"), (4, "add"),
+                                       (8, "add"), (4, "begin")])
+def test_replay_bit_exact_across_grid(model, sps, admit):
+    """A capsule replays with first_divergence None whichever programs
+    recorded it: the step program alone (``steps_per_sync=1``), two
+    window buckets (4, 8), a first token drawn inside a mixed step
+    (``begin_request``: no key anchor at admission).  Replay runs the
+    prefill and decode programs of recompute resume, not the step
+    loop's, so it is an independent reading of the same stream."""
     C.enable_capsule_capture()
-    eng = _mk(model, unified_step=unified, scan_decode=scan)
-    want = _run(eng, "g", [5, 9, 2, 14], 10)
+    eng = _mk(model, steps_per_sync=sps)
+    if admit == "begin":
+        eng.begin_request("g", [5, 9, 2, 14], max_new_tokens=10)
+        while eng.has_work():
+            eng.step()
+        want = eng.result("g")
+    else:
+        want = _run(eng, "g", [5, 9, 2, 14], 10)
     cap = C.get_capsule_store().get("g")
     assert cap["tokens"] == want
-    assert cap["fingerprint"]["unified_step"] == unified
+    assert {w["n_steps"] for w in cap["windows"]} == {1, sps}
     rep = C.replay_capsule(cap, eng)
     assert rep["first_divergence"] is None, rep
     assert rep["steps_compared"] == len(want)
@@ -346,31 +354,6 @@ def test_divergence_audit_and_fleet_federation(model):
     assert snap["fleet"]["capsules"]["divergent_replays_total"] == 0
     assert C.get_capsule_store().snapshot()["audits"], \
         "the audit summary must land in the store snapshot"
-
-
-# -- bench history -------------------------------------------------------------
-def test_bench_history_folds_rounds(tmp_path):
-    spec = importlib.util.spec_from_file_location(
-        "bench", Path(__file__).resolve().parent.parent / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "n": 1, "cmd": "x", "rc": 0,
-        "tail": "WARNING: platform noise\n"
-                '{"metric": "m", "value": 100.0, "unit": "t/s"}'}))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-        "n": 2, "cmd": "x", "rc": 0,
-        "tail": '{"metric": "m", "value": 110.0, "unit": "t/s"}\n'
-                '{"metric": "oops_ERROR", "error": "boom"}\n'
-                "not json at all"}))
-    (tmp_path / "BENCH_r03.json").write_text("truncated {")
-    out = bench.bench_history(root=str(tmp_path), emit=False)
-    assert out["rounds"] == [1, 2] and out["value"] == 2
-    assert out["rows"][0]["delta_pct"] is None
-    assert out["rows"][1]["delta_pct"] == 10.0
-    # the real repo fold covers every committed round
-    real = bench.bench_history(emit=False)
-    assert 14 in real["rounds"]
 
 
 # -- tier-1 budget guard -------------------------------------------------------

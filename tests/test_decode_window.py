@@ -3,10 +3,12 @@ as ONE compiled while_loop program — attend → sample → KV-append
 chained in-graph, host synced only at window boundaries.
 
 Contracts under test:
-* tokens BIT-IDENTICAL to host-chained single-token dispatch on every
-  path — plain greedy, int8 KV, sampling (the ``inference.sampling``
-  key-sequence contract), prefix-cache hits, preempt→resume (swap-in
-  AND recompute), migration — on the unified AND split engines;
+* a window's tokens equal the PER-TOKEN stream's (``steps_per_sync=1``:
+  the step program, itself held to the dense ``model.generate()``) on
+  every path — plain greedy, int8 KV, prefix-cache hits,
+  preempt→resume (swap-in AND recompute), migration; sampled windows
+  walk the ``inference.sampling`` key sequence (their per-token
+  oracle under the window's own key is in ``test_step_crossings.py``);
 * window-edge semantics: EOS on a window's last step, budget
   exhaustion at the window edge, ALL rows retiring early (the
   while_loop exits before n_steps — observable via
@@ -17,7 +19,7 @@ Contracts under test:
   with ZERO recompile anomalies under an enabled CompileWatch (the
   conftest guard re-asserts this for every test in this module);
 * TPOT regression (the window-boundary over-count): only tokens
-  actually DELIVERED advance the histogram, on both step paths;
+  actually DELIVERED advance the histogram;
 * a tier-1 budget guard keeps this module's fast footprint flat.
 
 Everything runs JAX_PLATFORMS=cpu on the tiny llama config.
@@ -73,51 +75,67 @@ def _serve(model, prompts, max_new=6, admit="add", eos=None, **kw):
     return [eng.result(f"r{i}") for i in range(len(prompts))], eng
 
 
-# -- scanned window vs host-chained parity -------------------------------------
-def test_scanned_matches_host_chained_unified(model):
-    """Acceptance: the one-dispatch mixed window produces bit-identical
-    tokens to host-chained single-token dispatch AND to per-token
-    (steps_per_sync=1) stepping, for synchronous and deferred
-    admission alike."""
+# -- the window program against the per-token stream ----------------------------
+def _generate(model, prompts, max_new):
+    return [np.asarray(model.generate(
+        paddle.to_tensor(np.asarray(p, np.int32)[None]),
+        max_new_tokens=max_new)[0].numpy())[0].tolist() for p in prompts]
+
+
+def test_window_matches_the_per_token_stream(model):
+    """Acceptance: the one-dispatch window produces the tokens of
+    per-token (steps_per_sync=1) stepping — which are the dense
+    ``generate()``'s — for synchronous and deferred admission alike."""
     base, _ = _serve(model, PROMPTS, max_new=9)
-    host, _ = _serve(model, PROMPTS, max_new=9, steps_per_sync=4,
-                     scan_decode=False)
+    assert base == _generate(model, PROMPTS, 9)
     scan, _ = _serve(model, PROMPTS, max_new=9, steps_per_sync=4)
-    assert scan == host == base
+    assert scan == base
     deferred, _ = _serve(model, PROMPTS, max_new=9, admit="begin",
                          steps_per_sync=4)
     assert deferred == base
 
 
-def test_scanned_matches_host_chained_split(model):
-    """The split path's ``_paged_decode_window`` (unified_step=False):
-    same bar — scanned window == fixed-length window == per-token."""
-    base, _ = _serve(model, PROMPTS, max_new=9, unified_step=False)
-    host, _ = _serve(model, PROMPTS, max_new=9, unified_step=False,
-                     steps_per_sync=4, scan_decode=False)
-    scan, _ = _serve(model, PROMPTS, max_new=9, unified_step=False,
-                     steps_per_sync=4)
-    assert scan == host == base
+def test_two_window_buckets_match_the_per_token_stream(model):
+    """``steps_per_sync=8`` with ragged budgets walks every bucket —
+    8, 4, 2 and the plain step program — in one drain; each stream is
+    the per-token engine's."""
+    def run(sps):
+        eng = _mk(model, steps_per_sync=sps)
+        for i, (p, n) in enumerate(zip(PROMPTS, (30, 13, 7, 4))):
+            eng.add_request(f"r{i}", p, max_new_tokens=n)
+        sizes = set()
+        while eng.has_work():
+            eng.step()
+            sizes.add(eng.last_window_steps)
+        return [eng.result(f"r{i}") for i in range(4)], sizes
+
+    want, sizes = run(1)
+    assert sizes == {1}
+    got, sizes = run(8)
+    assert got == want
+    assert sizes == {8, 4, 2, 1}
 
 
-def test_scanned_int8_kv_parity(model):
-    """int8 KV pools ride the scanned window (quantize-append inside
-    the while_loop, scale rows in the carry) bit-identically."""
-    want, _ = _serve(model, PROMPTS, max_new=9, kv_dtype="int8",
-                     steps_per_sync=4, scan_decode=False)
+def test_window_int8_kv_parity(model):
+    """int8 KV pools ride the window (quantize-append inside the
+    while_loop, scale rows in the carry): the per-token int8 stream."""
+    want, _ = _serve(model, PROMPTS, max_new=9, kv_dtype="int8")
     got, _ = _serve(model, PROMPTS, max_new=9, kv_dtype="int8",
                     steps_per_sync=4)
     assert got == want
-    split, _ = _serve(model, PROMPTS, max_new=9, kv_dtype="int8",
-                      unified_step=False, steps_per_sync=4)
-    assert split == want
+    deferred, _ = _serve(model, PROMPTS, max_new=9, kv_dtype="int8",
+                         admit="begin", steps_per_sync=4)
+    assert deferred == want
 
 
 def test_sampling_key_sequence_contract(model):
-    """Stochastic decoding: the scanned window derives step keys
-    in-graph through the SAME ``split_step`` chain the host-chained
-    path walks — draws are bit-identical; ``window_keys`` pins the
-    contract against a manual ``jax.random.split`` chain."""
+    """Stochastic decoding: the window derives its step keys in-graph
+    through the ``split_step`` chain; ``window_keys`` pins the contract
+    against a manual ``jax.random.split`` chain.  On the engine: a
+    sampled window's stream is its seed's alone, and with one
+    candidate left (``top_k=1``) every draw is the greedy token —
+    the per-token stream's — so the sampling branch of the window
+    body feeds back what it drew."""
     import jax
 
     from paddle_tpu.inference.sampling import split_step, window_keys
@@ -135,21 +153,23 @@ def test_sampling_key_sequence_contract(model):
                           np.asarray(jax.random.split(key)[0]))
 
     kw = dict(decode_strategy="sampling", top_k=5, temperature=0.8,
-              seed=11, max_new=9)
-    want, _ = _serve(model, PROMPTS[:3], steps_per_sync=4,
-                     scan_decode=False, **kw)
-    got, _ = _serve(model, PROMPTS[:3], steps_per_sync=4, **kw)
-    assert got == want
+              max_new=9, steps_per_sync=4)
+    a, _ = _serve(model, PROMPTS[:3], seed=11, **kw)
+    b, _ = _serve(model, PROMPTS[:3], seed=11, **kw)
+    c, _ = _serve(model, PROMPTS[:3], seed=12, **kw)
+    assert a == b != c
+    greedy, _ = _serve(model, PROMPTS[:3], max_new=9)
+    one, _ = _serve(model, PROMPTS[:3], **dict(kw, top_k=1), seed=11)
+    assert one == greedy
 
 
-def test_prefix_cache_parity_scanned(model):
+def test_prefix_cache_parity_window(model):
     """Prefix-hit admissions (shared pages mapped host-side) decode
-    through scanned windows bit-identically, with the same hit
+    through windows as they do token by token, with the same hit
     accounting."""
     sys_p = list(range(1, 17))               # 2 full shared pages
     prompts = [sys_p + [30 + i] for i in range(3)] + [sys_p]
-    want, eh = _serve(model, prompts, max_new=8, steps_per_sync=4,
-                      scan_decode=False)
+    want, eh = _serve(model, prompts, max_new=8)
     got, es = _serve(model, prompts, max_new=8, steps_per_sync=4)
     assert got == want
     assert es.prefix_stats["hit_tokens"] == \
@@ -178,13 +198,13 @@ def test_preempt_resume_swap_parity(model):
 def test_preempt_resume_recompute_parity(model):
     """Swap pool disabled: resume replays prefill + generated tokens
     (the replay's own windows are the fixed-length program) and the
-    scanned continuation matches the uninterrupted stream."""
+    windowed continuation matches the uninterrupted stream."""
     _interrupted(model, swap_pages=0, expect_path="recompute")
 
 
 def test_migration_parity(model):
-    """Export after a scanned window on one engine, import into a
-    second scanned engine: continuation == uninterrupted stream."""
+    """Export after a window on one engine, import into a second
+    windowed engine: continuation == uninterrupted stream."""
     prompt, n = PROMPTS[1], 8
     want, _ = _serve(model, [prompt], max_new=n)
     src = _mk(model, steps_per_sync=4)
@@ -202,49 +222,42 @@ def test_migration_parity(model):
 # -- window-edge semantics -----------------------------------------------------
 def test_eos_mid_and_last_step_of_window(model):
     """EOS landing anywhere in a window — the last step included —
-    retires the request with the same tokens as host-chained dispatch
+    retires the request with the same tokens as per-token stepping
     (the in-graph done predicate mirrors the host merge exactly)."""
-    ref, _ = _serve(model, [PROMPTS[0]], max_new=9)
+    ref, _ = _serve(model, [PROMPTS[1]], max_new=9)
     # generated index g = decode step g of the first 4-step window
     # (index 0 is the prefill token): g=4 is that window's LAST step
     for g in (2, 4):
         eos = ref[0][g]
-        want, _ = _serve(model, [PROMPTS[0]], max_new=9, eos=eos,
-                         steps_per_sync=4, scan_decode=False)
-        got, _ = _serve(model, [PROMPTS[0]], max_new=9, eos=eos,
+        want, _ = _serve(model, [PROMPTS[1]], max_new=9, eos=eos)
+        got, _ = _serve(model, [PROMPTS[1]], max_new=9, eos=eos,
                         steps_per_sync=4)
-        assert got == want
-        assert got[0][-1] == eos
+        assert got == want == [ref[0][:g + 1]]
 
 
 def test_budget_exhaustion_at_window_edge(model):
     """Ragged remaining budgets: the window is capped by the SMALLEST
     remaining budget (then pow2-floored), so exhaustion only ever
     lands on a window's final step — mixed max_new values must retire
-    each request at exactly its budget, scanned or chained."""
-    def run(scan):
-        eng = _mk(model, steps_per_sync=8, scan_decode=scan)
-        eng.add_request("a", PROMPTS[0], max_new_tokens=9)
-        eng.add_request("b", PROMPTS[1], max_new_tokens=3)
-        _drain(eng)
-        return eng.result("a"), eng.result("b")
-
-    sa, sb = run(True)
-    ha, hb = run(False)
-    assert (sa, sb) == (ha, hb)
-    assert len(sa) == 9 and len(sb) == 3
+    each request at exactly its budget, with the tokens ``generate()``
+    gives for that budget."""
+    eng = _mk(model, steps_per_sync=8)
+    eng.add_request("a", PROMPTS[0], max_new_tokens=9)
+    eng.add_request("b", PROMPTS[1], max_new_tokens=3)
+    _drain(eng)
+    assert [eng.result("a")] == _generate(model, PROMPTS[:1], 9)
+    assert [eng.result("b")] == _generate(model, PROMPTS[1:2], 3)
 
 
 def test_all_rows_early_exit(model):
     """When every live row retires mid-window the while_loop stops
     paying for the remaining steps: ``last_window_steps`` comes back
-    SHORT of the bucketed n_steps, tokens still bit-identical."""
-    ref, _ = _serve(model, [PROMPTS[0]], max_new=9)
+    SHORT of the bucketed n_steps, tokens still the per-token ones."""
+    ref, _ = _serve(model, [PROMPTS[1]], max_new=9)
     eos = ref[0][2]                          # retires at decode step 2
-    want, _ = _serve(model, [PROMPTS[0]], max_new=9, eos=eos,
-                     steps_per_sync=8, scan_decode=False)
+    want, _ = _serve(model, [PROMPTS[1]], max_new=9, eos=eos)
     eng = _mk(model, steps_per_sync=8)
-    eng.add_request("r0", PROMPTS[0], max_new_tokens=9,
+    eng.add_request("r0", PROMPTS[1], max_new_tokens=9,
                     eos_token_id=eos)
     _drain(eng)
     assert [eng.result("r0")] == want
@@ -262,10 +275,7 @@ def test_steps_per_sync_one_degenerates(model):
     got, eng = _serve(model, PROMPTS[:2], max_new=6)   # default sps=1
     assert LLMEngine.window_compiles() == base
     assert eng.metrics_snapshot()["window_compiles"] == base
-    split, _ = _serve(model, PROMPTS[:2], max_new=6,
-                      unified_step=False)
-    assert split == got
-    assert LLMEngine.window_compiles() == base
+    assert got == _generate(model, PROMPTS[:2], 6)
 
 
 def test_suspend_abort_between_windows(model):
@@ -321,21 +331,20 @@ def test_window_compiles_bounded_zero_recompiles(model):
 def test_tpot_counts_delivered_tokens_only(model):
     """Regression (window-boundary TPOT over-count): a request that
     retires mid-window must advance the TPOT histogram by the tokens
-    actually delivered, not by nsteps — on BOTH step paths."""
-    ref, _ = _serve(model, [PROMPTS[0]], max_new=9)
+    actually delivered, not by nsteps — whichever way it was admitted,
+    and token by token too."""
+    ref, _ = _serve(model, [PROMPTS[1]], max_new=9)
     eos = ref[0][2]
-    for unified in (True, False):
-        for scan in (True, False):
-            eng = _mk(model, steps_per_sync=8, unified_step=unified,
-                      scan_decode=scan)
-            eng.add_request("r", PROMPTS[0], max_new_tokens=9,
-                            eos_token_id=eos)
-            _drain(eng)
-            delivered = len(eng.result("r")) - 1   # prefill tok = TTFT
+    for admit in ("add", "begin"):
+        for sps in (8, 1):
+            got, eng = _serve(model, [PROMPTS[1]], max_new=9, eos=eos,
+                              admit=admit, steps_per_sync=sps)
+            delivered = len(got[0]) - 1            # prefill tok = TTFT
             count = eng.metrics_snapshot()["tpot_seconds"]["count"]
-            assert count == delivered, (
-                f"unified={unified} scan={scan}: tpot count {count} "
-                f"!= delivered {delivered} (over-counted the window)")
+            assert count == delivered == 2, (
+                f"admit={admit} steps_per_sync={sps}: tpot count "
+                f"{count} != delivered {delivered} (over-counted the "
+                f"window)")
 
 
 # -- tier-1 budget guard -------------------------------------------------------

@@ -34,6 +34,35 @@ def test_single_request_matches_generate(model):
     assert eng.result("r0") == want
 
 
+@pytest.mark.parametrize("admit", ["add", "begin"])
+@pytest.mark.parametrize("sps", [1, 4, 8])
+def test_served_tokens_equal_generate(model, sps, admit):
+    """Every program of the step loop against the dense reference: the
+    step program (``steps_per_sync=1``), two window buckets (4, 8), the
+    prompt prefilled at admission or packed into mixed steps."""
+    prompts = [[5, 9, 2, 14], list(range(1, 20)), [3, 3, 7]]
+    eng = LLMEngine(model, max_seqs=4, max_len=64, page_size=8,
+                    steps_per_sync=sps)
+    for i, p in enumerate(prompts):
+        (eng.begin_request if admit == "begin" else eng.add_request)(
+            i, p, max_new_tokens=12)
+    windows = set()
+    while eng.has_work():
+        eng.step()
+        windows.add(eng.last_window_steps)
+    assert max(windows) == sps
+    for i, p in enumerate(prompts):
+        assert eng.result(i) == _greedy_reference(model, p, 12)
+
+
+@pytest.mark.parametrize("flag", ["unified_step", "scan_decode"])
+def test_the_step_loop_has_no_switch(model, flag):
+    """One step loop: the options that chose the split loop and the
+    host-chained window are gone, not ignored."""
+    with pytest.raises(TypeError, match=flag):
+        LLMEngine(model, **{flag: False})
+
+
 def test_continuous_batching_requests_join_and_leave(model):
     pa = [5, 9, 2, 14]
     pb = [3, 3, 7]

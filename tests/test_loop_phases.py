@@ -15,8 +15,8 @@ Contracts under test:
   ``engine.step.wait`` a step, and every phase the benchmark's
   ``engine.idle_*`` specs sum is still written;
 * the counters at the same boundary (``steps``, ``step_prefill_tokens``)
-  are exact for a fixed request list on the mixed, window and split
-  paths.
+  are exact for a fixed request list on mixed steps, on windows and
+  with prompts prefilled at admission.
 """
 import glob
 import statistics
@@ -254,14 +254,14 @@ def test_the_front_ends_loop_adds_cmds_poll_and_wait(model, tmp_path):
 @pytest.mark.parametrize("kw,path", [
     (dict(steps_per_sync=1), "mixed"),
     (dict(steps_per_sync=4), "window"),
-    (dict(steps_per_sync=4, unified_step=False), "split"),
+    (dict(steps_per_sync=4), "add"),       # prompts prefilled at admission
 ])
 def test_step_counters_are_exact(model, kw, path):
     def run():
         eng = LLMEngine(model, max_seqs=4, max_len=64, page_size=8,
                         enable_prefix_caching=False, **kw)
         for i, p in enumerate(PROMPTS):
-            if path == "split":
+            if path == "add":
                 eng.add_request(f"r{i}", p, max_new_tokens=9)
             else:
                 eng.begin_request(f"r{i}", p, max_new_tokens=9)
@@ -278,7 +278,7 @@ def test_step_counters_are_exact(model, kw, path):
     assert snap["steps"] == calls
     assert snap["prompt_tokens"] == sum(len(p) for p in PROMPTS)
     assert snap["step_prefill_tokens"] == \
-        (0 if path == "split" else sum(len(p) for p in PROMPTS))
+        (0 if path == "add" else sum(len(p) for p in PROMPTS))
     assert snap["generated_tokens"] == sum(len(t) for t in toks) == 27
     calls2, snap2, toks2 = run()              # the counts repeat exactly
     assert (calls2, toks2) == (calls, toks)

@@ -7,7 +7,7 @@ Contracts under test:
   the supported families and the ``register_backbone`` escape hatch;
 * the ONE grouped_matmul dispatch per layer produces tokens
   BIT-IDENTICAL to the dense per-expert reference on every engine
-  path — (unified_step x scan_decode) grid, int8 expert weights,
+  path — steps_per_sync x admission x KV dtype, int8 expert weights,
   capacity-factor dispatch — and through preempt -> resume on both
   restore paths (swap-in and recompute);
 * token accounting: dropless drops NOTHING; a starved capacity
@@ -77,10 +77,11 @@ def _mk(model, **kw):
     return LLMEngine(model, **kw)
 
 
-def _serve(model, prompts, max_new=6, **kw):
+def _serve(model, prompts, max_new=6, admit="add", **kw):
     eng = _mk(model, **kw)
     for i, p in enumerate(prompts):
-        eng.add_request(f"r{i}", p, max_new_tokens=max_new)
+        (eng.begin_request if admit == "begin" else eng.add_request)(
+            f"r{i}", p, max_new_tokens=max_new)
     _drain(eng)
     return [eng.result(f"r{i}") for i in range(len(prompts))], eng
 
@@ -103,14 +104,16 @@ def test_backbone_resolution_and_unsupported_error(model):
 
 
 # -- grouped vs dense bit-identity ---------------------------------------------
-@pytest.mark.parametrize("unified,scan", [(False, False), (False, True),
-                                          (True, False), (True, True)])
-def test_grouped_matches_dense_grid(model, unified, scan):
+@pytest.mark.parametrize("sps,admit,kv", [
+    (1, "add", None), (4, "add", None), (8, "begin", None),
+    (4, "begin", "int8")])
+def test_grouped_matches_dense_grid(model, sps, admit, kv):
     """Acceptance: ONE grouped_matmul dispatch per layer produces the
-    dense per-expert reference's tokens bit-for-bit on every
-    (unified_step x scan_decode) path, prefill chunks included."""
-    kw = dict(unified_step=unified, scan_decode=scan,
-              steps_per_sync=4 if scan else 1)
+    dense per-expert reference's tokens bit-for-bit in every program
+    that routes: the step program alone, two window buckets, prompts
+    prefilled by ``add_request``'s chunk program or packed into mixed
+    steps beside decode rows, int8 KV pools."""
+    kw = dict(steps_per_sync=sps, admit=admit, kv_dtype=kv)
     want, _ = _serve(model, PROMPTS, moe_dispatch="dense", **kw)
     got, _ = _serve(model, PROMPTS, moe_dispatch="grouped", **kw)
     assert got == want

@@ -235,7 +235,6 @@ def test_export_import_carries_the_state_to_another_engine(tiny):
 
 @pytest.mark.parametrize("kw,needle", [
     (dict(enable_prefix_caching=True), "enable_prefix_caching=True"),
-    (dict(unified_step=False), "unified_step=False"),
     (dict(mesh="a mesh"), "mesh="),
     (dict(draft_model="a model"), "draft_model="),
     (dict(kv_dtype="int8"), "kv_dtype='int8'"),

@@ -5,7 +5,7 @@ root conftest's ``--xla_force_host_platform_device_count=8``):
 
 * ``mesh=``/``tp_axis=`` shards the engine bit-exactly: greedy tokens
   on tp=2 are IDENTICAL to tp=1 on every path — fp, int8 KV, prefix
-  hits, unified mixed step, scanned windows — because only OUTPUT axes
+  hits, mixed steps, on-device windows — because only OUTPUT axes
   are ever sharded and every contraction input is explicitly gathered
   first (no cross-device float reduction anywhere);
 * one compile per mesh shape: a second tp=2 engine with a different
@@ -54,11 +54,12 @@ def _mk(model, tp=None, **kw):
     return LLMEngine(model, mesh=mesh, **cfg)
 
 
-def _run(eng, reqs):
+def _run(eng, reqs, admit="add"):
     """reqs: [(rid, prompt, max_new)] — staggered admission (each rid
     joins after one step) so batches churn, then drain."""
     for rid, prompt, n in reqs:
-        eng.add_request(rid, prompt, max_new_tokens=n)
+        (eng.begin_request if admit == "begin" else eng.add_request)(
+            rid, prompt, max_new_tokens=n)
         eng.step()
     while eng.has_work():
         eng.step()
@@ -70,19 +71,18 @@ _REQS = [("a", [5, 9, 2, 14], 8), ("b", [3, 3, 7], 6),
 
 
 # -- bit-identity: tp=2 vs tp=1 on every serving path -------------------------
-@pytest.mark.parametrize("kw", [
-    {},                                       # split prefill + decode
-    {"kv_dtype": "int8"},
-    {"unified_step": True},
-    {"unified_step": True, "scan_decode": True},
-    {"scan_decode": True},
-    {"unified_step": True, "kv_dtype": "int8"},
-], ids=["split", "int8", "mixed", "mixed-scan", "split-scan",
-        "mixed-int8"])
-def test_tp2_greedy_bit_identical(model, kw):
-    want = _run(_mk(model, **kw), _REQS)
-    got = _run(_mk(model, tp=2, **kw), _REQS)
-    assert got == want, f"tp=2 diverged from tp=1 on {kw}"
+@pytest.mark.parametrize("admit,kw", [
+    ("add", {"steps_per_sync": 1}),           # the step program alone
+    ("add", {}),                              # 4-step windows
+    ("add", {"steps_per_sync": 8}),           # a second window bucket
+    ("begin", {}),                            # chunks packed beside decode
+    ("add", {"kv_dtype": "int8"}),
+    ("begin", {"steps_per_sync": 1, "kv_dtype": "int8"}),
+], ids=["step", "window4", "window8", "begin", "int8", "begin-int8"])
+def test_tp2_greedy_bit_identical(model, admit, kw):
+    want = _run(_mk(model, **kw), _REQS, admit)
+    got = _run(_mk(model, tp=2, **kw), _REQS, admit)
+    assert got == want, f"tp=2 diverged from tp=1 on {admit} {kw}"
 
 
 def test_tp2_sampling_bit_identical(model):
@@ -114,14 +114,14 @@ def test_tp_must_divide_kv_heads(model):
 
 # -- the one-compile invariant per mesh shape ---------------------------------
 def test_second_tp2_engine_adds_zero_compiles(model):
-    """Warm the tp=2 unified path, then a SECOND tp=2 engine with a
+    """Warm the tp=2 step loop, then a SECOND tp=2 engine with a
     different batch mix must add zero mixed/window compiles — the
     sharded jits key on the (hashable) mesh, not the engine."""
-    _run(_mk(model, tp=2, unified_step=True, scan_decode=True), _REQS)
+    _run(_mk(model, tp=2), _REQS)
     base_m = LLMEngine.mixed_compiles()
     base_w = LLMEngine.window_compiles()
     base_p = LLMEngine.prefill_compiles()
-    eng = _mk(model, tp=2, unified_step=True, scan_decode=True)
+    eng = _mk(model, tp=2)
     _run(eng, [("x", [9, 1, 4, 4, 2], 7), ("y", [2], 3)])
     assert LLMEngine.mixed_compiles() == base_m
     assert LLMEngine.window_compiles() == base_w
@@ -133,7 +133,7 @@ def test_compile_watch_zero_recompiles_under_tp_mixed_churn(model):
     as warmup within the declared allowances — zero recompile
     anomalies and zero ``jit_recompile_events_total``."""
     w = I.enable_compile_watch()
-    eng = _mk(model, tp=2, unified_step=True, scan_decode=True)
+    eng = _mk(model, tp=2)
     _run(eng, _REQS)
     _run(eng, [("d", [8, 8, 1], 6), ("e", list(range(2, 19)), 4)])
     snap = w.snapshot()

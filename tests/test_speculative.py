@@ -7,7 +7,7 @@ Contracts under test, all on the forced 8-device CPU platform:
   token-for-token the plain engine's greedy stream on every serving
   path: fp, int8 KV, prefix-cache hits, deferred (``begin_request``)
   admission with its plain-window prefill interludes, EOS retiring a
-  request mid-window, the unified×scan flag grid, a tp=2 mesh, and
+  request mid-window, plain decode in 4-step windows, a tp=2 mesh, and
   preempt→resume over BOTH restore paths;
 * SAMPLED ACCEPTANCE — ``rejection_accept`` preserves the target's
   post-filter distribution for an arbitrary draft proposal (the
@@ -24,7 +24,7 @@ Contracts under test, all on the forced 8-device CPU platform:
   re-asserts zero recompiles for every test in this module);
 * DELIVERED-ONLY ACCOUNTING — TPOT (and through it the scheduler's
   AIMD SLO input) advances by tokens actually DELIVERED, never by
-  proposed-but-rejected draft tokens, across the unified×scan grid;
+  proposed-but-rejected draft tokens, for either admission;
 * OBSERVABILITY — acceptance counters/rate in ``metrics_snapshot()``,
   the ``/statusz`` headline, and the ``/fleetz`` federation.
 
@@ -108,7 +108,7 @@ def _serve(eng, prompts, max_new=9, admit="add", eos=None):
 
 # -- greedy bit-identity over the serving grid ---------------------------------
 @pytest.mark.parametrize("case", ["fp", "int8", "prefix", "begin",
-                                  "split-host", "eos"])
+                                  "sps4", "eos"])
 def test_greedy_bit_identical(model, draft, case):
     """Acceptance (the tentpole invariant): the speculative greedy
     stream is BIT-IDENTICAL to plain decode — matched rows deliver the
@@ -122,8 +122,8 @@ def test_greedy_bit_identical(model, draft, case):
         prompts = [PROMPTS[2], PROMPTS[2], PROMPTS[1]]  # shared pages
     elif case == "begin":
         admit = "begin"          # prefill interludes between windows
-    elif case == "split-host":
-        kw = {"unified_step": False, "scan_decode": False}
+    elif case == "sps4":
+        kw = {"steps_per_sync": 4}   # plain decode runs 4-step windows
     if case == "eos":
         ref = _serve(_mk(model), PROMPTS, max_new=9)
         eos = ref[0][3]          # retires r0 mid-window
@@ -324,19 +324,18 @@ def test_compile_stability_churning_k(model, draft):
 def test_tpot_counts_delivered_tokens_only(model, draft):
     """Regression (satellite of the window-boundary TPOT fix): the
     TPOT histogram — the scheduler AIMD's SLO input — advances by
-    DELIVERED tokens only, never by proposed draft tokens, across the
-    unified×scan grid (the flags steer the prefill-interlude path)."""
-    for unified in (True, False):
-        for scan in (True, False):
-            eng = _mk(model, draft, unified_step=unified,
-                      scan_decode=scan)
-            eng.add_request("r", PROMPTS[0], max_new_tokens=9)
-            _drain(eng)
-            delivered = len(eng.result("r")) - 1  # prefill tok = TTFT
+    DELIVERED tokens only, never by proposed draft tokens, whether the
+    prompt was prefilled at admission or in mixed-step interludes
+    between the speculative windows."""
+    for admit in ("add", "begin"):
+        for sps in (1, 4):
+            eng = _mk(model, draft, steps_per_sync=sps)
+            _serve(eng, [PROMPTS[0]], admit=admit)
+            delivered = len(eng.result("r0")) - 1  # prefill tok = TTFT
             count = eng.metrics_snapshot()["tpot_seconds"]["count"]
             assert count == delivered, (
-                f"unified={unified} scan={scan}: tpot count {count} "
-                f"!= delivered {delivered} (counted rejected "
+                f"admit={admit} steps_per_sync={sps}: tpot count "
+                f"{count} != delivered {delivered} (counted rejected "
                 f"proposals?)")
             s = eng.metrics_snapshot()["spec"]
             assert s["delivered"] == delivered

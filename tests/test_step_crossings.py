@@ -18,7 +18,9 @@ Contracts under test, all on the CPU at tiny sizes:
   whole when the engine goes idle and before ``metrics_snapshot()``
   answers, also to a reader on another thread;
 * greedy and sampled token streams equal a recording made with the
-  inner programs and the key split on the host.
+  inner programs and the key split on the host — and one made with NO
+  window program at all: the inner step program, one token a
+  dispatch, fed back on the host under the window's own key chain.
 """
 import sys
 import threading
@@ -86,41 +88,89 @@ def begin(eng, prompts=PROMPTS, max_new=6, **kw):
 
 
 # -- the parent's launch, for the same plan --------------------------------------
+def descriptors(f, key):
+    """The unpacked upload as the inner programs take it, ``ids`` to
+    ``draw_base`` (``row_tables`` gathered here from the descriptors'
+    tables), and the state arguments' ``desc_slot``."""
+    d = {k: jnp.asarray(v) for k, v in f.items()}
+    return (d["ids"], d["positions"],
+            jnp.asarray(f["desc_tables"][f["desc_of_row"]]),
+            d["q_start"], d["q_len"], d["kv_len"], d["desc_tables"],
+            d["desc_of_row"], d["off_of_row"], key, d["draw_base"]), \
+        d.get("desc_slot")
+
+
 def inner_call(fn, args, kw):
     """What the engine launched before the wrappers: the key split on
     the host, the inner program fed array by array (``row_tables``
     gathered here from the descriptors' tables; the planner's own is
     compared with that gather in the test of the plans below)."""
-    window = fn is E._packed_mixed_window
-    (stack, norm_w, head_w, embed_w, rope, kp, vp, ks, vs, packed, key,
-     rec, conv) = args
+    packed, key, rec, conv = args[9:]
     kw = dict(kw)
     f = E._unpack_step(np.asarray(packed), kw.pop("geom"),
                        kw.get("hybrid") is not None)
-    next_key, sub = jax.random.split(key)
-    run_key = sub if window or int(f["fresh"]) else key
-    d = {k: jnp.asarray(v) for k, v in f.items()}
-    common = (stack, norm_w, head_w, embed_w, rope, kp, vp, ks, vs,
-              d["ids"], d["positions"],
-              jnp.asarray(f["desc_tables"][f["desc_of_row"]]),
-              d["q_start"], d["q_len"], d["kv_len"], d["desc_tables"],
-              d["desc_of_row"], d["off_of_row"], run_key, d["draw_base"])
-    state = (rec, conv, d.get("desc_slot"))
-    if window:
-        res = E._paged_mixed_window(*common, d["eos_ids"], d["budgets"],
-                                    d["n_rows"], *state, **kw)
-        toks, done, pools, chain, rest = \
-            res[0], int(res[2]), res[3:7], res[7], res[8:]
+    next_key, run_key = jax.random.split(key)
+    desc, desc_slot = descriptors(f, run_key)
+    common, state = args[:9] + desc, (rec, conv, desc_slot)
+    if fn is E._packed_mixed_window:
+        res = E._paged_mixed_window(
+            *common, *(jnp.asarray(f[k]) for k in (
+                "eos_ids", "budgets", "n_rows")), *state, **kw)
+        toks, done, pools, rest = res[0], int(res[2]), res[3:7], res[8:]
     else:
         res = E._paged_mixed_step(*common, *state, **kw)
-        toks, done, pools, chain, rest = \
-            res[0], 1, res[1:5], res[5], res[6:]
+        toks, done, pools, rest = res[0], 1, res[1:5], res[6:]
     counts = None
     if kw.get("arch") is not None:
         counts, rest = np.asarray(rest[0]), rest[1:]
     return dict(toks=np.asarray(toks, np.int32).ravel(), done=done,
                 words=key_fingerprint(run_key), counts=counts,
-                pools=pools, next_key=next_key, chain=chain, lin=rest)
+                pools=pools, next_key=next_key, lin=rest)
+
+
+def per_token_call(fn, args, kw):
+    """The window program's reference: NO on-device loop.  A window is
+    served by the inner STEP program, one token a dispatch; the host
+    feeds each live row's token back as its next input, bumps its
+    position and length, walks the window key's ``split_step`` chain
+    (the step program hands the chain key back) and stops once every
+    live row has met its EOS or its budget — ``inner_call``'s result
+    form.  A step launch is ``inner_call``'s."""
+    if fn is not E._packed_mixed_window:
+        return inner_call(fn, args, kw)
+    weights, pools, (packed, key, rec, conv) = args[:5], args[5:9], args[9:]
+    kw = dict(kw)
+    n_steps = kw.pop("n_steps")
+    f = E._unpack_step(np.array(packed), kw.pop("geom"),
+                       kw.get("hybrid") is not None)
+    next_key, chain = jax.random.split(key)
+    words = key_fingerprint(chain)
+    n, t = int(f["n_rows"]), f["ids"].size
+    toks = np.zeros((n_steps, t), np.int32)
+    emitted, live = np.zeros(n, np.int32), np.ones(n, bool)
+    counts, done = None, 0
+    while done < n_steps and live.any():
+        desc, desc_slot = descriptors(f, chain)
+        res = E._paged_mixed_step(*weights, *pools, *desc, rec, conv,
+                                  desc_slot, **kw)
+        nxt, pools, chain, rest = \
+            np.asarray(res[0], np.int32), res[1:5], res[5], res[6:]
+        if kw.get("arch") is not None:
+            counts = np.asarray(rest[0]) + (0 if counts is None else counts)
+            rest = rest[1:]
+        if rest:
+            rec, conv = rest
+        toks[done] = nxt
+        done += 1
+        emitted += live
+        live &= ~(((f["eos_ids"][:n] >= 0) & (nxt[:n] == f["eos_ids"][:n]))
+                  | (emitted >= f["budgets"][:n]))
+        f["ids"][:n] = nxt[:n]
+        f["positions"][:n] += 1
+        f["kv_len"][:n] += 1
+    return dict(toks=toks.ravel(), done=done, words=words, counts=counts,
+                pools=pools, next_key=next_key,
+                lin=(rec, conv) if kw.get("hybrid") is not None else ())
 
 
 def as_packed(want):
@@ -130,7 +180,7 @@ def as_packed(want):
     if want["counts"] is not None:
         parts.append(want["counts"].astype(np.int32).ravel())
     return (jnp.asarray(np.concatenate(parts)),) + tuple(want["pools"]) \
-        + (want["next_key"], want["chain"]) + tuple(want["lin"])
+        + (want["next_key"],) + tuple(want["lin"])
 
 
 def same(a, b):
@@ -166,8 +216,8 @@ def shadow(monkeypatch):
         if counts is not None:
             np.testing.assert_array_equal(counts, want["counts"])
         same(got[1:5], want["pools"])
-        same(got[5:7], (want["next_key"], want["chain"]))
-        same(got[7:], want["lin"])
+        same(got[5], want["next_key"])
+        same(got[6:], want["lin"])
         seen.append(dict(program=program, done=done, counts=counts,
                          packed=np.array(args[9])))
         return got
@@ -175,14 +225,17 @@ def shadow(monkeypatch):
     return seen
 
 
-@pytest.fixture
-def inner_only(monkeypatch):
-    """The engine runs on the inner programs alone, as the parent did."""
+@pytest.fixture(params=[inner_call, per_token_call],
+                ids=["inner_only", "per_token"])
+def oracle(request, monkeypatch):
+    """Once called, the engine runs on the inner programs alone, the
+    key split on the host (``inner_only``), or without any window
+    program at all (``per_token``)."""
     def call(program, fn, *args, **kw):
         if fn not in PACKED:
             return fn(*args, **kw)
-        return as_packed(inner_call(fn, args, kw))
-    monkeypatch.setattr(E._insp, "watched_call", call)
+        return as_packed(request.param(fn, args, kw))
+    return lambda: monkeypatch.setattr(E._insp, "watched_call", call)
 
 
 # -- (a) wrapper == inner, dispatch by dispatch ----------------------------------
@@ -350,26 +403,6 @@ def test_a_step_makes_one_upload_and_one_blocking_read(
                 f'{snap["host_transfers"][way]}') in text
 
 
-def test_a_host_chained_window_crosses_once_a_token(moe):
-    """``scan_decode=False``: a window is host-chained dispatches of the
-    step program, each its own upload and read — under the chain key,
-    so the tokens are the on-device window's."""
-    kw = dict(MOE, steps_per_sync=4, decode_strategy="sampling",
-              top_k=5, temperature=0.8, seed=11)
-    outs = []
-    for scan in (True, False):
-        eng = LLMEngine(moe, scan_decode=scan, **kw)
-        begin(eng, PROMPTS[:2], max_new=9)
-        outs.append(run(eng))
-        snap = eng.metrics_snapshot()
-        if scan:
-            assert snap["host_transfers"]["in"] == snap["steps"]
-        else:
-            assert snap["host_transfers"]["in"] > snap["steps"]
-        assert snap["host_transfers"]["in"] == snap["host_transfers"]["out"]
-    assert outs[0] == outs[1]
-
-
 # -- (d) the expert counters lose nothing -----------------------------------------
 def test_expert_counts_fold_one_dispatch_behind_and_whole_at_idle(
         moe, shadow):
@@ -428,24 +461,26 @@ def test_a_snapshot_mid_run_drains_the_counts_first(moe, shadow):
         {"engine.mixed_step", "engine.mixed_window"}
 
 
-@pytest.mark.parametrize("kw", [
-    dict(unified_step=False, steps_per_sync=4),
-    dict(unified_step=False, steps_per_sync=4, scan_decode=False),
-    dict(moe_dropless=False, moe_capacity_factor=0.5, steps_per_sync=1)],
-    ids=["split_window", "split_scan", "capacity_drops"])
-def test_every_path_counts_through_the_one_fold(moe, kw, inner_only):
-    """The split programs, admission's prefill chunks and a capacity
-    factor that drops: totals equal whichever programs ran, and the
-    parent's accounting identity (kept + dropped = routed) holds."""
+@pytest.mark.parametrize("admit,kw", [
+    ("add", dict(steps_per_sync=4)),
+    ("add", dict(moe_dropless=False, moe_capacity_factor=0.5,
+                 steps_per_sync=4)),
+    ("begin", dict(moe_dropless=False, moe_capacity_factor=0.5,
+                   steps_per_sync=1))],
+    ids=["add_window", "add_capacity_drops", "begin_capacity_drops"])
+def test_every_path_counts_through_the_one_fold(moe, admit, kw, oracle):
+    """Admission's own prefill-chunk program, windows and a capacity
+    factor that drops, wherever the prompt was prefilled: totals equal
+    whichever programs ran, and the accounting identity (kept + dropped
+    = routed) holds."""
     cfg = {k: v for k, v in MOE.items() if k != "prefill_token_budget"}
+    oracle()
 
     def serve(**extra):
         eng = LLMEngine(moe, **dict(cfg, **kw, **extra))
         for i, p in enumerate(PROMPTS):
-            if eng.unified_step:
-                eng.begin_request(f"r{i}", p, max_new_tokens=6)
-            else:
-                eng.add_request(f"r{i}", p, max_new_tokens=6)
+            (eng.begin_request if admit == "begin" else eng.add_request)(
+                f"r{i}", p, max_new_tokens=6)
         return run(eng), eng
     out, eng = serve()
     assert not eng._counts_aside
@@ -455,10 +490,7 @@ def test_every_path_counts_through_the_one_fold(moe, kw, inner_only):
     layers, top_k = eng._moe_counts.shape[0], moe_snap["top_k"]
     routed = (sum(len(p) for p in PROMPTS)
               + sum(len(t) - 1 for t in out.values())) * top_k * layers
-    if eng.unified_step:
-        assert moe_snap["dropped_tokens"] > 0
-    else:
-        assert moe_snap["dropped_tokens"] == 0
+    assert (moe_snap["dropped_tokens"] > 0) == ("moe_dropless" in kw)
     assert sum(moe_snap["expert_tokens"]) + moe_snap["dropped_tokens"] \
         >= routed                      # windows also route retired rows
     dense_out, dense_eng = serve(moe_dispatch="dense")
@@ -521,13 +553,12 @@ def test_a_reader_on_another_thread_loses_no_count(moe):
 @pytest.mark.parametrize("sampling", [
     dict(),
     dict(decode_strategy="sampling", top_k=5, temperature=0.8, seed=11),
-    dict(decode_strategy="sampling", top_p=0.9, seed=5,
-         scan_decode=False)],
-    ids=["greedy", "sampled", "sampled_host_chained"])
+    dict(decode_strategy="sampling", top_p=0.9, seed=5)],
+    ids=["greedy", "sampled_top_k", "sampled_top_p"])
 @pytest.mark.parametrize("model,cfg", [
     ("moe", dict(MOE, steps_per_sync=4)), ("hybrid", HYBRID)])
 def test_token_streams_equal_a_recording_made_with_the_inner_programs(
-        request, monkeypatch, model, cfg, sampling):
+        request, oracle, model, cfg, sampling):
     from paddle_tpu.observability import capsule as C
     net = request.getfixturevalue(model)
 
@@ -544,7 +575,7 @@ def test_token_streams_equal_a_recording_made_with_the_inner_programs(
             C.disable_capsule_capture()
         return out, keys, np.asarray(eng._key)
     got = serve()
-    request.getfixturevalue("inner_only")
+    oracle()
     want = serve()
     assert got[0] == want[0]
     # the windows' keys, as the capsules recorded them, and the key the

@@ -303,8 +303,7 @@ class TestServingTracing:
         assert on == off               # tracing cannot touch tokens
         # tracing adds ZERO compiles (counts are relative: tier-1 runs
         # every module in one process, so other geometries may already
-        # hold cache entries; a fresh-process run measures exactly 1 —
-        # bench_trace records it in BENCH_r09.json)
+        # hold cache entries; a fresh-process run measures exactly 1)
         assert LLMEngine.prefill_compiles() == pc >= 1
         assert LLMEngine.decode_compiles() == dc
 
