@@ -123,6 +123,7 @@ from ..observability import tracing as _tracing
 from ..observability.tracing import phase as _phase
 from ..profiler import RecordEvent
 from . import sampling as _sampling
+from .moe_dispatch import expert_buffer_rows
 from .paged_cache import PagedKVCache
 
 __all__ = ["LLMEngine", "GenRequest"]
@@ -1702,6 +1703,10 @@ class LLMEngine:
             # slots routed to experts this engine does not hold (an
             # expert share): counted, computed by no one here
             self._moe_absent = 0
+            # rows of the sorted buffer the grouped dispatch handed its
+            # kernels (m_pad x MoE layers x forwards: static a
+            # dispatch), which ``row_fill`` divides the kept slots by
+            self._moe_buffer_rows = 0
         # dispatches' counts put aside (host numbers already) until
         # ``_fold_expert_counts`` folds them behind the next launch;
         # the lock is for a reader on another thread (``/statusz``)
@@ -2119,6 +2124,12 @@ class LLMEngine:
                 "share this engine holds (they add +0 here; the "
                 "deployment's other chips compute them).",
                 lbl).labels(eid)
+            self._metrics["expert_buffer_rows"] = reg.counter(
+                "llm_engine_expert_buffer_rows_total",
+                "Rows of the sorted, tile-padded buffer the grouped "
+                "expert dispatch handed its kernels, summed over MoE "
+                "layers and forwards (kept slots held here over this "
+                "is the snapshot's row_fill).", lbl).labels(eid)
             self._metrics["expert_imbalance"] = reg.gauge(
                 "llm_engine_expert_imbalance",
                 "Max/mean cumulative per-expert routed load across "
@@ -2177,19 +2188,24 @@ class LLMEngine:
         m["mixed_compiles"].set(self.mixed_compiles())
         m["window_compiles"].set(self.window_compiles())
 
-    def _note_expert_counts(self, counts, routed_slots: int):
+    def _note_expert_counts(self, counts, routed_slots: int, rows: int,
+                            forwards: int = 1):
         """Put one MoE dispatch's routed-token counts ([L, E]: a slice
         of the unified step's one read-back, or another path's device
         array, read here) aside for ``_fold_expert_counts``.
         ``routed_slots`` is the number of live (row, top-k) slots the
-        dispatch routed PER LAYER — kept + capacity-dropped.  What an
+        dispatch routed PER LAYER — kept + capacity-dropped; ``rows``
+        the token rows (live or padding) each of its ``forwards`` ran
+        the expert layer over, which fixes the sorted buffer's size
+        (``expert_buffer_rows``: shapes, nothing read).  What an
         earlier dispatch put aside is folded first, behind this one's
         launch, so the counters are never more than one dispatch
         behind.  One read per dispatch WINDOW, never per token."""
         self._fold_expert_counts("behind_launch")
         cnt = np.asarray(counts, np.int64)
         with self._counts_lock:
-            self._counts_aside.append((cnt, int(routed_slots)))
+            self._counts_aside.append(
+                (cnt, int(routed_slots), int(rows), int(forwards)))
 
     def _crossed(self, way: str):
         """A unified step crossed the host-device boundary: one upload
@@ -2212,11 +2228,14 @@ class LLMEngine:
             aside, self._counts_aside = self._counts_aside, []
             if not aside:
                 return
-            cnt = sum(c for c, _ in aside)
-            dropped = sum(r for _, r in aside) * cnt.shape[0] \
+            cnt = sum(a[0] for a in aside)
+            dropped = sum(a[1] for a in aside) * cnt.shape[0] \
                 - int(cnt.sum())
+            buf = sum(expert_buffer_rows(self._arch, rows) * forwards
+                      for _, _, rows, forwards in aside) * cnt.shape[0]
             self._moe_counts += cnt
             self._moe_dropped += dropped
+            self._moe_buffer_rows += buf
             absent = 0
             if self._arch.experts_held:
                 lo = self._arch.expert_lo
@@ -2229,6 +2248,8 @@ class LLMEngine:
             self._metrics["folds_" + when].inc()
             if absent:
                 self._metrics["expert_absent"].inc(absent)
+            if buf:
+                self._metrics["expert_buffer_rows"].inc(buf)
             # hundreds of labelled counts a dispatch (L x E): one lock,
             # label tuples made once
             names = self._expert_label_values
@@ -2284,7 +2305,7 @@ class LLMEngine:
                 shardings=self._shardings, arch=self._arch)
             if self._arch is not None:
                 self._note_expert_counts(
-                    out[-1], real * self._arch.top_k)
+                    out[-1], real * self._arch.top_k, rows=P)
                 out = out[:-1]
             (logits, self.cache.k_pages, self.cache.v_pages,
              self.cache.k_scales, self.cache.v_scales) = out
@@ -2338,7 +2359,8 @@ class LLMEngine:
                 shardings=self._shardings, arch=self._arch)
             if self._arch is not None:
                 self._note_expert_counts(
-                    out[-1], self._arch.top_k * nsteps)
+                    out[-1], self._arch.top_k * nsteps,
+                    rows=self.max_seqs, forwards=nsteps)
                 out = out[:-1]
             (_, self.cache.k_pages, self.cache.v_pages,
              self.cache.k_scales, self.cache.v_scales) = out
@@ -2578,7 +2600,7 @@ class LLMEngine:
          self.cache.k_scales, self.cache.v_scales, _) = res[:6]
         if self._arch is not None:
             self._note_expert_counts(
-                res[6], len(rows) * kw * self._arch.top_k)
+                res[6], len(rows) * kw * self._arch.top_k, rows=T)
         if sampled:
             p_all = np.asarray(jax.device_get(res[-1]), np.float64)
         nxt = np.asarray(jax.device_get(nxt))
@@ -3142,7 +3164,8 @@ class LLMEngine:
                 # step of a window, + the packed prefill tokens
                 # (multi-step windows are pure decode)
                 self._note_expert_counts(
-                    counts, (n * steps_done + used) * self._arch.top_k)
+                    counts, (n * steps_done + used) * self._arch.top_k,
+                    rows=t_cap, forwards=steps_done)
         dt_win = time.perf_counter() - t_win
 
         with _phase("engine.step.merge"):
@@ -3716,6 +3739,13 @@ class LLMEngine:
                 "experts_held": self._arch.n_held,
                 "absent_slots": int(self._moe_absent),
                 "dropped_tokens": int(self._moe_dropped),
+                # how full the grouped dispatch's sorted buffer ran:
+                # kept slots of experts held here / rows handed to the
+                # kernels (0.0 before any, and for the dense reference)
+                "buffer_rows": int(self._moe_buffer_rows),
+                "row_fill": ((int(tot.sum()) - int(self._moe_absent))
+                             / self._moe_buffer_rows
+                             if self._moe_buffer_rows else 0.0),
                 "imbalance": (float(tot.max() / tot.mean())
                               if tot.sum() else 0.0),
             }

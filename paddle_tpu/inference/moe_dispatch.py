@@ -15,9 +15,13 @@ Two dispatch modes, BIT-IDENTICAL on CPU by construction:
 
 - ``grouped`` — the production shape: sort routed slots by expert into
   the tile-aligned dropless layout (ops/pallas/grouped_matmul.py's
-  ``make_dropless_plan_rows``) and run ONE grouped matmul per
-  projection per layer (no per-expert programs).  On TPU the Pallas
-  ``gmm`` kernels do the work; on CPU the per-row gathered-einsum
+  ``make_dropless_plan_rows``), a buffer in the rows' own dtype, and
+  run TWO Pallas calls a layer over it (no per-expert programs):
+  ``gmm_glu`` — gate and up in one pass over the rows, ``silu(g) * u``
+  on the float32 accumulators in VMEM — and ``gmm`` for the down
+  projection, both writing float32.  (Int8 expert pairs keep one
+  ``gmm`` a projection: their scale must land before ``silu``.)  On
+  CPU the per-row gathered-einsum
   oracle (``gmm_reference``'s idiom) does — which is exactly the
   row-wise math the dense mode runs, so the two modes agree bit for
   bit off-TPU (each row's contraction is independent of every other
@@ -42,9 +46,10 @@ scales that multiply the contraction OUTPUT — same fold the engine's
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
-__all__ = ["MoEArch", "moe_ffn"]
+__all__ = ["MoEArch", "moe_ffn", "expert_buffer_rows"]
 
 
 class MoEArch(NamedTuple):
@@ -104,13 +109,40 @@ def _expert_rows_mm(x, w, row_expert):
                       wr.astype(jnp.float32))
 
 
+def _row_tile(arch, t):
+    """The sorted buffer's row tile for a dispatch over ``t`` rows."""
+    from ..ops.pallas.grouped_matmul import _auto_tm
+    from ..runtime.device import is_compiled_with_tpu
+    return _auto_tm(arch.n_held, t * arch.top_k) \
+        if is_compiled_with_tpu() else 8
+
+
+def expert_buffer_rows(arch, t):
+    """Rows of the sorted buffer ONE grouped dispatch over ``t`` token
+    rows hands its kernels in a layer (``m_pad``: static, live or
+    not) — what the engine's ``row_fill`` divides the kept slots by.
+    The dense reference has no buffer: 0."""
+    from ..ops.pallas.grouped_matmul import padded_rows
+    if arch.dispatch != "grouped":
+        return 0
+    return padded_rows(t * arch.top_k, arch.n_held, _row_tile(arch, t))
+
+
+def _on_shards(fn, shardings, n_args):
+    """``fn`` as it is, or — under a tp mesh, where the expert stacks
+    are replicated — run whole by every shard (a Mosaic call cannot be
+    partitioned by GSPMD: ``TPShardings.per_shard``)."""
+    if shardings is None:
+        return fn
+    return lambda *a: shardings.per_shard(
+        lambda *b: (fn(*b),), (None,) * n_args, (None,))(*a)[0]
+
+
 def _gmm_apply(xs, w, tile_expert, gcounts, tm, on_tpu, shardings, base,
                e):
-    """One grouped matmul over the sorted tile-aligned buffer: the
-    Pallas kernel on TPU, the per-row oracle (same rows, same math as
-    dense mode) on CPU.  Under a tp mesh the expert stacks are
-    replicated, so every shard runs the whole kernel (a Mosaic call
-    cannot be partitioned by GSPMD: ``TPShardings.per_shard``).
+    """One grouped matmul over the sorted tile-aligned buffer, float32
+    out whatever the rows' dtype: the Pallas kernel on TPU, the per-row
+    oracle (same rows, same math as dense mode) on CPU.
 
     ``base`` (see ``moe_ffn``'s ``expert_base``) is where this layer's
     ``e`` experts start in ``w``: it rides the kernel's tile→expert
@@ -124,12 +156,8 @@ def _gmm_apply(xs, w, tile_expert, gcounts, tm, on_tpu, shardings, base,
         row_e = jnp.repeat(tile_expert, tm) + base
         return _expert_rows_mm(xs, w, row_e)
 
-    def gmm(lhs, rhs, te, gc, tm):
-        if shardings is None:
-            return grouped_matmul.gmm(lhs, rhs, te, gc, tm=tm)
-        return shardings.per_shard(
-            lambda *a: (grouped_matmul.gmm(*a, tm=tm),),
-            (None,) * 4, (None,))(lhs, rhs, te, gc)[0]
+    gmm = _on_shards(functools.partial(
+        grouped_matmul.gmm, tm=tm, out_dtype=jnp.float32), shardings, 4)
     if isinstance(w, tuple):
         # the kernel streams one weight dtype; upcast feeds the MXU
         # copy XLA fuses into the kernel's input stream, and the
@@ -139,9 +167,34 @@ def _gmm_apply(xs, w, tile_expert, gcounts, tm, on_tpu, shardings, base,
             # the upcast is a copy anyway: take this layer's rows first
             qw = jax.lax.dynamic_slice_in_dim(qw, base, e)
             sc = jax.lax.dynamic_slice_in_dim(sc, base, e)
-        y = gmm(xs, qw.astype(xs.dtype), tile_expert, gcounts, tm)
+        y = gmm(xs, qw.astype(xs.dtype), tile_expert, gcounts)
         return y * sc[jnp.repeat(tile_expert, tm)]
-    return gmm(xs, w, tile_expert + base, gcounts, tm)
+    return gmm(xs, w, tile_expert + base, gcounts)
+
+
+def _gate_up_apply(xs, wg, wu, tile_expert, gcounts, tm, on_tpu, shardings,
+                   base, e):
+    """``silu(xs @ wg[e]) * (xs @ wu[e])`` over the sorted buffer,
+    float32.  Float expert stacks on a TPU: ONE ``gmm_glu`` call — the
+    rows stream once for both projections and the epilogue runs on the
+    float32 accumulators in VMEM, so neither product goes to HBM —
+    reaching into the stacks through ``tile_expert + base`` as ``gmm``
+    does.  Int8 pairs (their per-channel scale must land before the
+    ``silu``) and the CPU oracle keep a call a projection."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.pallas import grouped_matmul
+    if on_tpu and not isinstance(wg, tuple) and not isinstance(wu, tuple):
+        glu = _on_shards(functools.partial(
+            grouped_matmul.gate_up, tm=tm, out_dtype=jnp.float32),
+            shardings, 5)
+        return glu(xs, wg, wu, tile_expert + base, gcounts)
+    hg = _gmm_apply(xs, wg, tile_expert, gcounts, tm, on_tpu, shardings,
+                    base, e)
+    hu = _gmm_apply(xs, wu, tile_expert, gcounts, tm, on_tpu, shardings,
+                    base, e)
+    return jax.nn.silu(hg) * hu
 
 
 def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
@@ -173,8 +226,7 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
     import jax
     import jax.numpy as jnp
 
-    from ..ops.pallas.grouped_matmul import (_auto_tm,
-                                             make_dropless_plan_rows)
+    from ..ops.pallas.grouped_matmul import make_dropless_plan_rows
     from ..runtime.device import is_compiled_with_tpu
 
     rw, egw, euw, edw, sgw, suw, sdw, seg = mw
@@ -231,22 +283,20 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
 
     if arch.dispatch == "grouped":
         on_tpu = is_compiled_with_tpu()
-        tm = _auto_tm(n_held, t * k) if on_tpu else 8
+        tm = _row_tile(arch, t)
         order, dest, valid_sorted, tile_expert, gcounts, m_pad = \
             make_dropless_plan_rows(row_expert, n_held, tm)
-        xs = jnp.zeros((m_pad, h), f32).at[dest].set(
-            xf[order // k], mode="drop")
-        hg = _gmm_apply(xs, egw, tile_expert, gcounts, tm, on_tpu,
-                        shardings, expert_base, n_held)
-        hu = _gmm_apply(xs, euw, tile_expert, gcounts, tm, on_tpu,
-                        shardings, expert_base, n_held)
-        hs = (jax.nn.silu(hg.astype(f32))
-              * hu.astype(f32)).astype(xs.dtype)
+        # the routed rows travel in their own dtype (``xf`` is an exact
+        # widening of ``hn``: the kernels read the same values at half
+        # the bytes); what the kernels write is float32, as before
+        xs = jnp.zeros((m_pad, h), hn.dtype).at[dest].set(
+            hn[order // k], mode="drop")
+        hs = _gate_up_apply(xs, egw, euw, tile_expert, gcounts, tm,
+                            on_tpu, shardings, expert_base, n_held)
         ys = _gmm_apply(hs, edw, tile_expert, gcounts, tm, on_tpu,
                         shardings, expert_base, n_held)
         dest_safe = jnp.minimum(dest, m_pad - 1)
-        y_sorted = jnp.where(valid_sorted[:, None],
-                             ys[dest_safe].astype(f32), 0.0)
+        y_sorted = jnp.where(valid_sorted[:, None], ys[dest_safe], 0.0)
         y = jnp.zeros((t * k, h), f32).at[order].set(y_sorted)
     else:
         # dense per-expert reference: the same row-wise contractions

@@ -26,6 +26,7 @@ static shapes).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -34,10 +35,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .vma import out_sds
 
-__all__ = ["grouped_matmul", "glu_grouped", "gmm_reference",
+__all__ = ["grouped_matmul", "glu_grouped", "gate_up", "gmm_reference",
            "make_dropless_plan",
            "make_dropless_plan_rows", "dropless_moe_ffn",
            "dropless_moe_ffn_rows"]
+
+
+class GmmCfg(NamedTuple):
+    """The static argument of the two custom-vjp entries: row tile,
+    K block, N block, interpret mode, and what the forward's float32
+    accumulators are rounded to on the way out (``None``: the rows'
+    dtype; gradients always take their primal's)."""
+    tm: int
+    tk: int
+    tn: int
+    interpret: bool = False
+    out_dtype: object = None
 
 
 def _pick_tile(dim: int, cap: int) -> int:
@@ -62,9 +75,63 @@ def _pick_tile(dim: int, cap: int) -> int:
     return t
 
 
+# A call's blocks live in VMEM twice over (Pallas double-buffers every
+# input and output block) beside its float32 accumulators.  Mosaic
+# scopes a call to 16 MiB of a v5e's 128 MiB unless the call asks for
+# more, so a call whose blocks need more says so — and the block
+# choosers below never plan past ``_VMEM_BUDGET``.
+_SCOPED_VMEM = 16 * 2 ** 20
+_VMEM_HEADROOM = 2 * 2 ** 20       # Mosaic's own scratch beside the blocks
+_VMEM_BUDGET = 40 * 2 ** 20
+# Row tiles this small mean a handful of rows an expert (serving): the
+# call is bound by the expert matrices it streams, not by the MXU.
+_WEIGHT_BOUND_TM = 64
+
+
+def _vmem_bytes(blocks, acc_elems):
+    """``blocks``: (elements, dtype) of every input and output block."""
+    return sum(2 * n * jnp.dtype(dt).itemsize for n, dt in blocks) \
+        + 4 * acc_elems
+
+
+def _vmem_params(need):
+    """``compiler_params`` for a call whose blocks take ``need`` bytes:
+    nothing under Mosaic's default scope, else the limit it needs."""
+    if need + _VMEM_HEADROOM <= _SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need + _VMEM_HEADROOM)
+
+
+def _whole_matrix_fits(tm, k, n, n_w, row_dt, w_dt, out_dt):
+    """Whether ``n_w`` WHOLE [k, n] expert matrices fit the budget as
+    one block each beside a weight-bound row tile.  With one block a
+    matrix, a tile of the same expert as the tile before it finds its
+    weights in VMEM (Pallas skips a DMA whose block index did not
+    change): each expert's matrix crosses HBM once a call, and the
+    tiles past the last live one — all mapped to the last expert —
+    fetch nothing.  Split along K or N, every tile fetches every block
+    again, the empty ones too."""
+    if tm > _WEIGHT_BOUND_TM:
+        return False
+    need = _vmem_bytes([(tm * k, row_dt), (tm * n, out_dt)]
+                       + [(k * n, w_dt)] * n_w, n_w * tm * n)
+    return need <= _VMEM_BUDGET
+
+
 # ---------------------------------------------------------------------------
 # out[i] = lhs[i] @ w[e(i)]    (and the dX variant via transpose_w)
 # ---------------------------------------------------------------------------
+
+def _mxu_pair(a, b):
+    """The two operands of a kernel's dot.  A bf16 pair goes to the MXU
+    as it is: a product of two bf16 values is exact in float32 and the
+    accumulators are float32, so nothing is rounded that a widened pair
+    would not round, at a fraction of the passes.  Anything else is
+    widened to float32 first."""
+    if a.dtype == b.dtype == jnp.bfloat16:
+        return a, b
+    return a.astype(jnp.float32), b.astype(jnp.float32)
+
 
 def _gmm_kernel(te_ref, lhs_ref, w_ref, out_ref, acc_ref, *, nc,
                 transpose_w):
@@ -74,8 +141,7 @@ def _gmm_kernel(te_ref, lhs_ref, w_ref, out_ref, acc_ref, *, nc,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = lhs_ref[...].astype(jnp.float32)                   # [tm, tc]
-    b = w_ref[0].astype(jnp.float32)                       # [tc,tj]|[tj,tc]
+    a, b = _mxu_pair(lhs_ref[...], w_ref[0])     # [tm, tc], [tc,tj]|[tj,tc]
     dims = (((1,), (1,)), ((), ())) if transpose_w \
         else (((1,), (0,)), ((), ()))
     acc_ref[...] += jax.lax.dot_general(
@@ -87,7 +153,9 @@ def _gmm_kernel(te_ref, lhs_ref, w_ref, out_ref, acc_ref, *, nc,
 
 
 def _gmm_call(lhs, w, tile_expert, *, transpose_w, tm, tc, tj,
-              interpret=False):
+              interpret=False, out_dtype=None):
+    """``out_dtype`` (default ``lhs.dtype``): what the float32
+    accumulator is rounded to on its way out."""
     m, _ = lhs.shape
     if transpose_w:      # w [E, J, C], contract C
         j_dim = w.shape[1]
@@ -98,9 +166,13 @@ def _gmm_call(lhs, w, tile_expert, *, transpose_w, tm, tc, tj,
         w_block = (1, tc, tj)
         w_imap = lambda i, j, c, te: (te[i], c, j)
     nm, nj, nc = m // tm, j_dim // tj, lhs.shape[1] // tc
+    out_dtype = out_dtype or lhs.dtype
+    need = _vmem_bytes([(tm * tc, lhs.dtype), (tc * tj, w.dtype),
+                        (tm * tj, out_dtype)], tm * tj)
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, nc=nc, transpose_w=transpose_w),
         name="gmm",
+        compiler_params=_vmem_params(need),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nm, nj, nc),
@@ -111,7 +183,7 @@ def _gmm_call(lhs, w, tile_expert, *, transpose_w, tm, tc, tj,
             out_specs=pl.BlockSpec((tm, tj), lambda i, j, c, te: (i, j)),
             scratch_shapes=[pltpu.VMEM((tm, tj), jnp.float32)],
         ),
-        out_shape=out_sds((m, j_dim), lhs.dtype, tile_expert, lhs, w),
+        out_shape=out_sds((m, j_dim), out_dtype, tile_expert, lhs, w),
         interpret=interpret,
     )(tile_expert.astype(jnp.int32), lhs, w)
     return out
@@ -140,14 +212,12 @@ def _gmm_glu_kernel(te_ref, lhs_ref, wg_ref, wu_ref, *refs, nc,
         accg_ref[...] = jnp.zeros_like(accg_ref)
         accu_ref[...] = jnp.zeros_like(accu_ref)
 
-    a = lhs_ref[...].astype(jnp.float32)                   # [tm, tc]
-    dims = (((1,), (0,)), ((), ()))
-    accg_ref[...] += jax.lax.dot_general(
-        a, wg_ref[0].astype(jnp.float32), dims,
-        preferred_element_type=jnp.float32)
-    accu_ref[...] += jax.lax.dot_general(
-        a, wu_ref[0].astype(jnp.float32), dims,
-        preferred_element_type=jnp.float32)
+    lhs = lhs_ref[...]                                     # [tm, tc]
+    for w_ref, acc_ref in ((wg_ref, accg_ref), (wu_ref, accu_ref)):
+        a, b = _mxu_pair(lhs, w_ref[0])
+        acc_ref[...] += jax.lax.dot_general(
+            a, b, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(ic == nc - 1)
     def _():
@@ -160,19 +230,22 @@ def _gmm_glu_kernel(te_ref, lhs_ref, wg_ref, wu_ref, *refs, nc,
 
 
 def _gmm_glu_call(lhs, wg, wu, tile_expert, *, tm, tc, tj, save_pre,
-                  interpret=False):
+                  interpret=False, out_dtype=None):
     m, _ = lhs.shape
+    out_dtype = out_dtype or lhs.dtype
     f_dim = wg.shape[2]
     nm, nj, nc = m // tm, f_dim // tj, lhs.shape[1] // tc
     row_spec = pl.BlockSpec((tm, tj), lambda i, j, c, te: (i, j))
     out_specs = [row_spec] + ([row_spec, row_spec] if save_pre else [])
-    out_shape = [out_sds((m, f_dim), lhs.dtype, tile_expert, lhs, wg)]
-    if save_pre:
-        out_shape += [out_sds((m, f_dim), lhs.dtype, tile_expert, lhs,
-                              wg)] * 2
+    out_shape = [out_sds((m, f_dim), out_dtype, tile_expert, lhs, wg)] \
+        * (3 if save_pre else 1)
+    need = _vmem_bytes(
+        [(tm * tc, lhs.dtype), (tc * tj, wg.dtype), (tc * tj, wu.dtype)]
+        + [(tm * tj, out_dtype)] * len(out_shape), 2 * tm * tj)
     outs = pl.pallas_call(
         functools.partial(_gmm_glu_kernel, nc=nc, save_pre=save_pre),
         name="gmm_glu",
+        compiler_params=_vmem_params(need),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nm, nj, nc),
@@ -192,14 +265,19 @@ def _gmm_glu_call(lhs, wg, wu, tile_expert, *, tm, tc, tj, save_pre,
     return tuple(outs) if isinstance(outs, (list, tuple)) else (outs,)
 
 
-def _glu_cfg(tm, k, n):
-    """Tile choice for the two-weight kernel, or None when no safe
-    tiling exists: both weight blocks live in VMEM together, so the K
-    block halves vs the single-weight gmm (two [tc, tj] bf16 blocks
-    double-buffered + two f32 accumulators must stay under the ~16M
-    scoped budget).  _pick_tile's full-dim fallback can exceed the cap
-    (e.g. K=1408 has no >=128 divisor <= 512) — those shapes keep the
-    two-gmm path."""
+def _glu_cfg(tm, k, n, row_dt=jnp.bfloat16, w_dt=jnp.bfloat16,
+             out_dt=jnp.float32):
+    """Blocks (tm, tk, tn) for the two-weight kernel, or None when no
+    safe tiling exists.  A weight-bound row tile takes both matrices
+    whole when they fit (``_whole_matrix_fits``).  At training's tiles
+    both weight blocks live in VMEM together under Mosaic's default
+    scope, so the K block halves vs the single-weight gmm (two
+    [tc, tj] bf16 blocks double-buffered + two f32 accumulators must
+    stay under the ~16M scoped budget).  _pick_tile's full-dim
+    fallback can exceed the cap (e.g. K=1408 has no >=128 divisor
+    <= 512) — those shapes keep the two-gmm path."""
+    if _whole_matrix_fits(tm, k, n, 2, row_dt, w_dt, out_dt):
+        return (tm, k, n)
     tk = _pick_tile(k, 512)
     tn = _pick_tile(n, 1024)
     if tk > 512 or tn > 1408:
@@ -210,23 +288,24 @@ def _glu_cfg(tm, k, n):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def glu_grouped(lhs, wg, wu, tile_expert, counts, cfg):
     """Fused silu(lhs @ wg[e]) * (lhs @ wu[e]) over the sorted
-    tile-aligned layout.  ``cfg`` = (tm, tk, tn, interpret)."""
-    tm, tk, tn, interp = cfg
-    (hs,) = _gmm_glu_call(lhs, wg, wu, tile_expert, tm=tm, tc=tk,
-                          tj=tn, save_pre=False, interpret=interp)
+    tile-aligned layout.  ``cfg`` is a :class:`GmmCfg`."""
+    (hs,) = _gmm_glu_call(lhs, wg, wu, tile_expert, tm=cfg.tm, tc=cfg.tk,
+                          tj=cfg.tn, save_pre=False,
+                          interpret=cfg.interpret, out_dtype=cfg.out_dtype)
     return hs
 
 
 def _glu_grouped_fwd(lhs, wg, wu, tile_expert, counts, cfg):
-    tm, tk, tn, interp = cfg
-    hs, hg, hu = _gmm_glu_call(lhs, wg, wu, tile_expert, tm=tm, tc=tk,
-                               tj=tn, save_pre=True, interpret=interp)
+    hs, hg, hu = _gmm_glu_call(lhs, wg, wu, tile_expert, tm=cfg.tm,
+                               tc=cfg.tk, tj=cfg.tn, save_pre=True,
+                               interpret=cfg.interpret,
+                               out_dtype=cfg.out_dtype)
     return hs, (lhs, wg, wu, tile_expert, counts, hg, hu)
 
 
 def _glu_grouped_bwd(cfg, res, dhs):
     lhs, wg, wu, tile_expert, counts, hg, hu = res
-    tm, tk, tn, interp = cfg
+    tm, tk, tn, interp = cfg[:4]
     g = hg.astype(jnp.float32)
     sg = jax.nn.sigmoid(g)
     silu_g = g * sg
@@ -282,9 +361,12 @@ def _gmm_dw_call(lhs, dout, tile_expert, counts, num_experts, *, tm, tk,
     m, k = lhs.shape
     n = dout.shape[1]
     nm, nk, nn = m // tm, k // tk, n // tn
+    need = _vmem_bytes([(tm * tk, lhs.dtype), (tm * tn, dout.dtype),
+                        (tk * tn, lhs.dtype)], tk * tn)
     dw = pl.pallas_call(
         functools.partial(_gmm_dw_kernel, nm=nm),
         name="gmm_dw",
+        compiler_params=_vmem_params(need),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             # m innermost: each (e, kk, j) output block is one contiguous
@@ -315,10 +397,10 @@ def _gmm_dw_call(lhs, dout, tile_expert, counts, num_experts, *, tm, tk,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def grouped_matmul(lhs, w, tile_expert, counts, cfg):
     """lhs [M, K] @ w[tile_expert[i]] -> [M, N], rows pre-grouped so each
-    tm-row tile maps to one expert.  ``cfg`` = (tm, tk, tn, interpret)."""
-    tm, tk, tn, interp = cfg
-    return _gmm_call(lhs, w, tile_expert, transpose_w=False, tm=tm,
-                     tc=tk, tj=tn, interpret=interp)
+    tm-row tile maps to one expert.  ``cfg`` is a :class:`GmmCfg`."""
+    return _gmm_call(lhs, w, tile_expert, transpose_w=False, tm=cfg.tm,
+                     tc=cfg.tk, tj=cfg.tn, interpret=cfg.interpret,
+                     out_dtype=cfg.out_dtype)
 
 
 def _grouped_matmul_fwd(lhs, w, tile_expert, counts, cfg):
@@ -328,7 +410,7 @@ def _grouped_matmul_fwd(lhs, w, tile_expert, counts, cfg):
 
 def _grouped_matmul_bwd(cfg, res, dout):
     lhs, w, tile_expert, counts = res
-    tm, tk, tn, interp = cfg
+    tm, tk, tn, interp = cfg[:4]
     dlhs = _gmm_call(dout, w, tile_expert, transpose_w=True, tm=tm,
                      tc=tn, tj=tk, interpret=interp)
     dw = _gmm_dw_call(lhs, dout, tile_expert, counts, w.shape[0],
@@ -339,8 +421,10 @@ def _grouped_matmul_bwd(cfg, res, dout):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-def gmm(lhs, w, tile_expert, counts, *, tm=512, interpret=False):
-    """Convenience wrapper picking legal tile sizes for [M,K]@[E,K,N].
+def gmm(lhs, w, tile_expert, counts, *, tm=512, interpret=False,
+        out_dtype=None):
+    """Convenience wrapper picking legal tile sizes for [M,K]@[E,K,N];
+    the result is in ``out_dtype`` (default: the rows' dtype).
 
     Measured on v5e (36864×1024 @ 8×1024×704, bf16): tm=512 with the
     full K as one block beats tm=256/tk=512 by ~1.5× and beats XLA's
@@ -350,8 +434,13 @@ def gmm(lhs, w, tile_expert, counts, *, tm=512, interpret=False):
     140 TF/s vs tm=384/tk=1024's 121; tk=2048 at tm>=384 overflows
     VMEM)."""
     k, n = w.shape[1], w.shape[2]
-    kcap = 2048 if tm <= 256 else 1024
-    cfg = (tm, _pick_tile(k, kcap), _pick_tile(n, 1024), interpret)
+    if _whole_matrix_fits(tm, k, n, 1, lhs.dtype, w.dtype,
+                          out_dtype or lhs.dtype):
+        tk, tn = k, n
+    else:
+        tk = _pick_tile(k, 2048 if tm <= 256 else 1024)
+        tn = _pick_tile(n, 1024)
+    cfg = GmmCfg(tm, tk, tn, interpret, out_dtype)
     return grouped_matmul(lhs, w, tile_expert, counts, cfg)
 
 
@@ -383,6 +472,13 @@ def make_dropless_plan(expert_idx, num_experts: int, tm: int):
     return order, dest, tile_expert, counts, m_pad
 
 
+def padded_rows(n_rows: int, num_experts: int, tm: int) -> int:
+    """Rows of the sorted buffer (``m_pad``): a static bound on
+    ``n_rows`` rows laid out with every expert's share padded to a
+    whole ``tm``-row tile."""
+    return -(-n_rows // tm) * tm + num_experts * tm
+
+
 def make_dropless_plan_rows(row_expert, num_experts: int, tm: int):
     """Rows-level variant of :func:`make_dropless_plan` for pre-routed
     buffers (the EP all-to-all receive side): ``row_expert`` [M] holds
@@ -403,7 +499,7 @@ def make_dropless_plan_rows(row_expert, num_experts: int, tm: int):
         [jnp.zeros(1, counts.dtype), jnp.cumsum(counts)[:-1]])
     safe_e = jnp.clip(sorted_e, 0, num_experts - 1)
     rank = jnp.arange(m) - start[safe_e]
-    m_pad = -(-m // tm) * tm + num_experts * tm            # static bound
+    m_pad = padded_rows(m, num_experts, tm)
     dest = jnp.where(valid_sorted, pad_start[safe_e] + rank, m_pad)
     tile_start = jnp.arange(m_pad // tm) * tm
     tile_expert = jnp.searchsorted(pad_start, tile_start,
@@ -424,33 +520,41 @@ def _auto_tm(e: int, n_rows: int) -> int:
     sane.
 
     Every expert's rows are padded to a whole tile, so the tile halves
-    while ``e`` tiles would outnumber the rows; how far depends on how
-    many experts share them.  Up to 64 experts the tile stops at 128
-    (the table above); past that the padding is the buffer — 256 held
-    experts x 11 live rows each at tm=128 made 38.5k rows of 5.8k, every
-    ``gmm`` call reading and writing 13 x the rows it needs — and it
-    stops at 32 (my chip runs, PR 28: a serving step 49.7 -> 42.9 ms;
-    float32 rows tile by 8, and the kernel is bound by the expert
-    weights it streams either way)."""
+    while ``e`` tiles would outnumber the rows, down to 32 (bf16 rows
+    tile by 16, float32 rows by 8).  Where ``e * tm <= n_rows`` — every
+    training shape above — nothing halves.  Where it does the padding
+    is the buffer: 256 held experts x 11 live rows each at tm=128 made
+    38.5k rows of 5.8k (my chip runs, PR 28: a serving step 49.7 ->
+    42.9 ms at 32), and 64 experts serving 160 rows x top-6 made 9,216
+    rows of at most 960 (PR 31: 3,008 at 32).  The floor used to be 128
+    up to 64 experts, from round 4's table — but that table was
+    measured at training's row counts, where the MXU binds; a buffer
+    this empty is bound by the expert matrices it streams whatever the
+    tile, and by the rows it reads and writes."""
     tm = 512 if e <= 16 else 256
-    floor = 128 if e <= 64 else 32
-    while tm > floor and e * tm > n_rows:
+    while tm > 32 and e * tm > n_rows:
         tm //= 2
     return tm
 
 
-def _gate_up(xs, wg, wu, tile_expert, counts, *, tm, interpret, act):
-    """silu-GLU goes through the fused two-dot kernel (one lhs stream,
-    epilogue in VMEM); any other activation keeps the two-gmm path."""
-    cfg = _glu_cfg(tm, wg.shape[1], wg.shape[2]) \
-        if act is jax.nn.silu else None
+def gate_up(xs, wg, wu, tile_expert, counts, *, tm, interpret=False,
+            act=jax.nn.silu, out_dtype=None):
+    """act(xs @ wg[e]) * (xs @ wu[e]) over the sorted layout, in
+    ``out_dtype`` (default: the rows' dtype).  silu-GLU goes through
+    the fused two-dot kernel (one lhs stream, epilogue on the float32
+    accumulators in VMEM); any other activation, or a shape it has no
+    safe blocks for, keeps the two-gmm path."""
+    cfg = _glu_cfg(tm, wg.shape[1], wg.shape[2], xs.dtype, wg.dtype,
+                   out_dtype or xs.dtype) if act is jax.nn.silu else None
     if cfg is not None:
         return glu_grouped(xs, wg, wu, tile_expert, counts,
-                           cfg + (interpret,))
-    hg = gmm(xs, wg, tile_expert, counts, tm=tm, interpret=interpret)
-    hu = gmm(xs, wu, tile_expert, counts, tm=tm, interpret=interpret)
+                           GmmCfg(*cfg, interpret, out_dtype))
+    hg = gmm(xs, wg, tile_expert, counts, tm=tm, interpret=interpret,
+             out_dtype=out_dtype)
+    hu = gmm(xs, wu, tile_expert, counts, tm=tm, interpret=interpret,
+             out_dtype=out_dtype)
     return (act(hg.astype(jnp.float32)) *
-            hu.astype(jnp.float32)).astype(xs.dtype)
+            hu.astype(jnp.float32)).astype(hg.dtype)
 
 
 def dropless_moe_ffn_rows(x_rows, row_expert, wg, wu, wd, *, tm=None,
@@ -470,8 +574,8 @@ def dropless_moe_ffn_rows(x_rows, row_expert, wg, wu, wd, *, tm=None,
     xs = jnp.zeros((m_pad, h), x_rows.dtype).at[dest].set(
         x_rows[order], mode="drop")
 
-    hs = _gate_up(xs, wg, wu, tile_expert, counts, tm=tm,
-                  interpret=interpret, act=act)
+    hs = gate_up(xs, wg, wu, tile_expert, counts, tm=tm,
+                 interpret=interpret, act=act)
     ys = gmm(hs, wd, tile_expert, counts, tm=tm, interpret=interpret)
 
     dest_safe = jnp.minimum(dest, m_pad - 1)
@@ -499,8 +603,8 @@ def dropless_moe_ffn(x, gate_vals, expert_idx, wg, wu, wd, *, tm=None,
     rows = x[order // k]                                   # [T*k, H]
     xs = jnp.zeros((m_pad, h), x.dtype).at[dest].set(rows)
 
-    hs = _gate_up(xs, wg, wu, tile_expert, counts, tm=tm,
-                  interpret=interpret, act=act)
+    hs = gate_up(xs, wg, wu, tile_expert, counts, tm=tm,
+                 interpret=interpret, act=act)
     ys = gmm(hs, wd, tile_expert, counts, tm=tm, interpret=interpret)
 
     y_slots = ys[dest]                                     # [T*k, H] sorted

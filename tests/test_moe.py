@@ -3,6 +3,7 @@ training, Qwen2-MoE e2e (config #5 pattern, SURVEY.md §2.3)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.distributed import fleet
@@ -166,6 +167,92 @@ def test_grouped_matmul_fwd_and_grads_match_reference():
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(g[1]), np.asarray(gr[1]),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("case", ["deepseek_flat_stack", "qwen3_next_share"])
+def test_serving_gate_up_is_one_fused_call_equal_to_the_three_call_route(
+        case):
+    """What ``moe_ffn`` runs on a TPU since PR 31, in interpret mode:
+    bf16 rows, float32 out, a row tile of 32, ONE ``gmm_glu`` call
+    whose tile->expert map is ``tile_expert + base`` — into a
+    flattened ``[L*E, ..]`` stack (DeepSeekMoE: F = 1408 = 11 x 128 is
+    no multiple of 512) and into a held share (Qwen3-Next: F 512, rows
+    routed to experts held elsewhere dropped).  It equals the route it
+    replaced — float32 rows, a ``gmm`` a projection, ``silu(hg) * hu``
+    outside — to float32 rounding: a bf16 row widened is the same
+    value, and the epilogue runs on the float32 accumulators."""
+    from paddle_tpu.ops.pallas.grouped_matmul import (
+        gate_up, gmm, make_dropless_plan_rows, padded_rows)
+    rng = np.random.default_rng(31)
+    k, tm = 256, 32
+    if case == "deepseek_flat_stack":
+        e, f, layers, layer, slots, held = 8, 1408, 2, 1, 120, 1.0
+    else:
+        e, f, layers, layer, slots, held = 16, 512, 1, 0, 360, 0.5
+    base = layer * e
+    row_expert = jnp.asarray(np.where(
+        rng.random(slots) < held, rng.integers(0, e, slots), e), jnp.int32)
+    order, dest, valid, te, counts, m_pad = make_dropless_plan_rows(
+        row_expert, e, tm)
+    assert m_pad == padded_rows(slots, e, tm)
+    x = jnp.asarray(rng.standard_normal((slots, k)), jnp.bfloat16)
+    wg, wu = (jnp.asarray(rng.standard_normal((layers * e, k, f)) * 0.05,
+                          jnp.bfloat16) for _ in range(2))
+    xs = jnp.zeros((m_pad, k), jnp.bfloat16).at[dest].set(
+        x[order], mode="drop")
+
+    def fused(xs, wg, wu):
+        return gate_up(xs, wg, wu, te + base, counts, tm=tm,
+                       interpret=True, out_dtype=jnp.float32)
+    text = str(jax.make_jaxpr(fused)(xs, wg, wu))
+    assert text.count("pallas_call") == 1 and "gmm_glu" in text
+    hs = fused(xs, wg, wu)
+    assert hs.dtype == jnp.float32 and hs.shape == (m_pad, f)
+    xs32 = xs.astype(jnp.float32)
+    hg = gmm(xs32, wg, te + base, counts, tm=tm, interpret=True)
+    hu = gmm(xs32, wu, te + base, counts, tm=tm, interpret=True)
+    want = jax.nn.silu(hg) * hu
+    live = np.asarray(dest)[np.asarray(valid)]
+    assert len(live) == int((np.asarray(row_expert) < e).sum()) > 0
+    np.testing.assert_allclose(np.asarray(hs)[live], np.asarray(want)[live],
+                               rtol=2e-6, atol=2e-6)
+    # and against the expert the rows were routed to, by hand
+    r0 = int(np.asarray(order)[0])
+    ex = int(row_expert[r0]) + base
+    xr = np.asarray(x[r0], np.float32)
+    g = xr @ np.asarray(wg[ex], np.float32)
+    u = xr @ np.asarray(wu[ex], np.float32)
+    np.testing.assert_allclose(np.asarray(hs)[int(dest[0])],
+                               g / (1 + np.exp(-g)) * u, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("e,n_rows,tm", [
+    # serving: the padding is the buffer, so the tile halves to 32
+    (64, 960, 32), (64, 192, 32), (256, 5760, 32), (512, 1600, 32),
+    (64, 4096, 64), (64, 8192, 128), (64, 16383, 128),
+    # training's shapes (e * tm <= n_rows): the measured table
+    (64, 98304, 256), (64, 16384, 256), (60, 32768, 256),
+    (8, 36864, 512), (16, 131072, 512), (8, 4096, 512), (8, 2048, 256),
+])
+def test_auto_tm_table(e, n_rows, tm):
+    from paddle_tpu.ops.pallas.grouped_matmul import _auto_tm
+    assert _auto_tm(e, n_rows) == tm
+
+
+@pytest.mark.parametrize("tm,k,n,blocks", [
+    # serving's row tiles: both matrices whole (each crosses HBM once)
+    (32, 2048, 1408, (32, 2048, 1408)), (32, 2048, 512, (32, 2048, 512)),
+    (64, 2048, 1408, (64, 2048, 1408)),
+    # ... unless they do not fit the budget: training's rule
+    (32, 4096, 14336, (32, 512, 1024)),
+    # training's tiles keep training's blocks
+    (256, 2048, 1408, (256, 512, 1408)), (512, 1024, 704, (512, 512, 704)),
+    (128, 2048, 1408, (128, 512, 1408)),
+    (256, 1408, 2048, None),      # K = 1408 has no block of 512 or less
+])
+def test_glu_blocks_follow_the_row_tile(tm, k, n, blocks):
+    from paddle_tpu.ops.pallas.grouped_matmul import _glu_cfg
+    assert _glu_cfg(tm, k, n) == blocks
 
 
 def test_dropless_ffn_matches_dense_oracle_with_grads():
@@ -419,7 +506,7 @@ def test_deepseek_moe_class_many_experts_grouped_path():
     _, _, _, _, m_pad_512 = make_dropless_plan(eidx, e, 512)
     assert m_pad_128 - slots <= e * 128 + 128
     # tm=512 at this expert count would pad >100x the slot count —
-    # exactly why dropless_moe_ffn's auto tile stays at the 128 floor
+    # exactly why dropless_moe_ffn's auto tile halves (down to 32)
     assert m_pad_512 - slots >= e * 512
 
 

@@ -288,6 +288,31 @@ def test_expert_metrics_surface(model):
     assert f'llm_engine_expert_tokens_total{{engine="{eid}"' in text
     assert 'layer="0"' in text and 'expert="' in text
     assert f'llm_engine_expert_imbalance{{engine="{eid}"}}' in text
+    # how full the grouped dispatch's sorted buffer ran (PR 31), exact
+    # for a known routing: every step a mixed step of ONE forward over
+    # the engine's t_cap rows (live or padding), so the buffer handed
+    # to the kernels is m_pad x layers x steps, and dropless routing
+    # keeps top-k slots a layer for every prompt token and for every
+    # generated token but a request's last
+    from paddle_tpu.inference.moe_dispatch import expert_buffer_rows
+    from paddle_tpu.ops.pallas.grouped_matmul import padded_rows
+    _, one = _serve(model, PROMPTS[:2], max_new=4, admit="begin",
+                    steps_per_sync=1)
+    snap = one.metrics_snapshot()
+    m1, arch = snap["moe"], one._arch
+    layers, t_cap = one._moe_counts.shape[0], one._step_geom[0]
+    steps = snap["host_transfers"]["in"]
+    m_pad = padded_rows(t_cap * arch.top_k, arch.num_experts, 8)
+    assert expert_buffer_rows(arch, t_cap) == m_pad
+    assert expert_buffer_rows(arch._replace(dispatch="dense"), t_cap) == 0
+    kept = layers * arch.top_k * (len(PROMPTS[0]) + len(PROMPTS[1]) + 2 * 3)
+    assert sum(m1["expert_tokens"]) == kept and m1["absent_slots"] == 0
+    assert m1["buffer_rows"] == layers * steps * m_pad > kept
+    assert m1["row_fill"] == kept / (layers * steps * m_pad)
+    assert (f'llm_engine_expert_buffer_rows_total{{engine="'
+            f'{one.engine_id}"}} {layers * steps * m_pad}') \
+        in get_registry().expose_text()
+    assert 0.0 < moe["row_fill"] < 1.0 and moe["buffer_rows"] > 0
     sched = Scheduler(_mk(model), max_queue=8)
     sched.submit("s", PROMPTS[0], max_new_tokens=3)
     sched.run_until_idle(max_steps=100)

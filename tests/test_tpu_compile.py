@@ -157,7 +157,21 @@ def _moe_rows(sds):
     e, h, f, rows = 64, 2048, 1408, 64
     return (dropless_moe_ffn_rows,
             (sds((rows, h), BF16), sds((rows,), I32), sds((e, h, f), BF16),
-             sds((e, h, f), BF16), sds((e, f, h), BF16)), ("gmm",))
+             sds((e, h, f), BF16), sds((e, f, h), BF16)),
+            ("gmm_glu", "gmm"))
+
+
+def _moe_rows_grad(sds):
+    """The same tiny buffer differentiated: at a row tile of 32 every
+    kernel of the backward takes whole-matrix blocks too (``gmm`` on
+    the transposed weights, ``gmm_dw``), past Mosaic's default scope —
+    each call has to ask for the VMEM it needs."""
+    fn, args, _ = _moe_rows(sds)
+
+    def loss(x, row_expert, wg, wu, wd):
+        return fn(x, row_expert, wg, wu, wd).astype(F32).sum()
+    return (jax.grad(loss, argnums=(0, 2, 3, 4)), args,
+            ("gmm_glu", "gmm", "gmm_dw"))
 
 
 def _fused_update(kind):
@@ -208,6 +222,7 @@ CASES = {
     "decode_append_12_4": _decode(True),
     "decode_12_4": _decode(False),
     "moe_ffn_rows_64e_64rows": _moe_rows,
+    "moe_ffn_rows_64e_64rows_grad": _moe_rows_grad,
     **{f"fused_update_{k}": _fused_update(k)
        for k in ("sgd", "momentum", "adam")},
     "add_rms_norm": _add_norm,
@@ -222,6 +237,13 @@ def _kernels_in(text):
             and 'op_name="' in line]
 
 
+def _has_kernel(ops, name):
+    """``name`` as a whole word of some kernel's op name (``gmm`` is
+    not ``gmm_glu``; under ``jax.grad`` the name sits in ``jvp(..)``)."""
+    import re
+    return any(re.search(rf"(?<!\w){name}(?!\w)", op) for op in ops)
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache, on_tpu):
     def sds(shape, dtype):
@@ -230,7 +252,7 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache, on_tpu):
     compiled = jax.jit(fn).lower(*args).compile()
     ops = _kernels_in(compiled.as_text())
     for name in expect:
-        assert any(name in op for op in ops), (name, ops)
+        assert _has_kernel(ops, name), (name, ops)
 
 
 def test_expert_parallel_dispatch_compiles_for_four_chips(
@@ -302,6 +324,37 @@ def test_ragged_kernel_runs_per_shard_under_a_tp_mesh(
         jax.jit(kernel).lower(*args)
 
 
+def test_expert_layer_runs_per_shard_under_a_tp_mesh(
+        topo, no_compile_cache, on_tpu):
+    """``moe_ffn`` under the serving mesh (tp=4 on the four described
+    devices, expert stacks replicated): both of its Pallas calls —
+    ``gmm_glu`` and ``gmm`` — go through ``TPShardings.per_shard``, at
+    DeepSeekMoE's widths, reaching into a flattened two-layer stack."""
+    from paddle_tpu.distributed.sharding import TPShardings
+    from paddle_tpu.inference.moe_dispatch import MoEArch, moe_ffn
+    sh = TPShardings(Mesh(np.array(topo.devices).reshape(4), ("tp",)))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=sh._sharding(len(shape), None))
+    t, h, e, f, layers = 160, 2048, 64, 1408, 2
+    arch = MoEArch(num_experts=e, top_k=6, norm_topk=False, capacity=0,
+                   shared=False, shared_gate=False, attn_bias=False,
+                   dispatch="grouped")
+    zed = sds((1, 1), F32)
+    mw = (sds((h, e), BF16), sds((layers * e, h, f), BF16),
+          sds((layers * e, h, f), BF16), sds((layers * e, f, h), BF16),
+          zed, zed, zed, zed)
+
+    def fn(hn, mw, live, layer):
+        return moe_ffn(hn, mw, arch, live, shardings=sh,
+                       expert_base=layer * e)
+    compiled = jax.jit(fn).lower(
+        sds((t, h), BF16), mw, sds((t,), jnp.bool_), sds((), I32)).compile()
+    ops = _kernels_in(compiled.as_text())
+    assert _has_kernel(ops, "gmm_glu") and _has_kernel(ops, "gmm"), ops
+
+
 # -- whole step programs: the layer loop uses its operands where they lie -----
 
 _MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
@@ -334,6 +387,29 @@ def _bytes_moved(text):
             size += n
         rows.append((size, m.group(1)))
     return rows
+
+
+def _expert_rows_guard(text, arch, t, hidden):
+    """The guard on PR 31's gain.  The routed rows reach the expert
+    kernels ONCE, in the activations' own dtype: the program holds
+    ``gmm_glu`` (gate and up in one pass, ``silu x up`` in VMEM) and
+    ``gmm`` (down), and NO operation of it — fused or not — writes a
+    float32 ``[m_pad, hidden]`` array except the down kernel itself.
+    Before, the sorted buffer was float32 (``xs``: written once, read
+    by two calls) and ``hg`` / ``hu`` / ``hs`` went through HBM."""
+    import re
+    from paddle_tpu.inference.moe_dispatch import expert_buffer_rows
+    ops = _kernels_in(text)
+    for name in ("gmm_glu", "gmm"):
+        assert _has_kernel(ops, name), (name, ops)
+    m_pad = expert_buffer_rows(arch, t)
+    assert m_pad % 32 == 0 and m_pad < 3 * t * arch.top_k + 32 * arch.n_held
+    assert f"bf16[{m_pad},{hidden}]" in text        # the buffer itself
+    writers = [ln.strip()[:160] for ln in text.splitlines()
+               if re.search(rf"= f32\[{m_pad},{hidden}\]", ln)
+               and " parameter(" not in ln
+               and "/gmm/pallas_call" not in ln]
+    assert not writers, writers
 
 
 def _step_program(sds, moe, window, packed, n_layers=2):
@@ -390,8 +466,8 @@ def _step_program(sds, moe, window, packed, n_layers=2):
         lowered = E._paged_mixed_window.lower(*args, **kw)
     else:
         lowered = E._paged_mixed_step.lower(*args, **kw)
-    return lowered, 2 * 2 * pool.size, min(filter(None,
-                                                  (smallest, one_pool)))
+    return lowered, 2 * 2 * pool.size, min(filter(
+        None, (smallest, one_pool))), (arch, t, h)
 
 
 @pytest.mark.parametrize("packed", [False, True], ids=["inner", "packed"])
@@ -413,12 +489,14 @@ def test_step_program_moves_no_layer_of_weights_or_pool(
     launches, and are held to the same."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    lowered, pool_bytes, limit = _step_program(sds, moe, window, packed)
+    lowered, pool_bytes, limit, rows = _step_program(sds, moe, window,
+                                                     packed)
     compiled = lowered.compile()
     text = compiled.as_text()
     ops = _kernels_in(text)
-    for name in ("ragged_paged_append_attend",) + (("gmm",) if moe else ()):
-        assert any(name in op for op in ops), (name, ops)
+    assert any("ragged_paged_append_attend" in op for op in ops), ops
+    if moe:
+        _expert_rows_guard(text, *rows)
     moved = sorted(_bytes_moved(text), reverse=True)
     assert moved, "the parser found no copy or slice at all"
     assert moved[0][0] < limit, (limit, moved[:6])
@@ -498,11 +576,13 @@ def _hybrid_step_program(sds, window, packed):
                     sds((2,), jnp.uint32), rec, conv]
         return (E._packed_mixed_window if window
                 else E._packed_mixed_step).lower(
-            *args, geom=geom, **kw), state
+            *args, geom=geom, **kw), state, (arch, t, h)
     if window:
         return E._paged_mixed_window.lower(
-            *args, rows, rows, sds((), I32), rec, conv, desc, **kw), state
-    return E._paged_mixed_step.lower(*args, rec, conv, desc, **kw), state
+            *args, rows, rows, sds((), I32), rec, conv, desc,
+            **kw), state, (arch, t, h)
+    return E._paged_mixed_step.lower(*args, rec, conv, desc,
+                                     **kw), state, (arch, t, h)
 
 
 @pytest.mark.parametrize("packed", [False, True], ids=["inner", "packed"])
@@ -515,14 +595,16 @@ def test_hybrid_step_program_fits_and_updates_its_state_in_place(
     ``gmm`` over the held share inside), weights held once plus pools,
     state and temporaries fit the chip, and BOTH kinds of per-request
     state — KV pools and the recurrent state / conv windows — are
-    donated in and aliased out."""
+    donated in and aliased out; the routed rows travel as PR 31 left
+    them (``_expert_rows_guard``)."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    lowered, state_bytes = _hybrid_step_program(sds, window, packed)
+    lowered, state_bytes, rows = _hybrid_step_program(sds, window, packed)
     compiled = lowered.compile()
-    ops = _kernels_in(compiled.as_text())
-    for name in ("ragged_paged_append_attend", "gmm"):
-        assert any(name in op for op in ops), (name, ops)
+    text = compiled.as_text()
+    assert any("ragged_paged_append_attend" in op
+               for op in _kernels_in(text))
+    _expert_rows_guard(text, *rows)
     mem = compiled.memory_analysis()
     print("hybrid step program:", mem.argument_size_in_bytes,
           "B arguments,", mem.temp_size_in_bytes, "B temporaries")
