@@ -11,7 +11,7 @@ predicate registry resolves a model instance to its spec by DUCK
 TYPING, never by class identity, so converted/quantized wrappers keep
 working as long as the attribute shape survives.
 
-Three backbones register here:
+Four backbones register here:
 
 - ``llama`` — ``LlamaForCausalLM``-shaped models (``model.llama.*``),
   the original engine contract, byte-identical programs.
@@ -31,6 +31,14 @@ Three backbones register here:
   ``mesh=``, a ``draft_model=``, int8 KV or weights, capacity-factor
   dispatch.
 
+- ``nemotron_h`` — ``NemotronHForCausalLM``-shaped hybrids: blocks
+  that are a mixer ALONE (``ssm``: a Mamba-2 mixer with a per-slot
+  recurrent state; ``full``: softmax attention over KV pages with no
+  position signal) or a feed-forward part alone (``ffn``: a latent
+  expert layer with a sigmoid router that may hold a share of the
+  published experts), one norm a block.  Admitted and refused as the
+  other hybrid is.
+
 Unsupported models get ONE clear error listing what would make them
 servable, instead of the old attribute crash.
 """
@@ -47,11 +55,18 @@ __all__ = ["BackboneSpec", "HybridArch", "register_backbone",
 
 class HybridArch(NamedTuple):
     """Hashable static-jit description of a backbone whose layers are
-    of several kinds: the per-layer kind (``"linear"`` / ``"full"``),
-    the linear mixer's geometry under the config's own names
-    (``ops/pallas/gated_delta.py``'s helpers read either), and whether
-    the norms' stored weights are zero-centred (scale ``1 + w``, the
-    product taken in float32)."""
+    of several kinds.  ``kinds`` names each layer's MIXER: ``"full"``
+    (softmax attention over the KV pages), one of the two recurrent
+    mixers with a per-slot state — ``"linear"`` (Gated DeltaNet) or
+    ``"ssm"`` (Mamba-2 / SSD) — or ``"ffn"``: no mixer, the layer is
+    its feed-forward part alone.  (Whether a layer HAS a feed-forward
+    part, and of which form, is read from its weights.)  The recurrent
+    mixer's geometry is kept under its config's own names
+    (``ops/pallas/gated_delta.py``'s and ``mamba2_ssd.py``'s helpers
+    read either a config or this); the fields of the mixer a backbone
+    has not are 0.  ``rotary_dim`` 0 means no rotary at all;
+    ``zero_centred_norm``: the norms' stored weights are zero-centred
+    (scale ``1 + w``, the product taken in float32)."""
     kinds: tuple
     rotary_dim: int
     linear_num_key_heads: int
@@ -61,14 +76,28 @@ class HybridArch(NamedTuple):
     linear_conv_kernel_dim: int
     conv_channels: int
     zero_centred_norm: bool
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    n_groups: int = 0
+    ssm_state_size: int = 0
 
     @property
     def n_linear(self) -> int:
-        return sum(k == "linear" for k in self.kinds)
+        """Layers with a recurrent mixer, of either recurrence."""
+        return sum(k in ("linear", "ssm") for k in self.kinds)
 
     @property
     def n_full(self) -> int:
         return sum(k == "full" for k in self.kinds)
+
+    @property
+    def state_shape(self) -> tuple:
+        """One slot's float32 recurrent state in one layer."""
+        if self.mamba_num_heads:
+            return (self.mamba_num_heads, self.mamba_head_dim,
+                    self.ssm_state_size)
+        return (self.linear_num_value_heads, self.linear_key_head_dim,
+                self.linear_value_head_dim)
 
 
 @dataclass
@@ -135,7 +164,9 @@ def resolve_backbone(model) -> BackboneSpec:
         f"``linear_attn``/``self_attn`` mixers (Qwen3-Next hybrid "
         f"family: unified step only — no prefix caching, split "
         f"programs, tp mesh, draft model, int8 or capacity-factor "
-        f"dispatch); register new families with "
+        f"dispatch), or per-block ``kind`` (``ssm`` / ``full`` / "
+        f"``ffn``) with ONE part a block under ``mixer`` (Nemotron-H "
+        f"hybrid family: the same refusals); register new families with "
         f"inference.backbone.register_backbone().")
 
 
@@ -248,7 +279,50 @@ def _build_qwen3_next(model) -> BackboneSpec:
         layer_weights=model.serving_layer_weights())
 
 
+# -- nemotron-h hybrid (a mixer or an expert layer a block) ---------------------
+
+def _is_nemotron_h(model) -> bool:
+    if hasattr(model, "llama") or not hasattr(model, "layers"):
+        return False
+    layers = list(model.layers)
+    return bool(layers) and all(
+        getattr(l, "kind", None) in ("ssm", "full", "ffn")
+        and hasattr(l, "mixer") and hasattr(l, "norm")
+        for l in layers) and hasattr(model, "serving_layer_weights")
+
+
+def _build_nemotron_h(model) -> BackboneSpec:
+    c = model.config
+    layers = list(model.layers)
+    arch = model.moe_arch()
+    moe = {"num_experts": arch.num_experts, "top_k": arch.top_k,
+           "norm_topk": arch.norm_topk, "capacity_factor": 1.0,
+           "shared": arch.shared, "shared_gate": arch.shared_gate,
+           "expert_lo": arch.expert_lo, "experts_held": arch.n_held,
+           "scoring": arch.scoring, "route_scale": arch.route_scale,
+           "expert_act": arch.expert_act}
+    return BackboneSpec(
+        arch="nemotron_h", config=c, layers=layers, norm=model.norm,
+        embed_tokens=model.embed_tokens, lm_head=model.lm_head,
+        rope_cos=model.rope_cos, rope_sin=model.rope_sin,
+        attn_bias=False,
+        moe=moe if any(l.kind == "ffn" for l in layers) else None,
+        hybrid=HybridArch(
+            kinds=tuple(l.kind for l in layers), rotary_dim=0,
+            linear_num_key_heads=0, linear_num_value_heads=0,
+            linear_key_head_dim=0, linear_value_head_dim=0,
+            linear_conv_kernel_dim=int(c.conv_kernel),
+            conv_channels=int(c.conv_channels),
+            zero_centred_norm=False,
+            mamba_num_heads=int(c.mamba_num_heads),
+            mamba_head_dim=int(c.mamba_head_dim),
+            n_groups=int(c.n_groups),
+            ssm_state_size=int(c.ssm_state_size)),
+        layer_weights=model.serving_layer_weights())
+
+
 register_backbone("llama", _is_llama, _build_llama)
-# the more specific shape first: a hybrid also has ``layers`` + ``mlp``
+# the more specific shapes first: a hybrid also has ``layers``
+register_backbone("nemotron_h", _is_nemotron_h, _build_nemotron_h)
 register_backbone("qwen3_next", _is_qwen3_next, _build_qwen3_next)
 register_backbone("qwen2_moe", _is_qwen2_moe, _build_qwen2_moe)

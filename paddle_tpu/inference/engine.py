@@ -678,21 +678,26 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
     identically.
 
     ONE layer function, ``layer(kind, ...)``: norm -> mixer(kind) ->
-    residual -> norm -> FFN/MoE, the kind STATIC.  ``"full"`` is
+    residual -> norm -> FFN/MoE, the kind STATIC — and each HALF there
+    only if the layer has it: kind ``"ffn"`` is a layer with no mixer
+    (its feed-forward part alone), and a layer whose weights bring
+    neither a router nor a dense ``gate`` / ``up`` / ``down`` is its
+    mixer alone (one norm and one residual add, then).  ``"full"`` is
     softmax attention over the KV pages — every layer of the Llama and
     Qwen2-MoE backbones, scanned over their stacked weights — with
     what a layer's weights bring along: projection biases, a q/k norm
     over the head (``q_norm`` / ``k_norm``), rotary on the first
     ``rope`` -width dims only, an output gate packed beside q (a ``q``
     projection twice as wide as ``o``'s input).  ``"linear"`` is the
-    Gated-DeltaNet mixer, whose per-slot recurrent state and conv
-    window ride the carry beside the pools.  A ``hybrid`` backbone
+    Gated-DeltaNet mixer and ``"ssm"`` the Mamba-2 (SSD) mixer, whose
+    per-slot recurrent state and conv window ride the carry beside the
+    pools.  A ``hybrid`` backbone
     (``backbone.HybridArch``) gives the kinds; its ``stack`` is one
     weight dict a layer (``BackboneSpec.layer_weights``: the model's
     own arrays, so the expert matrices exist once on the device)
     walked by a Python loop over the period, its KV pools hold the full
-    layers only, and ``rec_state`` / ``conv_state`` (one array a linear
-    layer) plus ``desc_slot`` [S] (each descriptor's sequence slot)
+    layers only, and ``rec_state`` / ``conv_state`` (one array a
+    recurrent layer) plus ``desc_slot`` [S] (each descriptor's sequence slot)
     come in and go out after the counts."""
     import jax
     import jax.numpy as jnp
@@ -745,6 +750,8 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
     else:
         from ..ops.pallas.gated_delta import (
             causal_conv_step, gdn_inputs, gdn_output, ragged_gated_delta)
+        from ..ops.pallas.mamba2_ssd import (
+            ragged_ssd, ssd_inputs, ssd_output)
         # the rows' view of the descriptors, for the conv window: each
         # row's slot, how many of this step's rows of its sequence
         # precede it (they are contiguous), live rows per slot, and the
@@ -837,6 +844,28 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
         y = gdn_output(o, qkvz[:, cc:], lp["norm"], eps).astype(hn.dtype)
         return _mm(y, lp["o"]), rec, conv
 
+    def ssm_mixer(hn, lp, rec, conv):
+        """Mamba-2 over the flat rows: one projection, the causal conv
+        (with its bias) over each sequence's window, the ragged SSD
+        recurrence over the descriptors (state read and written where
+        it lies), the gated group norm, the output projection."""
+        f32 = jnp.float32
+        d_in = hybrid.mamba_num_heads * hybrid.mamba_head_dim
+        cc = hybrid.conv_channels
+        zxd = _mm(hn, lp["in_proj"])
+        xbc, conv = causal_conv_step(
+            zxd[:, d_in:d_in + cc].astype(f32), lp["conv"].astype(f32),
+            conv, row_slot, row_hist, slot_rows, slot_fresh)
+        xs, delta, a, b, c = ssd_inputs(
+            xbc + lp["conv_bias"].astype(f32)[None, :],
+            zxd[:, d_in + cc:], lp["A_log"], lp["dt_bias"], hybrid)
+        y, rec = ragged_ssd(
+            xs, delta, a, b, c, lp["D"].astype(f32), rec, q_start, q_len,
+            kv_len, desc_slot, page_size=k_pages.shape[3])
+        y = ssd_output(y, zxd[:, :d_in], lp["norm"], eps,
+                       hybrid.n_groups).astype(hn.dtype)
+        return _mm(y, lp["o"]), rec, conv
+
     def norm(x, w):
         if hybrid is not None and hybrid.zero_centred_norm:
             return _nn.rms_norm_zero_centred(x, w, epsilon=eps)
@@ -846,6 +875,8 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
         """Half rotation over the first ``rot`` dims of the head (all
         of them for the homogeneous backbones); float32 in and out."""
         rot = cos.shape[-1]
+        if rot == 0:                # tables of width 0: no rotary
+            return xf
         if rot == head_dim:
             return xf * cos + rotate_half(xf) * sin
         xr = xf[..., :rot]
@@ -858,7 +889,7 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
         nh = _win(lp["o"]) // head_dim
         gated = _wout(lp["q"]) == 2 * nh * head_dim
         qx, kx, vx = _mm(hn, lp["q"]), _mm(hn, lp["k"]), _mm(hn, lp["v"])
-        if "q_bias" in lp and arch.attn_bias:
+        if "q_bias" in lp and arch is not None and arch.attn_bias:
             qx, kx, vx = (qx + lp["q_bias"], kx + lp["k_bias"],
                           vx + lp["v_bias"])
         if gated:
@@ -882,39 +913,43 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
                    lp["o"]), pools
 
     def layer(kind, hcur, pools, li, lp, lin=None):
-        """norm -> mixer(kind) -> residual -> norm -> FFN/MoE.  ``li``
-        indexes the KV pools (and, scanned, the expert stacks); ``lp``
-        is the layer's weights by name (a scanned layer's tuple gets
-        its names here); ``lin`` is a linear layer's (state, conv
-        window)."""
+        """norm -> mixer(kind) -> residual, then norm -> FFN/MoE ->
+        residual, each half if the layer has it.  ``li`` indexes the
+        KV pools (and, scanned, the expert stacks); ``lp`` is the
+        layer's weights by name (a scanned layer's tuple gets its names
+        here); ``lin`` is a recurrent layer's (state, conv window)."""
         if not isinstance(lp, dict):
             lp = dict(zip(_DENSE_LAYER if arch is None else _MOE_LAYER,
                           lp))
-        hn = norm(hcur, lp["in_norm"])
-        if kind == "linear":
-            mixed, *lin = linear_mixer(hn, lp, *lin)
-        else:
-            mixed, pools = attention_mixer(hn, lp, pools, li)
-        hcur = _tpc(hcur + mixed, shardings)
-        hn = norm(hcur, lp["post_norm"])
-        if arch is None:
+        if kind != "ffn":
+            hn = norm(hcur, lp["in_norm"])
+            if kind == "linear":
+                mixed, *lin = linear_mixer(hn, lp, *lin)
+            elif kind == "ssm":
+                mixed, *lin = ssm_mixer(hn, lp, *lin)
+            else:
+                mixed, pools = attention_mixer(hn, lp, pools, li)
+            hcur = _tpc(hcur + mixed, shardings)
+        lin = tuple(lin) if lin else None
+        if "router" in lp:
+            hn = norm(hcur, lp["post_norm"])
+            if "experts_up" in lp:      # the layer's own [E_held, ..]
+                mw, base = lp, 0
+            else:                       # every layer's, flattened
+                mw = (lp["router"], egw, euw, edw, lp["shared_gate"],
+                      lp["shared_up"], lp["shared_down"],
+                      lp["shared_expert_gate"])
+                base = li * arch.num_experts
+            ff, cnt = moe_ffn(hn, mw, arch, moe_live, moe_group,
+                              shardings, expert_base=base)
+            return (_tpc(hcur + ff, shardings), pools, lin), cnt
+        if "gate" in lp:
+            hn = norm(hcur, lp["post_norm"])
             ff = _tpc(_nn.silu(_mm(hn, lp["gate"])) * _mm(hn, lp["up"]),
                       shardings, 1)
-            return (_tpc(hcur + _mm(_tpc(ff, shardings), lp["down"]),
-                         shardings), pools, lin), None
-        if "experts_gate" in lp:        # the layer's own [E_held, ..]
-            experts = (lp["experts_gate"], lp["experts_up"],
-                       lp["experts_down"])
-            base = 0
-        else:                           # every layer's, flattened
-            experts, base = (egw, euw, edw), li * arch.num_experts
-        ff, cnt = moe_ffn(
-            hn, (lp["router"],) + experts + (
-                lp["shared_gate"], lp["shared_up"], lp["shared_down"],
-                lp["shared_expert_gate"]),
-            arch, moe_live, moe_group, shardings, expert_base=base)
-        return (_tpc(hcur + ff, shardings), pools,
-                tuple(lin) if lin else None), cnt
+            hcur = _tpc(hcur + _mm(_tpc(ff, shardings), lp["down"]),
+                        shardings)
+        return (hcur, pools, lin), None
 
     pools = (k_pages, v_pages, k_scales, v_scales)
     if hybrid is None:
@@ -932,7 +967,7 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
         rec_state, conv_state = list(rec_state), list(conv_state)
         cnts, fi, ji = [], 0, 0
         for kind, lp in zip(hybrid.kinds, stack):
-            if kind == "linear":
+            if kind in ("linear", "ssm"):
                 (x, pools, lin), cnt = layer(
                     kind, x, pools, None, lp,
                     (rec_state[ji], conv_state[ji]))
@@ -941,9 +976,10 @@ def _mixed_forward(stack, norm_w, head_w, embed_w, rope,
             else:
                 (x, pools, _), cnt = layer(kind, x, pools,
                                            jnp.int32(fi), lp)
-                fi += 1
-            cnts.append(cnt)
-        cnts = jnp.stack(cnts)
+                fi += kind == "full"
+            if cnt is not None:         # one row an expert layer
+                cnts.append(cnt)
+        cnts = jnp.stack(cnts) if cnts else None
         x = norm(x, norm_w)
     k_pages, v_pages, k_scales, v_scales = pools
     logits = _tpc(jnp.matmul(x, head_w.T) if transpose_head
@@ -1099,8 +1135,8 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
               v_scales, key)
     if arch is not None:
         state0 = state0 + (jnp.zeros(
-            (len(stack) if hybrid is not None else stack[0].shape[0],
-             arch.num_experts), jnp.int32),)
+            (sum("router" in lp for lp in stack) if hybrid is not None
+             else stack[0].shape[0], arch.num_experts), jnp.int32),)
     if hybrid is not None:
         state0 = state0 + (rec_state, conv_state)
     carry0 = (jnp.zeros((), jnp.int32), state0, toks0,
@@ -1405,20 +1441,21 @@ class LLMEngine:
                 if bad:
                     raise ValueError(
                         f"LLMEngine({spec.arch}): {what} is not "
-                        f"supported for a backbone with linear-attention "
-                        f"layers — {why}")
+                        f"supported for a backbone with recurrent "
+                        f"(linear-attention or state-space) layers — "
+                        f"{why}")
             refuse(enable_prefix_caching, "enable_prefix_caching=True",
                    "a prefix hit would need the recurrent state at the "
                    "prefix boundary, which the cache does not snapshot "
                    "(it builds with prefix caching off)")
             refuse(mesh is not None, "mesh=",
-                   "the recurrent state pools and the DeltaNet mixer "
-                   "have no tensor-parallel plan")
+                   "the recurrent state pools and their mixers have no "
+                   "tensor-parallel plan")
             refuse(draft_model is not None, "draft_model=",
                    "speculative verify cannot roll a recurrent state "
                    "back")
             refuse(kv_dtype == "int8", "kv_dtype='int8'",
-                   "the gated-attention layers' int8 pools are not "
+                   "the full-attention layers' int8 pools are not "
                    "carried through")
             refuse(weight_dtype == "int8", "weight_dtype='int8'",
                    "the per-layer weight dicts carry no int8 scales")
@@ -1464,7 +1501,10 @@ class LLMEngine:
                 attn_bias=bool(spec.attn_bias),
                 dispatch=moe_dispatch,
                 expert_lo=int(m.get("expert_lo", 0)),
-                experts_held=int(m.get("experts_held", 0)))
+                experts_held=int(m.get("experts_held", 0)),
+                scoring=m.get("scoring", "softmax"),
+                route_scale=float(m.get("route_scale", 1.0)),
+                expert_act=m.get("expert_act", "silu_glu"))
             if cap:
                 # capacity ranks are defined per page-group, so the
                 # step's planner packs WHOLE page chunks in this
@@ -1696,8 +1736,12 @@ class LLMEngine:
         # routed-slot counts per (layer, expert) plus the running
         # capacity-drop total (always 0 dropless)
         if self._arch is not None:
+            # one row an expert layer (every layer of the scanned
+            # backbones; those whose weights bring a router otherwise)
+            n_moe = len(layers) if hy is None else sum(
+                "router" in lp for lp in spec.layer_weights)
             self._moe_counts = np.zeros(
-                (len(layers), self._arch.num_experts), np.int64)
+                (n_moe, self._arch.num_experts), np.int64)
             self._moe_dropped = 0
             self._expert_label_values = None     # set by _init_metrics
             # slots routed to experts this engine does not hold (an
@@ -1745,7 +1789,13 @@ class LLMEngine:
         # by kind and live descriptors (host counters, like the prefix
         # stats — the registry series mirror them)
         self.linear_stats = {"prefill_rows": 0, "decode_rows": 0,
-                             "descriptors": 0, "state_snapshots": 0}
+                             "descriptors": 0, "state_snapshots": 0,
+                             "state_bytes_moved": 0}
+        # each live descriptor reads and writes one slot's state and
+        # conv window in one recurrent layer
+        self._state_bytes_a_descriptor = 0 if not (hy and hy.n_linear) \
+            else 2 * (self.cache.state_bytes() // (max_seqs + 1)
+                      // hy.n_linear)
         self._init_metrics(enable_metrics)
         # compile-watch registration: this engine's three jit entry
         # points and their warmup allowances (the decode program of
@@ -1813,10 +1863,12 @@ class LLMEngine:
         }
         if hy is not None:
             # TOKEN-AFFECTING: the layer pattern and the held share
-            self._capsule_fp["hybrid"] = {
-                "kinds": list(hy.kinds),
-                "expert_lo": self._arch.expert_lo,
-                "experts_held": self._arch.n_held}
+            self._capsule_fp["hybrid"] = {"kinds": list(hy.kinds)}
+            if self._arch is not None:
+                ar = self._arch
+                self._capsule_fp["hybrid"].update(
+                    expert_lo=ar.expert_lo, experts_held=ar.n_held,
+                    router=[ar.scoring, ar.route_scale, ar.expert_act])
         self._spec = None
         if draft_model is not None:
             self._init_spec(draft_model, spec_k, dtype, page_size,
@@ -2155,6 +2207,12 @@ class LLMEngine:
                 "Device bytes of the per-slot recurrent state and "
                 "conv-window pools.", lbl).labels(eid)
             self._metrics["state_bytes"].set(self.cache.state_bytes())
+            self._metrics["state_bytes_moved"] = reg.counter(
+                "llm_engine_state_bytes_moved_total",
+                "Recurrent-state bytes the recurrent layers' live "
+                "descriptors read plus wrote (each reads and writes one "
+                "slot's state and conv window in one layer; from "
+                "shapes, nothing transferred).", lbl).labels(eid)
             self._metrics["state_snapshots"] = reg.counter(
                 "llm_engine_state_snapshots_total",
                 "Per-slot recurrent-state snapshots moved between the "
@@ -3266,7 +3324,7 @@ class LLMEngine:
                 m["mixed_decode_slots"].set(n)
                 m["mixed_prefill_tokens"].set(used)
                 self._record_compiles()
-            if hy is not None:
+            if hy is not None and hy.n_linear:
                 # what the recurrence was given: the decode rows of
                 # every step of the window, the packed prefill rows,
                 # one live descriptor each decode row and chunk
@@ -3274,12 +3332,15 @@ class LLMEngine:
                 dec, pre = nl * n * steps_done, nl * used
                 st["decode_rows"] += dec
                 st["prefill_rows"] += pre
-                st["descriptors"] += nl * (n * steps_done + len(plan))
+                desc = nl * (n * steps_done + len(plan))
+                st["descriptors"] += desc
+                moved = desc * self._state_bytes_a_descriptor
+                st["state_bytes_moved"] += moved
                 if self._metrics is not None:
                     m["linear_rows_decode"].inc(dec)
                     m["linear_rows_prefill"].inc(pre)
-                    m["linear_descriptors"].inc(
-                        nl * (n * steps_done + len(plan)))
+                    m["linear_descriptors"].inc(desc)
+                    m["state_bytes_moved"].inc(moved)
         return out
 
     def has_work(self) -> bool:

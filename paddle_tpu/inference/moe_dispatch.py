@@ -4,7 +4,11 @@ One traced function, :func:`moe_ffn`, replaces the dense SwiGLU FFN
 inside every serving program's decoder-layer body when the engine's
 backbone is an MoE family (Qwen2-MoE/DeepSeekMoE geometry): top-k
 router → token→expert dispatch → per-expert SwiGLU → top-k combine,
-plus the always-on shared expert.  All routing tensors are TRACED data
+plus the always-on shared expert.  The router's scoring (softmax, or
+sigmoid with a correction bias and a route scale), the experts' form
+(gated SiLU, or ``relu^2`` with no gate matrix) and a narrow latent the
+routed experts live in are static fields of the arch and what the
+layer's weights bring (:class:`MoEArch`, :func:`moe_ffn`).  All routing tensors are TRACED data
 — descriptors never surface to the host — so the engine's one-compile
 invariants (``mixed_compiles() == 1`` per geometry) survive untouched.
 
@@ -75,10 +79,22 @@ class MoEArch(NamedTuple):
     dispatch: str
     expert_lo: int = 0
     experts_held: int = 0
+    scoring: str = "softmax"
+    route_scale: float = 1.0
+    expert_act: str = "silu_glu"
 
     @property
     def n_held(self) -> int:
         return self.experts_held or self.num_experts
+
+
+# ``moe_ffn``'s weights by name: the scanned backbones hand them over as
+# a tuple in this order, a layer-dict backbone as its dict (which may
+# leave out what its layer has not — a gate matrix, a token gate on the
+# shared expert — and bring ``router_bias``, ``latent_in``,
+# ``latent_out``)
+_MW = ("router", "experts_gate", "experts_up", "experts_down",
+       "shared_gate", "shared_up", "shared_down", "shared_expert_gate")
 
 
 def _mm(x, w):
@@ -110,10 +126,13 @@ def _expert_rows_mm(x, w, row_expert):
 
 
 def _row_tile(arch, t):
-    """The sorted buffer's row tile for a dispatch over ``t`` rows."""
+    """The sorted buffer's row tile for a dispatch over ``t`` rows: by
+    the rows the experts held here can expect, their share of the
+    routed slots (all of them without a share)."""
     from ..ops.pallas.grouped_matmul import _auto_tm
     from ..runtime.device import is_compiled_with_tpu
-    return _auto_tm(arch.n_held, t * arch.top_k) \
+    return _auto_tm(arch.n_held,
+                    t * arch.top_k * arch.n_held // arch.num_experts) \
         if is_compiled_with_tpu() else 8
 
 
@@ -197,6 +216,12 @@ def _gate_up_apply(xs, wg, wu, tile_expert, gcounts, tm, on_tpu, shardings,
     return jax.nn.silu(hg) * hu
 
 
+def _relu2(x):
+    import jax
+    x = jax.nn.relu(x)
+    return x * x
+
+
 def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
             expert_base=0):
     """The MoE decoder-layer FFN for one serving dispatch.
@@ -205,7 +230,12 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
     weight tuple ``(rw, egw, euw, edw, sgw, suw, sdw, seg)`` (router
     [H, E] fp; expert stacks [E, H, F]/[E, F, H], fp or int8 pairs;
     shared-expert Linears, placeholder [1, 1] zeros when
-    ``arch.shared`` is off); ``live`` [T] bool masks padding rows out
+    ``arch.shared`` is off) or the same by name (``_MW``), a dict.  A
+    dict that brings ``latent_in`` [H, Z] / ``latent_out`` [Z, H] puts
+    the routed experts in a ``Z``-wide latent: ONE shared projection of
+    the rows before the dispatch and one of the combined sum after it
+    (slots held elsewhere add +0 BEFORE it); the router and the shared
+    expert read the full-width rows.  ``live`` [T] bool masks padding rows out
     of routing (their FFN output is unread); ``group_start`` [T] int32
     maps each row to its capacity page-group's first row (``None`` =
     every row its own group — the decode programs, where top-k's
@@ -229,8 +259,11 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
     from ..ops.pallas.grouped_matmul import make_dropless_plan_rows
     from ..runtime.device import is_compiled_with_tpu
 
-    rw, egw, euw, edw, sgw, suw, sdw, seg = mw
-    t, h = hn.shape
+    if not isinstance(mw, dict):
+        mw = dict(zip(_MW, mw))
+    rw, euw, edw = mw["router"], mw["experts_up"], mw["experts_down"]
+    glu = arch.expert_act == "silu_glu"
+    t = hn.shape[0]
     e, k = arch.num_experts, arch.top_k
     # the experts whose matrices are here: all ``e``, or a share
     n_held, lo = arch.n_held, arch.expert_lo
@@ -238,14 +271,32 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
     f32 = jnp.float32
     xf = hn.astype(f32)
 
-    # router (nn/moe.py _router_parts math, serving subset): softmax
-    # over ALL experts, then top-k; HF Qwen2-MoE ships norm_topk off
     logits = jnp.dot(xf, rw.astype(f32))
-    probs = jax.nn.softmax(logits, axis=-1)                 # [T, E]
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)         # [T, k]
-    if arch.norm_topk:
-        gate_vals = gate_vals / jnp.clip(
-            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if arch.scoring == "softmax":
+        # router (nn/moe.py _router_parts math, serving subset): softmax
+        # over ALL experts, then top-k; HF Qwen2-MoE ships norm_topk off
+        probs = jax.nn.softmax(logits, axis=-1)             # [T, E]
+        gate_vals, expert_idx = jax.lax.top_k(probs, k)     # [T, k]
+        if arch.norm_topk:
+            gate_vals = gate_vals / jnp.clip(
+                jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    else:
+        # independent scores: chosen by the bias-corrected ones, weighed
+        # by the uncorrected ones
+        scores = jax.nn.sigmoid(logits)
+        pick = scores + mw["router_bias"].astype(f32)[None, :] \
+            if "router_bias" in mw else scores
+        _, expert_idx = jax.lax.top_k(pick, k)
+        gate_vals = jnp.take_along_axis(scores, expert_idx, axis=1)
+        if arch.norm_topk:
+            gate_vals = gate_vals / (
+                jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-20)
+    if arch.route_scale != 1.0:
+        gate_vals = gate_vals * arch.route_scale
+    # the rows the experts read: ``hn``, or its shared projection into
+    # the experts' latent
+    rows = _mm(hn, mw["latent_in"]) if "latent_in" in mw else hn
+    h = rows.shape[1]
 
     live_slot = jnp.repeat(live, k)                         # [T*k]
     eidx = expert_idx.reshape(-1)
@@ -289,10 +340,15 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
         # the routed rows travel in their own dtype (``xf`` is an exact
         # widening of ``hn``: the kernels read the same values at half
         # the bytes); what the kernels write is float32, as before
-        xs = jnp.zeros((m_pad, h), hn.dtype).at[dest].set(
-            hn[order // k], mode="drop")
-        hs = _gate_up_apply(xs, egw, euw, tile_expert, gcounts, tm,
-                            on_tpu, shardings, expert_base, n_held)
+        xs = jnp.zeros((m_pad, h), rows.dtype).at[dest].set(
+            rows[order // k], mode="drop")
+        if glu:
+            hs = _gate_up_apply(xs, mw["experts_gate"], euw, tile_expert,
+                                gcounts, tm, on_tpu, shardings,
+                                expert_base, n_held)
+        else:
+            hs = _relu2(_gmm_apply(xs, euw, tile_expert, gcounts, tm,
+                                   on_tpu, shardings, expert_base, n_held))
         ys = _gmm_apply(hs, edw, tile_expert, gcounts, tm, on_tpu,
                         shardings, expert_base, n_held)
         dest_safe = jnp.minimum(dest, m_pad - 1)
@@ -302,24 +358,32 @@ def moe_ffn(hn, mw, arch, live, group_start=None, shardings=None,
         # dense per-expert reference: the same row-wise contractions
         # on the unsorted slot rows, dropped slots zeroed after
         safe = jnp.clip(eidx - lo, 0, n_held - 1) + expert_base
-        xdup = jnp.repeat(xf, k, axis=0)                    # [T*k, H]
-        hg = _expert_rows_mm(xdup, egw, safe)
+        xdup = jnp.repeat(rows.astype(f32), k, axis=0)      # [T*k, H]
         hu = _expert_rows_mm(xdup, euw, safe)
-        hs = (jax.nn.silu(hg.astype(f32))
-              * hu.astype(f32)).astype(xdup.dtype)
+        if glu:
+            hg = _expert_rows_mm(xdup, mw["experts_gate"], safe)
+            hs = (jax.nn.silu(hg.astype(f32))
+                  * hu.astype(f32)).astype(xdup.dtype)
+        else:
+            hs = _relu2(hu.astype(f32))
         ys = _expert_rows_mm(hs, edw, safe)
         y = jnp.where(here[:, None], ys.astype(f32), 0.0)
 
     out = jnp.einsum("tk,tkh->th", gate_vals.astype(f32),
                      y.reshape(t, k, h))                    # [T, H]
+    if "latent_out" in mw:
+        out = _mm(out.astype(hn.dtype), mw["latent_out"]).astype(f32)
 
     if arch.shared:
         # shared-expert SwiGLU (+ optional sigmoid token gate) — the
-        # Qwen2-MoE composition (nn/moe.py MoELayer)
-        sh = jax.nn.silu(_mm(xf, sgw)) * _mm(xf, suw)
+        # Qwen2-MoE composition (nn/moe.py MoELayer) — or relu^2
+        suw, sdw = mw["shared_up"], mw["shared_down"]
+        sh = jax.nn.silu(_mm(xf, mw["shared_gate"])) * _mm(xf, suw) \
+            if glu else _relu2(_mm(xf, suw))
         shared = _mm(sh, sdw)
         if arch.shared_gate:
-            shared = shared * jax.nn.sigmoid(_mm(xf, seg))
+            shared = shared * jax.nn.sigmoid(
+                _mm(xf, mw["shared_expert_gate"]))
         out = out + shared
 
     return out.astype(hn.dtype), counts

@@ -164,9 +164,9 @@ class PagedKVCache:
                 self.k_scales = shardings.put(self.k_scales, 1)
                 self.v_scales = shardings.put(self.v_scales, 1)
         # A SECOND kind of per-request state, for backbones with
-        # linear-attention layers (``state_spec``: backbone.HybridArch):
-        # per linear layer one float32 recurrent state per slot
-        # [max_seqs + 1, Hv, dk, dv] and the conv window of the last
+        # recurrent mixers (``state_spec``: backbone.HybridArch): per
+        # such layer one float32 recurrent state per slot [max_seqs +
+        # 1, *state_spec.state_shape] and the conv window of the last
         # K - 1 inputs [max_seqs + 1, K - 1, C].  They are indexed by
         # the SLOT (not by pages), live and die with it, ride the step
         # programs' carry whole and are aliased in and out like the
@@ -182,9 +182,7 @@ class PagedKVCache:
                     "recurrent state pools are not sharded over a mesh")
             sp = state_spec
             self.rec_state = tuple(
-                jnp.zeros((max_seqs + 1, sp.linear_num_value_heads,
-                           sp.linear_key_head_dim,
-                           sp.linear_value_head_dim), jnp.float32)
+                jnp.zeros((max_seqs + 1,) + sp.state_shape, jnp.float32)
                 for _ in range(sp.n_linear))
             self.conv_state = tuple(
                 jnp.zeros((max_seqs + 1, sp.linear_conv_kernel_dim - 1,
@@ -633,8 +631,7 @@ class PagedKVCache:
         sp = self.state_spec
         return {"page_size": self.page_size,
                 "state": None if sp is None else [
-                    sp.n_linear, sp.linear_num_value_heads,
-                    sp.linear_key_head_dim, sp.linear_value_head_dim,
+                    sp.n_linear, *sp.state_shape,
                     sp.linear_conv_kernel_dim - 1, sp.conv_channels,
                     str(np.dtype(self.conv_state[0].dtype))],
                 "num_layers": self.num_layers,

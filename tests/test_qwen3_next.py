@@ -20,11 +20,15 @@ from paddle_tpu.models.qwen3_next import (Qwen3NextForCausalLM,
                                           qwen3_next_tiny_config)
 from paddle_tpu.models.references import qwen3_next as ref
 
-ENGINE = dict(max_seqs=3, max_len=256, page_size=16, steps_per_sync=8,
+ENGINE = dict(max_seqs=3, max_len=272, page_size=16, steps_per_sync=8,
               prefill_token_budget=48)
 # every engine of this file has ONE geometry, so the file compiles one
 # mixed-step program and three window programs, all traced with a spy on
-# the logits they sample from (``SEEN`` fills while ``RECORD`` is set)
+# the logits they sample from (``SEEN`` fills while ``RECORD`` is set).
+# The geometry (17 pages a sequence) is this file's OWN: a file that had
+# compiled the same model at the same geometry earlier in the same
+# worker process (``tests/test_step_crossings.py`` serves this model at
+# 16 pages) would leave programs without the spy in the jit caches.
 SEEN, RECORD, COMPILED = [], [False], {}
 
 

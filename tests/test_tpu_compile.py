@@ -612,3 +612,115 @@ def test_hybrid_step_program_fits_and_updates_its_state_in_place(
     held_once = 4 * 3 * 256 * 2048 * 512 * 2
     assert held_once < mem.argument_size_in_bytes < 10.5e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+
+
+def _ssm_moe_step_program(sds, window, packed=True, slots=None):
+    """The packed step / window program for the backbone whose blocks
+    are a mixer or an expert layer alone, at the cell
+    ``ssm_moe_serve_reason``'s sizes: the eleven blocks ``MEMEMEMEM*E``
+    at Nemotron-3-Super's widths, 128 of 512 experts held in a 1,024
+    latent, vocabulary 32,768, and the engine settings of the
+    configuration's file."""
+    import json
+    import os
+    from paddle_tpu.inference import engine as E
+    from paddle_tpu.inference.backbone import HybridArch
+    from paddle_tpu.inference.moe_dispatch import MoEArch
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "perfbench", "configs",
+            "nemotron3_super_120b_a12b_11l.json"), encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    eng = cfg["engine"]
+    h, nh, kvh, d = 4096, 32, 2, 128
+    held, z, f, fs, vocab = 128, 1024, 2688, 5376, 32768
+    mh, mp, g, n = 128, 64, 8, 128
+    d_in, cc = mh * mp, mh * mp + 2 * g * n
+    slots = slots or eng["max_seqs"]
+    max_len, page, budget = (eng["max_len"], eng["page_size"],
+                             eng["prefill_token_budget"])
+    n_pages, maxp = slots * (max_len // page) + 1, max_len // page
+    t = slots + budget
+    kinds = tuple({"M": "ssm", "*": "full", "E": "ffn"}[p]
+                  for p in cfg["hybrid_override_pattern"])
+    hy = HybridArch(kinds=kinds, rotary_dim=0, linear_num_key_heads=0,
+                    linear_num_value_heads=0, linear_key_head_dim=0,
+                    linear_value_head_dim=0, linear_conv_kernel_dim=4,
+                    conv_channels=cc, zero_centred_norm=False,
+                    mamba_num_heads=mh, mamba_head_dim=mp, n_groups=g,
+                    ssm_state_size=n)
+
+    def w(*shape):
+        return sds(shape, BF16)
+
+    def layer(kind):
+        if kind == "ssm":
+            return dict(in_norm=w(h), in_proj=w(h, d_in + cc + mh),
+                        conv=w(4, cc), conv_bias=w(cc), A_log=w(mh),
+                        dt_bias=w(mh), D=w(mh), norm=w(d_in),
+                        o=w(d_in, h))
+        if kind == "full":
+            return dict(in_norm=w(h), q=w(h, nh * d), k=w(h, kvh * d),
+                        v=w(h, kvh * d), o=w(nh * d, h))
+        return dict(post_norm=w(h), router=w(h, 512), router_bias=w(512),
+                    latent_in=w(h, z), latent_out=w(z, h),
+                    experts_up=w(held, z, f), experts_down=w(held, f, z),
+                    shared_up=w(h, fs), shared_down=w(fs, h))
+    arch = MoEArch(num_experts=512, top_k=22, norm_topk=True, capacity=0,
+                   shared=True, shared_gate=False, attn_bias=False,
+                   dispatch="grouped", expert_lo=0, experts_held=held,
+                   scoring="sigmoid", route_scale=5.0, expert_act="relu2")
+    pool = sds((1, kvh, n_pages, page, d), BF16)
+    n_desc = slots + 3 + budget // page           # the engine's cap
+    n_ssm = kinds.count("ssm")
+    rec = tuple(sds((slots + 1, mh, mp, n), F32) for _ in range(n_ssm))
+    conv = tuple(sds((slots + 1, 3, cc), BF16) for _ in range(n_ssm))
+    geom = (t, n_desc, maxp)
+    args = [tuple(layer(k) for k in kinds), w(h), w(h, vocab),
+            w(vocab, h), (sds((max_len, 0), F32),) * 2, pool, pool,
+            None, None, sds((E._step_layout(*geom, True)[1],), I32),
+            sds((2,), jnp.uint32), rec, conv]
+    kw = dict(eps=1e-5, kvh=kvh, head_dim=d, arch=arch, hybrid=hy,
+              geom=geom)
+    if window:
+        kw["n_steps"] = 8
+    state = 2 * pool.size * 2 + sum(a.size * 4 for a in rec) \
+        + sum(a.size * 2 for a in conv)
+    return (E._packed_mixed_window if window
+            else E._packed_mixed_step).lower(*args, **kw), state, \
+        (arch, t, z)
+
+
+@pytest.mark.parametrize("window", [False, True],
+                         ids=["mixed_step", "mixed_window"])
+def test_ssm_moe_step_program_fits_and_updates_its_state_in_place(
+        window, one_chip, no_compile_cache, on_tpu):
+    """Blocks that are a mixer or an expert layer alone, at the cell
+    ``ssm_moe_serve_reason``'s real sizes: the chip's compiler takes the
+    programs the engine launches (the ragged kernel at 32 : 2 heads of
+    128, ``gmm`` at K 1024 / N 2688 and K 2688 / N 1024 over the held
+    share), weights held once plus pools, the Mamba-2 state and the
+    temporaries fit the chip, and both kinds of per-request state are
+    donated in and aliased out.  The routed rows travel in bf16 at the
+    latent's width and nothing but the up kernel writes a float32
+    ``[m_pad, 2688]``."""
+    import re
+    from paddle_tpu.inference.moe_dispatch import expert_buffer_rows
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    lowered, state_bytes, (arch, t, z) = _ssm_moe_step_program(sds, window)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    ops = _kernels_in(text)
+    assert any("ragged_paged_append_attend" in op for op in ops)
+    assert _has_kernel(ops, "gmm") and not _has_kernel(ops, "gmm_glu")
+    m_pad = expert_buffer_rows(arch, t)
+    assert f"bf16[{m_pad},{z}]" in text              # the buffer itself
+    mem = compiled.memory_analysis()
+    print("ssm-moe step program:", mem.argument_size_in_bytes,
+          "B arguments,", mem.temp_size_in_bytes, "B temporaries,",
+          mem.alias_size_in_bytes, "B aliased")
+    assert mem.alias_size_in_bytes >= state_bytes
+    held_once = 5 * 2 * 128 * 1024 * 2688 * 2
+    assert held_once < mem.argument_size_in_bytes < 13.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
