@@ -73,7 +73,11 @@ at construction); the per-step body IS the single-step program's
 body and the key sequence is the same ``inference.sampling``
 ``split_step`` chain, so a window's tokens equal the per-token
 stream's (``steps_per_sync=1``) on every path — plain, int8 KV,
-prefix hits, preempt→resume, migration.
+prefix hits, preempt→resume, migration.  The window program runs at
+a geometry of its own, one row and one descriptor a slot (the mixed
+step's rows are the slots plus the prefill budget, which a pure-decode
+window cannot use): ``llm_engine_forward_row_capacity_total{path}``
+counts the rows each path's programs ran.
 
 Automatic prefix caching (``enable_prefix_caching=``, default on):
 admission looks up the longest cached page-aligned prefix of the
@@ -1204,9 +1208,13 @@ def _paged_mixed_window(stack, norm_w, head_w, embed_w, rope,
 @functools.lru_cache(maxsize=None)
 def _step_layout(t_cap: int, s_cap: int, maxp: int, hybrid: bool):
     """Where each int32 input of a unified step lies in the step's one
-    upload: ``(((name, offset, shape), ...), size)``.  The geometry is what
-    the engine observes at construction (rows, descriptors — one a row,
-    or the hybrid backbone's cap —, pages a sequence), all static.
+    upload: ``(((name, offset, shape), ...), size)``.  The geometry is
+    one of the two the engine observes at construction (rows,
+    descriptors, pages a sequence), all static: the mixed step's —
+    slots + prefill budget rows, a descriptor a row or the hybrid
+    backbone's cap — or the decode window's — one row and one
+    descriptor a slot, and for a hybrid backbone one dead descriptor
+    more, which its padding rows name.
     ``row_tables`` is not here: it is ``desc_tables[desc_of_row]``, row
     for row, and the wrappers gather it on the device."""
     shapes = [(name, (t_cap,)) for name in (
@@ -1404,8 +1412,9 @@ class LLMEngine:
         self.kv_dtype = kv_dtype
         self.weight_dtype = weight_dtype
         # ONE compiled program serves every mixed prefill+decode
-        # batch.  The STATIC prefill-token budget sizes the flat batch
-        # (T = max_seqs + budget rows); the runtime budget
+        # batch.  The STATIC prefill-token budget sizes its flat batch
+        # (T = max_seqs + budget rows; a pure-decode window's program
+        # runs max_seqs rows); the runtime budget
         # (``prefill_token_budget`` attribute) can be lowered per step
         # — e.g. by a scheduler's decode-latency SLO loop — without
         # recompiling, since T never changes.
@@ -1763,28 +1772,29 @@ class LLMEngine:
         # ... and its ONE upload: a host buffer the engine owns, the
         # descriptors views into it at static offsets.  A step copies
         # ``blank`` over it and fills in what is live; it is written
-        # only after the step that read it has been read back.
+        # only after the step that read it has been read back.  There
+        # is one for each of the TWO static geometries, observed here:
+        # the mixed step's rows are the slots plus the prefill budget
+        # (descriptors one a row, or the hybrid backbone's cap); a
+        # decode window is pure decode, so its program runs one row and
+        # one descriptor a slot (plus the one dead descriptor a hybrid
+        # backbone's padding rows name).  Decode row i is descriptor i
+        # in both.
         hybrid = self._hybrid is not None
         t_cap = max_seqs + self._pf_budget_static
+        maxp = self.cache.page_table.shape[1]
         self._step_geom = (t_cap, self._desc_cap if hybrid else t_cap,
-                           self.cache.page_table.shape[1])
-        blank = np.zeros(_step_layout(*self._step_geom, hybrid)[1],
-                         np.int32)
-        f = _unpack_step(blank, self._step_geom, hybrid)
-        # padding rows name a dead (q_len == 0) descriptor, whose
-        # kernel output block is zeroed and whose table is zeros: their
-        # own, or the hybrid's last; unused descriptors the pad slot
-        if hybrid:
-            f["desc_of_row"][:] = self._desc_cap - 1
-            f["desc_slot"][:] = max_seqs
-        else:
-            f["desc_of_row"][:] = np.arange(t_cap)
-        f["eos_ids"][:] = -1
-        f["budgets"][:] = 1
-        self._step_blank = blank
-        self._step_buf = blank.copy()
-        self._step_fields = _unpack_step(self._step_buf, self._step_geom,
-                                         hybrid)
+                           maxp)
+        self._window_geom = (max_seqs,
+                             max_seqs + 1 if hybrid else max_seqs, maxp)
+        self._step_bufs = {geom: self._blank_step(geom) for geom in
+                           (self._step_geom, self._window_geom)}
+        # rows the launched programs ran (rows of the program x its
+        # forwards) beside the rows that were live in them, by path:
+        # the step's row fill (shapes and host counts, no transfer)
+        self.forward_rows = {
+            path: {"capacity": 0, "live": 0}
+            for path in ("mixed", "window")}
         # what the linear layers' recurrence was given, per step: rows
         # by kind and live descriptors (host counters, like the prefix
         # stats — the registry series mirror them)
@@ -2059,7 +2069,16 @@ class LLMEngine:
             "dispatches: behind the next launch (the chip busy under "
             "them), or at_idle (the engine left without work, or a "
             "reader asked).", ("engine", "when"))
+        capacity = reg.counter(
+            "llm_engine_forward_row_capacity_total",
+            "Rows the launched step programs ran: rows of the program "
+            "(slots + prefill budget for path=mixed, one a slot for "
+            "path=window) x the forwards it ran.  The live rows over "
+            "this is the step's row fill (metrics_snapshot()"
+            "['forward_rows']).", ("engine", "path"))
         self._metrics = {
+            "row_capacity_mixed": capacity.labels(eid, "mixed"),
+            "row_capacity_window": capacity.labels(eid, "window"),
             "transfers_in": transfers.labels(eid, "in"),
             "transfers_out": transfers.labels(eid, "out"),
             "folds_behind_launch": folds.labels(eid, "behind_launch"),
@@ -2245,6 +2264,26 @@ class LLMEngine:
         m["decode_compiles"].set(self.decode_compiles())
         m["mixed_compiles"].set(self.mixed_compiles())
         m["window_compiles"].set(self.window_compiles())
+
+    def _blank_step(self, geom):
+        """One geometry's upload as the host holds it: (blank, buffer,
+        the buffer's fields as views at ``_step_layout``'s offsets).
+        In the blank every descriptor is dead (``q_len == 0``: its
+        kernel output block is zeroed, its table zeros) and every row a
+        padding row that names one — its own, or a hybrid backbone's
+        last, whose slot is the pad slot."""
+        hybrid = self._hybrid is not None
+        blank = np.zeros(_step_layout(*geom, hybrid)[1], np.int32)
+        f = _unpack_step(blank, geom, hybrid)
+        if hybrid:
+            f["desc_of_row"][:] = geom[1] - 1
+            f["desc_slot"][:] = self.max_seqs
+        else:
+            f["desc_of_row"][:] = np.arange(geom[0])
+        f["eos_ids"][:] = -1
+        f["budgets"][:] = 1
+        buf = blank.copy()
+        return blank, buf, _unpack_step(buf, geom, hybrid)
 
     def _note_expert_counts(self, counts, routed_slots: int, rows: int,
                             forwards: int = 1):
@@ -3042,7 +3081,16 @@ class LLMEngine:
         prefill is pending, the ``steps_per_sync`` window dispatches
         ONCE as the on-device ``_paged_mixed_window`` program
         (power-of-two buckets, early exit), whose tokens are the
-        per-token stream's by construction.
+        per-token stream's by construction.  Each program runs at its
+        own static geometry (``_step_geom`` / ``_window_geom``): the
+        mixed step over slots + prefill budget rows, the window — pure
+        decode — over ONE row and one descriptor a slot, so nothing a
+        decode forward does (embedding, projections, routing, the
+        expert buffer, the ragged kernel's grid, the argmax) is sized
+        by a prefill budget it cannot use.  Decode row i is descriptor
+        i in both; the tokens, the expert buffer's rows and the
+        row-capacity counter are taken by the rows of the program that
+        ran.
 
         Either way the call is ONE launch, which crosses the
         host-device boundary ONCE EACH WAY.  In: every host-made
@@ -3064,9 +3112,10 @@ class LLMEngine:
         with _phase("engine.step.plan"):
             P = self.cache.page_size
             hy = self._hybrid
-            # rows; descriptors: one a row, or the hybrid backbone's cap
-            # (its last descriptor stays dead: the padding rows' own)
-            t_cap, s_cap, _ = self._step_geom
+            # the MIXED step's descriptors, which the prefill plan is
+            # held to: one a row, or the hybrid backbone's cap (its last
+            # descriptor stays dead: the padding rows' own)
+            s_cap = self._step_geom[1]
             batch = list(self._active)
             n = len(batch)
 
@@ -3130,16 +3179,19 @@ class LLMEngine:
         # runs as one while_loop program that exits as soon as every
         # row has retired, syncing the host once
         window = nsteps > 1
+        # ... at its own geometry: one row and one descriptor a slot
+        geom = self._window_geom if window else self._step_geom
+        t_rows = geom[0]
+        path = "window" if window else "mixed"
         sp.set_metadata(decode_slots=n, prefill_tokens=used,
-                        nsteps=nsteps,
-                        path="window" if window else "mixed")
+                        nsteps=nsteps, path=path)
 
         with _phase("engine.step.pack"):
             # the step's ONE upload, written in place: the blank (dead
             # descriptors, padding rows that name them) copied over
             # what the last step left, then the live rows
-            np.copyto(self._step_buf, self._step_blank)
-            f = self._step_fields
+            blank, step_buf, f = self._step_bufs[geom]
+            np.copyto(step_buf, blank)
             ids, positions = f["ids"], f["positions"]
             q_start, q_len, kv_len = f["q_start"], f["q_len"], f["kv_len"]
             desc_tables = f["desc_tables"]
@@ -3176,7 +3228,7 @@ class LLMEngine:
                         eos_ids[i] = r.eos
                     budgets[i] = r.max_new - len(r.out)
 
-        kw = dict(geom=self._step_geom, eps=self.eps, kvh=self.kvh,
+        kw = dict(geom=geom, eps=self.eps, kvh=self.kvh,
                   head_dim=self.head_dim, transpose_head=self._tied,
                   strategy=self.decode_strategy, top_k=self.top_k,
                   top_p=self.top_p, temperature=self.temperature,
@@ -3197,7 +3249,7 @@ class LLMEngine:
                 self._embed_w, self._rope,
                 self.cache.k_pages, self.cache.v_pages,
                 self.cache.k_scales, self.cache.v_scales,
-                jax.device_put(self._step_buf, self._step_sharding),
+                jax.device_put(step_buf, self._step_sharding),
                 self._key, self.cache.rec_state, self.cache.conv_state,
                 **kw)
             (out_d, self.cache.k_pages, self.cache.v_pages,
@@ -3214,16 +3266,16 @@ class LLMEngine:
                 self._fold_expert_counts("behind_launch")
         with _phase("engine.step.wait"):
             toks, steps_done, sub_words, counts = _unpack_result(
-                jax.device_get(out_d), nsteps * t_cap, counts_shape)
+                jax.device_get(out_d), nsteps * t_rows, counts_shape)
             self._crossed("out")
-            toks_all = toks.reshape(nsteps, t_cap)
+            toks_all = toks.reshape(nsteps, t_rows)
             if counts is not None:
                 # live rows this dispatch: n decode slots, every
                 # step of a window, + the packed prefill tokens
                 # (multi-step windows are pure decode)
                 self._note_expert_counts(
                     counts, (n * steps_done + used) * self._arch.top_k,
-                    rows=t_cap, forwards=steps_done)
+                    rows=t_rows, forwards=steps_done)
         dt_win = time.perf_counter() - t_win
 
         with _phase("engine.step.merge"):
@@ -3311,8 +3363,13 @@ class LLMEngine:
             if delivered:
                 _health.get_health().observe_tpot(dt_win / steps_done,
                                                   n=delivered)
+            # the rows the program ran against the rows live in it
+            ran = self.forward_rows[path]
+            ran["capacity"] += t_rows * steps_done
+            ran["live"] += n * steps_done + used
             if self._metrics is not None:
                 m = self._metrics
+                m["row_capacity_" + path].inc(t_rows * steps_done)
                 if delivered:
                     m["tpot"].observe(dt_win / steps_done, n=delivered)
                 m["steps"].inc()
@@ -3775,6 +3832,8 @@ class LLMEngine:
             "suspended_requests": self.suspended_count(),
             "free_slots": self.free_slots(),
             "host_transfers": dict(self.host_transfers),
+            "forward_rows": {path: dict(ran) for path, ran
+                             in self.forward_rows.items()},
             "count_folds": dict(self.count_folds),
             "prefix_caching": dict(
                 self.prefix_stats,

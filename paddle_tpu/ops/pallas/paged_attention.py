@@ -912,8 +912,11 @@ def ragged_paged_append_attend_raw(q, k_pages, v_pages, k_new, v_new,
     every descriptor of a flat token batch.
 
     q:            [T, H, D] flat query rows (decode slots and prefill
-                  chunks packed back to back; T is the engine's static
-                  token capacity).
+                  chunks packed back to back; T is the static row count
+                  of the program that calls: the engine's mixed step
+                  runs slots + prefill budget rows, its decode window
+                  one a slot — T may be smaller than a page, the row
+                  blocks below clamp to T - 1).
     k_new/v_new:  [T, KVH, D] the rows to append, same flat layout.
     q_start/q_len/kv_len: [S] int32 descriptors — descriptor s covers
                   flat rows [q_start, q_start + q_len) at context

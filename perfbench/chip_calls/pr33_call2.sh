@@ -1,0 +1,5 @@
+#!/bin/bash
+# PR 33, chip call 2: plain alternating pairs parent | change, then the traced pair(s) with the engine's forward_rows
+#   chiprun --chips 1 --timeout 3500 -- bash perfbench/chip_calls/pr33_call2.sh
+python3 perfbench/chip_calls/ab_set.py perfbench/chip_calls/pr33_pairs_a.txt
+python3 perfbench/chip_calls/pr33_forward_rows.py perfbench/chip_calls/pr33_traced_a.txt

@@ -82,16 +82,26 @@ def _generate(model, prompts, max_new):
         max_new_tokens=max_new)[0].numpy())[0].tolist() for p in prompts]
 
 
-def test_window_matches_the_per_token_stream(model):
+@pytest.mark.parametrize("slots,prompts", [
+    (8, PROMPTS), (4, PROMPTS), (8, PROMPTS[1:2])],
+    ids=["half_full", "every_slot_live", "one_live_row"])
+def test_window_matches_the_per_token_stream(model, slots, prompts):
     """Acceptance: the one-dispatch window produces the tokens of
     per-token (steps_per_sync=1) stepping — which are the dense
-    ``generate()``'s — for synchronous and deferred admission alike."""
-    base, _ = _serve(model, PROMPTS, max_new=9)
-    assert base == _generate(model, PROMPTS, 9)
-    scan, _ = _serve(model, PROMPTS, max_new=9, steps_per_sync=4)
+    ``generate()``'s — for synchronous and deferred admission alike.
+    The window program runs ONE row a slot (the per-token step program
+    slots + prefill budget rows): with every slot live it has no
+    padding row at all, with one live row all the others are."""
+    base, _ = _serve(model, prompts, max_new=9, max_seqs=slots)
+    assert base == _generate(model, prompts, 9)
+    scan, eng = _serve(model, prompts, max_new=9, steps_per_sync=4,
+                       max_seqs=slots)
     assert scan == base
-    deferred, _ = _serve(model, PROMPTS, max_new=9, admit="begin",
-                         steps_per_sync=4)
+    ran = eng.metrics_snapshot()["forward_rows"]["window"]
+    assert ran["capacity"] == 8 * slots         # 4 + 4 forwards, no more
+    assert ran["live"] == 8 * len(prompts)
+    deferred, _ = _serve(model, prompts, max_new=9, admit="begin",
+                         steps_per_sync=4, max_seqs=slots)
     assert deferred == base
 
 

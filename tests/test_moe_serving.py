@@ -312,7 +312,37 @@ def test_expert_metrics_surface(model):
     assert (f'llm_engine_expert_buffer_rows_total{{engine="'
             f'{one.engine_id}"}} {layers * steps * m_pad}') \
         in get_registry().expose_text()
+    assert snap["forward_rows"] == {
+        "mixed": {"capacity": steps * t_cap, "live": kept // (
+            layers * arch.top_k)},
+        "window": {"capacity": 0, "live": 0}}
     assert 0.0 < moe["row_fill"] < 1.0 and moe["buffer_rows"] > 0
+    # ... and with windows, by the rows of the program that ran: a
+    # window forward lays out a buffer for one row a slot, not for the
+    # mixed step's slots + prefill budget (PR 33)
+    _, two = _serve(model, PROMPTS[:2], max_new=12, admit="begin",
+                    steps_per_sync=4)
+    snap = two.metrics_snapshot()
+    ran, m2 = snap["forward_rows"], snap["moe"]
+    slots = two.max_seqs
+    assert two._window_geom[0] == slots < t_cap
+    mixed, window = (ran[p]["capacity"] // r for p, r in (
+        ("mixed", t_cap), ("window", slots)))
+    assert mixed > 0 and window >= 8
+    assert ran["mixed"]["capacity"] == mixed * t_cap
+    assert ran["window"]["capacity"] == window * slots
+    w_pad = padded_rows(slots * arch.top_k, arch.num_experts, 8)
+    assert expert_buffer_rows(arch, slots) == w_pad < m_pad
+    assert m2["buffer_rows"] == layers * (mixed * m_pad + window * w_pad)
+    # windows also route rows that have retired: live counts them
+    assert sum(m2["expert_tokens"]) == layers * arch.top_k * (
+        ran["mixed"]["live"] + ran["window"]["live"])
+    assert m2["row_fill"] == sum(m2["expert_tokens"]) / m2["buffer_rows"]
+    text = get_registry().expose_text()
+    for path in ran:
+        assert (f'llm_engine_forward_row_capacity_total{{engine="'
+                f'{two.engine_id}",path="{path}"}} '
+                f'{ran[path]["capacity"]}') in text
     sched = Scheduler(_mk(model), max_queue=8)
     sched.submit("s", PROMPTS[0], max_new_tokens=3)
     sched.run_until_idle(max_steps=100)

@@ -385,11 +385,16 @@ def test_counters_are_exact_for_a_fixed_request_list(tiny):
     assert tot.sum() == 2 * 3 * (75 + 15) and moe["dropped_tokens"] == 0
     assert moe["absent_slots"] == tot[4:].sum() > 0
     assert (moe["expert_lo"], moe["experts_held"]) == (0, 4)
-    # rows of the sorted buffer: 51 rows a mixed step or window forward
-    # (3 slots + 48 budget) x top-3 = 153 slots + 4 experts x the CPU's
-    # tile of 8, rounded up to the tile: 192 a block a forward; 2 mixed
-    # steps (48 + 27 prompt rows) + 15 window forwards
-    assert moe["buffer_rows"] == 2 * 192 * (2 + 15)
+    # rows of the sorted buffer, by the rows of the program that ran: a
+    # mixed step's 51 (3 slots + 48 budget) x top-3 = 153 slots + 4
+    # experts x the CPU's tile of 8, rounded up to the tile: 192 a block
+    # a forward; a window forward's 3 (one a slot): 9 slots -> 16 + 32 =
+    # 48.  3 mixed steps (48 + 27 prompt rows, and the last decode row
+    # alone: a window of one IS the step program) + windows of 8 + 4 + 2
+    assert moe["buffer_rows"] == 2 * (192 * 3 + 48 * 14)
+    assert snap["forward_rows"] == {
+        "mixed": {"capacity": 51 * 3, "live": 75 + 1},
+        "window": {"capacity": 3 * 14, "live": 14}}
     assert moe["row_fill"] == pytest.approx(
         (tot.sum() - moe["absent_slots"]) / moe["buffer_rows"])
     from paddle_tpu.observability import get_registry
@@ -405,7 +410,11 @@ def test_counters_are_exact_for_a_fixed_request_list(tiny):
             f'llm_engine_expert_absent_slots_total{{engine="{eid}"}} '
             f'{moe["absent_slots"]}',
             f'llm_engine_expert_buffer_rows_total{{engine="{eid}"}} '
-            f'{moe["buffer_rows"]}'):
+            f'{moe["buffer_rows"]}',
+            f'llm_engine_forward_row_capacity_total{{engine="{eid}",'
+            f'path="mixed"}} 153',
+            f'llm_engine_forward_row_capacity_total{{engine="{eid}",'
+            f'path="window"}} 42'):
         assert line in text, line
 
 

@@ -219,7 +219,7 @@ def shadow(monkeypatch):
         same(got[5], want["next_key"])
         same(got[6:], want["lin"])
         seen.append(dict(program=program, done=done, counts=counts,
-                         packed=np.array(args[9])))
+                         packed=np.array(args[9]), geom=kw["geom"]))
         return got
     monkeypatch.setattr(E._insp, "watched_call", call)
     return seen
@@ -290,11 +290,80 @@ def test_hybrid_steps_with_a_dead_descriptor_equal_the_inner_program(
     assert {s["program"] for s in shadow} == \
         {"engine.mixed_step", "engine.mixed_window"}
     for s in shadow:
-        f = E._unpack_step(s["packed"], eng._step_geom, True)
+        # each program at its own geometry: a window has one more
+        # descriptor than rows, the dead one
+        rows, descs, _ = s["geom"]
+        assert (rows, descs) == (
+            (eng.max_seqs, eng.max_seqs + 1)
+            if s["program"] == "engine.mixed_window" else (t_cap, s_cap))
+        f = E._unpack_step(s["packed"], s["geom"], True)
         assert f["q_len"][-1] == 0 and not f["desc_tables"][-1].any()
         assert f["desc_slot"][-1] == eng.max_seqs       # the pad slot
-        pad = np.arange(t_cap) >= int(f["q_len"].sum())
-        assert (f["desc_of_row"][pad] == s_cap - 1).all()
+        pad = np.arange(rows) >= int(f["q_len"].sum())
+        assert (f["desc_of_row"][pad] == descs - 1).all()
+
+
+@pytest.mark.parametrize("model,cfg", [
+    ("moe", dict(MOE, steps_per_sync=8)),
+    ("dense", dict(max_seqs=4, max_len=64, page_size=8, steps_per_sync=4,
+                   prefill_token_budget=12)),
+    ("hybrid", HYBRID)])
+def test_each_program_is_launched_at_its_own_geometry(
+        request, shadow, model, cfg):
+    """The mixed step runs slots + prefill budget rows, the decode
+    window ONE row and one descriptor a slot (a hybrid backbone one
+    descriptor more, dead, for its padding rows): the upload's size,
+    the result's tokens and the row-capacity counter are the launched
+    program's, and a step still crosses the boundary once each way."""
+    eng = LLMEngine(request.getfixturevalue(model), **cfg)
+    hy = model == "hybrid"
+    slots, maxp = eng.max_seqs, eng.cache.page_table.shape[1]
+    t_cap = slots + cfg["prefill_token_budget"]
+    geoms = {
+        "engine.mixed_step": (
+            t_cap, slots + 3 + -(-cfg["prefill_token_budget"]
+                                 // eng.cache.page_size) if hy else t_cap,
+            maxp),
+        "engine.mixed_window": (slots, slots + 1 if hy else slots, maxp)}
+    assert (eng._step_geom, eng._window_geom) == \
+        tuple(geoms[k] for k in sorted(geoms))
+    begin(eng, (PROMPTS * 2)[:slots], max_new=11)     # every slot taken
+    run(eng)
+    assert {s["program"] for s in shadow} == set(geoms)
+    ran = {"mixed": 0, "window": 0}
+    live, fullest = dict(ran), 0
+    for s in shadow:
+        rows, descs, _ = geom = s["geom"]
+        assert geom == geoms[s["program"]]
+        assert s["packed"].shape == (E._step_layout(*geom, hy)[1],)
+        f = E._unpack_step(s["packed"], geom, hy)
+        n = int(f["n_rows"])
+        path = "window" if s["program"] == "engine.mixed_window" \
+            else "mixed"
+        ran[path] += rows * s["done"]
+        if path == "window":
+            # pure decode: row i is descriptor i, the rest dead
+            assert 1 <= n <= slots
+            assert (f["q_len"][:n] == 1).all() and not f["q_len"][n:].any()
+            assert (f["desc_of_row"][:n] == np.arange(n)).all()
+            pad = descs - 1 if hy else np.arange(n, rows)
+            assert (f["desc_of_row"][n:] == pad).all()
+            live[path] += n * s["done"]
+            fullest = max(fullest, n)
+        else:
+            live[path] += int(f["q_len"].sum())
+    assert fullest == slots           # a window with no padding row
+    snap = eng.metrics_snapshot()
+    assert snap["forward_rows"] == {
+        path: {"capacity": ran[path], "live": live[path]} for path in ran}
+    assert 0 < live["mixed"] < ran["mixed"]
+    assert 0 < live["window"] <= ran["window"] < ran["mixed"]
+    assert snap["host_transfers"] == {"in": len(shadow),
+                                      "out": len(shadow)}
+    text = get_registry().expose_text()
+    for path in ran:
+        assert (f'llm_engine_forward_row_capacity_total{{engine="'
+                f'{eng.engine_id}",path="{path}"}} {ran[path]}') in text
 
 
 # -- (b) the gather on the device is the table the host used to build -----------

@@ -396,7 +396,8 @@ def _expert_rows_guard(text, arch, t, hidden):
     ``gmm`` (down), and NO operation of it — fused or not — writes a
     float32 ``[m_pad, hidden]`` array except the down kernel itself.
     Before, the sorted buffer was float32 (``xs``: written once, read
-    by two calls) and ``hg`` / ``hu`` / ``hs`` went through HBM."""
+    by two calls) and ``hg`` / ``hu`` / ``hs`` went through HBM.
+    Returns ``m_pad``, the buffer's rows at this program's geometry."""
     import re
     from paddle_tpu.inference.moe_dispatch import expert_buffer_rows
     ops = _kernels_in(text)
@@ -410,6 +411,17 @@ def _expert_rows_guard(text, arch, t, hidden):
                and " parameter(" not in ln
                and "/gmm/pallas_call" not in ln]
     assert not writers, writers
+    return m_pad
+
+
+def _ragged_grid(text):
+    """(descriptors, KV heads) of every ``ragged_paged_append_attend``
+    call in the compiled text: its grid, read off the first output —
+    one ``[P x G, D]`` block a (descriptor, KV head)."""
+    import re
+    return {tuple(int(v) for v in m.groups()) for m in re.finditer(
+        r"= \(bf16\[(\d+),(\d+),\d+,\d+\][^\n]*custom-call\([^\n]*"
+        r"ragged_paged_append_attend", text)}
 
 
 def _step_program(sds, moe, window, packed, n_layers=2):
@@ -419,14 +431,16 @@ def _step_program(sds, moe, window, packed, n_layers=2):
     experts of 2048 x 1408 + a shared 2816; dense: InternLM2-1.8B's
     16:8 heads, 8192), two layers, the cell's 32 slots x 2048 (513
     pages: one layer's K pool is then larger than the ragged kernel's
-    own per-descriptor row blocks, which are S3's to shrink)."""
+    own per-descriptor row blocks, which are S3's to shrink), each at
+    the geometry the engine launches it at: the mixed step slots +
+    prefill budget rows, the window ONE row a slot (PR 33)."""
     from paddle_tpu.inference import engine as E
     from paddle_tpu.inference.moe_dispatch import MoEArch
     h, nh, d, e, f, vocab = 2048, 16, 128, 64, 1408, 32000
     kvh = nh if moe else 8
     slots, max_len, page = 32, 2048, 128
     n_pages, maxp = slots * (max_len // page) + 1, max_len // page
-    t = slots + page
+    t = slots if window else slots + page
 
     def w(*shape, dtype=BF16):
         return sds((n_layers,) + shape, dtype)
@@ -495,8 +509,12 @@ def test_step_program_moves_no_layer_of_weights_or_pool(
     text = compiled.as_text()
     ops = _kernels_in(text)
     assert any("ragged_paged_append_attend" in op for op in ops), ops
+    # one descriptor a row: 32 in a window, 160 in a mixed step
+    assert _ragged_grid(text) == {(32 if window else 160,
+                                   16 if moe else 8)}
     if moe:
-        _expert_rows_guard(text, *rows)
+        assert _expert_rows_guard(text, *rows) == (
+            2240 if window else 3008)
     moved = sorted(_bytes_moved(text), reverse=True)
     assert moved, "the parser found no copy or slice at all"
     assert moved[0][0] < limit, (limit, moved[:6])
@@ -526,7 +544,10 @@ def _hybrid_step_program(sds, window, packed):
         eng["max_seqs"], eng["max_len"], eng["page_size"],
         eng["prefill_token_budget"])
     n_pages, maxp = slots * (max_len // page) + 1, max_len // page
-    t = slots + budget
+    # the engine's two geometries: slots + budget rows under its
+    # descriptor cap, or a window's one row a slot + the dead descriptor
+    t = slots if window else slots + budget
+    n_desc = slots + 1 if window else slots + 3 + budget // page
     hy = HybridArch(kinds=("linear",) * 3 + ("full",), rotary_dim=64,
                     linear_num_key_heads=16, linear_num_value_heads=32,
                     linear_key_head_dim=128, linear_value_head_dim=128,
@@ -556,7 +577,6 @@ def _hybrid_step_program(sds, window, packed):
                    shared=True, shared_gate=True, attn_bias=False,
                    dispatch="grouped", expert_lo=0, experts_held=held)
     pool = sds((1, kvh, n_pages, page, d), BF16)
-    n_desc = slots + 3 + budget // page           # the engine's cap
     rows, tbl = sds((t,), I32), sds((t, maxp), I32)
     desc, dtbl = sds((n_desc,), I32), sds((n_desc, maxp), I32)
     rec = tuple(sds((slots + 1, 32, 128, 128), F32) for _ in range(3))
@@ -604,7 +624,8 @@ def test_hybrid_step_program_fits_and_updates_its_state_in_place(
     text = compiled.as_text()
     assert any("ragged_paged_append_attend" in op
                for op in _kernels_in(text))
-    _expert_rows_guard(text, *rows)
+    assert _ragged_grid(text) == {(65 if window else 71, 2)}
+    assert _expert_rows_guard(text, *rows) == (8832 if window else 13952)
     mem = compiled.memory_analysis()
     print("hybrid step program:", mem.argument_size_in_bytes,
           "B arguments,", mem.temp_size_in_bytes, "B temporaries")
@@ -639,7 +660,8 @@ def _ssm_moe_step_program(sds, window, packed=True, slots=None):
     max_len, page, budget = (eng["max_len"], eng["page_size"],
                              eng["prefill_token_budget"])
     n_pages, maxp = slots * (max_len // page) + 1, max_len // page
-    t = slots + budget
+    t = slots if window else slots + budget
+    n_desc = slots + 1 if window else slots + 3 + budget // page
     kinds = tuple({"M": "ssm", "*": "full", "E": "ffn"}[p]
                   for p in cfg["hybrid_override_pattern"])
     hy = HybridArch(kinds=kinds, rotary_dim=0, linear_num_key_heads=0,
@@ -670,7 +692,6 @@ def _ssm_moe_step_program(sds, window, packed=True, slots=None):
                    dispatch="grouped", expert_lo=0, experts_held=held,
                    scoring="sigmoid", route_scale=5.0, expert_act="relu2")
     pool = sds((1, kvh, n_pages, page, d), BF16)
-    n_desc = slots + 3 + budget // page           # the engine's cap
     n_ssm = kinds.count("ssm")
     rec = tuple(sds((slots + 1, mh, mp, n), F32) for _ in range(n_ssm))
     conv = tuple(sds((slots + 1, 3, cc), BF16) for _ in range(n_ssm))
@@ -714,7 +735,9 @@ def test_ssm_moe_step_program_fits_and_updates_its_state_in_place(
     ops = _kernels_in(text)
     assert any("ragged_paged_append_attend" in op for op in ops)
     assert _has_kernel(ops, "gmm") and not _has_kernel(ops, "gmm_glu")
+    assert _ragged_grid(text) == {(129 if window else 135, 2)}
     m_pad = expert_buffer_rows(arch, t)
+    assert m_pad == (6912 if window else 18176)
     assert f"bf16[{m_pad},{z}]" in text              # the buffer itself
     mem = compiled.memory_analysis()
     print("ssm-moe step program:", mem.argument_size_in_bytes,
