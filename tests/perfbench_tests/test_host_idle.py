@@ -143,7 +143,7 @@ def test_read_sums_the_named_buckets_per_span(monkeypatch):
              "engine.step": 1e6, NONE: 5e6}, {"engine.step": 4}))
     spec = manifest.layer_metric("engine.idle_prepare_ms.sat")
     assert host_idle.read(_Rec, spec) == pytest.approx(2.0)       # ms
-    assert seen == [(".perfbench_trace/moe_serve_sat/x", PHASES)]
+    assert seen == [(".perfbench_trace/moe_chat_overload/x", PHASES)]
     spec = manifest.layer_metric("engine.idle_finish_ms.sat")
     assert host_idle.read(_Rec, spec) == pytest.approx(0.25)
     # no sched.step in the trace, as on the parent: nothing to report
@@ -195,11 +195,14 @@ def test_each_new_metric_file_loads_and_names_its_reader(name):
 def test_the_manifest_is_consistent_with_the_additions():
     bench = manifest.benchmark()
     assert validate.problems(bench, manifest.ROOT) == []
-    # the additions are at the end, in the order the issue gave them
-    assert [m["name"] for m in bench["per_layer"]][-7:] == NEW_METRICS
+    # the additions stand together, in the order the issue gave them
+    # (later PRs' entries follow them: the driver takes those at the end)
+    names = [m["name"] for m in bench["per_layer"]]
+    i = names.index(NEW_METRICS[0])
+    assert names[i:i + 7] == NEW_METRICS
     # every idle second of a serving cell is read by exactly one of the
     # cell's metrics
-    for cell in ("moe_serve_sat", "moe_serve_steady"):
+    for cell in ("moe_chat_overload", "moe_chat_knee80"):
         seen = []
         for name in NEW_METRICS:
             spec = manifest.layer_metric(name)
